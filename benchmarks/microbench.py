@@ -39,9 +39,9 @@ def run():
     qd = jax.random.normal(key, (B, H, D))
     kc = jax.random.normal(key, (B, S, KV, D))
     vc = jax.random.normal(key, (B, S, KV, D))
-    valid = jnp.ones((B, S), bool)
-    dec = jax.jit(lambda q, k, v, m: decode_attention(q, k, v, m))
-    t = _time(dec, qd, kc, vc, valid)
+    lens = jnp.full((B,), S, jnp.int32)
+    dec = jax.jit(lambda q, k, v, n: decode_attention(q, k, v, n))
+    t = _time(dec, qd, kc, vc, lens)
     bytes_ = 2 * B * S * KV * D * 4
     rows.append(("micro_decode_attn_4k", t * 1e6,
                  f"{bytes_/t/1e9:.1f}_GB/s_cache_read"))

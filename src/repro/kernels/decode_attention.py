@@ -13,17 +13,27 @@ point — it multiplies effective bandwidth by G).
 
 Layout — this kernel consumes the **dense** cache layout: each sequence
 owns a contiguous per-slot slab, q: (B, KV, G, D); k/v cache:
-(B, KV, S, D); valid: (B, S) bool (ring-buffer validity — RoPE is
-pre-applied so slot order is free).  It serves ``generate()`` /
-fixed-batch PPO decode, the dense continuous scheduler
-(``kv_layout="dense"``), and sliding-window / ring-buffer caches, which
-are inherently contiguous.  The **paged** serving layout — a shared
-block pool indexed through per-slot block tables, selected by
-``kv_layout="paged"`` in :class:`repro.serving.engine.GenerationEngine`
-— is served by the sibling kernel in
-:mod:`repro.kernels.paged_attention`, which reuses this online-softmax
-scheme but walks the block table (via scalar prefetch) as its
-sequential grid axis instead of a contiguous S axis.
+(B, KV, S, D); lens: (B,) int32 number of valid rows.  The valid rows of
+a slot are always a prefix of its slab (a ring buffer fills slots
+``0..S-1`` in order and RoPE is pre-applied, so slot order is free), so
+one length per row replaces a ``(B, S)`` mask.  ``lens`` arrives by
+scalar prefetch: tiles wholly past it skip their dot-products and their
+K/V index map stays on the last needed tile, so no DMA is issued for
+them.  It serves ``generate()`` / fixed-batch PPO decode, the dense
+continuous scheduler (``kv_layout="dense"``), and sliding-window /
+ring-buffer caches, which are inherently contiguous.  The **paged**
+serving layout — a shared block pool indexed through per-slot block
+tables, selected by ``kv_layout="paged"`` in
+:class:`repro.serving.engine.GenerationEngine` — is served by the
+sibling kernel in :mod:`repro.kernels.paged_attention`, which reuses
+this online-softmax scheme but walks the block table (via scalar
+prefetch) as its sequential grid axis instead of a contiguous S axis.
+
+TPU tiling: the last two dims of every block are either the array's own
+or multiples of (8, 128).  ``s_block`` must therefore divide S and be a
+multiple of 128 or S itself (see ``ops.decode_block``); the int8 scale
+planes ``(B, KV, S)`` travel as ``(1, KV, s_block)`` blocks holding every
+KV head, and the kernel reads its head's row.
 """
 from __future__ import annotations
 
@@ -38,71 +48,108 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, ns):
-    ik = pl.program_id(2)
+def decode_body(n_valid, q_ref, k_ref, v_ref, rest, *, blk, n_steps,
+                quant):
+    """One grid step (b, h, i) of a decode kernel, shared with the paged
+    kernel: fold the i-th ``blk``-row K/V tile of head h into the
+    online-softmax scratch, masking rows at or past ``n_valid``, and
+    write the output on the last step.  ``rest`` is (o, m, l, acc) refs,
+    preceded by the (k_scale, v_scale) refs of an int8 cache, whose
+    ``(1, KV, blk)`` blocks hold every head's row."""
+    if quant:
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
+    h = pl.program_id(1)
+    i = pl.program_id(2)
 
-    @pl.when(ik == 0)
+    @pl.when(i == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)              # (G, D)
-    k = k_ref[0, 0].astype(jnp.float32)              # (sb, D)
-    v = v_ref[0, 0].astype(jnp.float32)
-    valid = valid_ref[0]                             # (sb,)
-    G, D = q.shape
+    # tiles wholly past the length contribute nothing: skip their dots
+    @pl.when(i * blk < n_valid)
+    def _accumulate():
+        q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
+        k = k_ref[0, 0].astype(jnp.float32)          # (blk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
+        D = q.shape[-1]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * (1.0 / np.sqrt(D))                   # (G, blk)
+        if quant:                                    # dequant K on scores
+            s = s * ks_ref[0, pl.ds(h, 1), :]        # (1, blk) fp32
+        rows = i * blk + jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)
+        s = jnp.where(rows < n_valid, s, NEG_INF)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s * (1.0 / np.sqrt(D))                       # (G, sb)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(-1))
+        p = jnp.exp(s - m_new[:, None])
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(-1)
+        if quant:                                    # dequant V on probs
+            p = p * vs_ref[0, pl.ds(h, 1), :]
+        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(-1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + p.sum(-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(ik == ns - 1)
+    @pl.when(i == n_steps - 1)
     def _done():
         l = jnp.maximum(l_ref[...], 1e-20)
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
-def decode_attention_fwd(q, k_cache, v_cache, valid, *, s_block=512,
-                         interpret=False):
-    """q: (B, KV, G, D); k/v: (B, KV, S, D); valid: (B, S) bool."""
-    B, KV, G, D = q.shape
-    S = k_cache.shape[2]
-    s_block = min(s_block, S)
-    assert S % s_block == 0, (S, s_block)
-    ns = S // s_block
-    grid = (B, KV, ns)
+def _kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sb, ns, quant):
+    decode_body(lens_ref[pl.program_id(0)], q_ref, k_ref, v_ref, rest,
+                blk=sb, n_steps=ns, quant=quant)
 
-    kernel = functools.partial(_kernel, ns=ns)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, s_block, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, s_block, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, s_block), lambda b, h, ik: (b, ik)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+
+def _grid_spec(B, KV, G, D, sb, ns, extra_in_specs=()):
+    """Grid (B, KV, ns) over the dense slab; ``lens`` is the one
+    scalar-prefetch operand."""
+    def q_map(b, h, ik, lens):
+        return (b, h, 0, 0)
+
+    def kv_map(b, h, ik, lens):
+        # tiles past the row's length repeat the last needed tile's index,
+        # so the pipeline issues no DMA for them
+        return (b, h, jnp.minimum(ik, jnp.maximum(lens[b] - 1, 0) // sb), 0)
+
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, KV, ns),
+        in_specs=[pl.BlockSpec((1, 1, G, D), q_map),
+                  pl.BlockSpec((1, 1, sb, D), kv_map),
+                  pl.BlockSpec((1, 1, sb, D), kv_map),
+                  *extra_in_specs],
+        out_specs=pl.BlockSpec((1, 1, G, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
+    )
+
+
+def decode_attention_fwd(q, k_cache, v_cache, lens, *, s_block=512,
+                         interpret=False):
+    """q: (B, KV, G, D); k/v: (B, KV, S, D); lens: (B,) int32 valid-row
+    counts (rows ``0..lens-1`` of each slab are attended)."""
+    B, KV, G, D = q.shape
+    S = k_cache.shape[2]
+    s_block = min(s_block, S)
+    assert S % s_block == 0, (S, s_block)
+    ns = S // s_block
+    kernel = functools.partial(_kernel, sb=s_block, ns=ns, quant=False)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=_grid_spec(B, KV, G, D, s_block, ns),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(q, k_cache, v_cache, valid)
+    )(lens.astype(jnp.int32), q, k_cache, v_cache)
 
 
 # ===================================================================== #
@@ -120,75 +167,26 @@ def decode_attention_fwd(q, k_cache, v_cache, valid, *, s_block=512,
 # 2*D int8 + 2 fp32 scales instead of 2*D fp values, which is the whole
 # memory-bandwidth win of int8 KV on this bandwidth-bound kernel.
 # ===================================================================== #
-def _quant_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, valid_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, ns):
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)              # (G, D)
-    k = k_ref[0, 0].astype(jnp.float32)              # (sb, D) int8 widened
-    v = v_ref[0, 0].astype(jnp.float32)
-    ks = ks_ref[0, 0]                                # (sb,) fp32
-    vs = vs_ref[0, 0]
-    valid = valid_ref[0]                             # (sb,)
-    G, D = q.shape
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s * ks[None, :] * (1.0 / np.sqrt(D))         # dequant K on scores
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(-1))
-    p = jnp.exp(s - m_new[:, None])
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + p.sum(-1)
-    pv = p * vs[None, :]                             # dequant V on probs
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(ik == ns - 1)
-    def _done():
-        l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-
-
-def decode_attention_quant_fwd(q, k_cache, v_cache, k_scale, v_scale, valid,
+def decode_attention_quant_fwd(q, k_cache, v_cache, k_scale, v_scale, lens,
                                *, s_block=512, interpret=False):
     """q: (B, KV, G, D) fp; k/v: (B, KV, S, D) int8; k/v_scale:
-    (B, KV, S) fp32; valid: (B, S) bool."""
+    (B, KV, S) fp32; lens: (B,) int32 valid-row counts."""
     B, KV, G, D = q.shape
     S = k_cache.shape[2]
     s_block = min(s_block, S)
     assert S % s_block == 0, (S, s_block)
     ns = S // s_block
-    grid = (B, KV, ns)
 
-    kernel = functools.partial(_quant_kernel, ns=ns)
+    def scale_map(b, h, ik, lens):
+        return (b, 0, jnp.minimum(ik, jnp.maximum(lens[b] - 1, 0) // s_block))
+
+    scale_spec = pl.BlockSpec((1, KV, s_block), scale_map)
+    kernel = functools.partial(_kernel, sb=s_block, ns=ns, quant=True)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, s_block, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, s_block, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, s_block), lambda b, h, ik: (b, h, ik)),
-            pl.BlockSpec((1, 1, s_block), lambda b, h, ik: (b, h, ik)),
-            pl.BlockSpec((1, s_block), lambda b, h, ik: (b, ik)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
+        grid_spec=_grid_spec(B, KV, G, D, s_block, ns,
+                             (scale_spec, scale_spec)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
         interpret=interpret,
-    )(q, k_cache, v_cache, k_scale.astype(jnp.float32),
-      v_scale.astype(jnp.float32), valid)
+    )(lens.astype(jnp.int32), q, k_cache, v_cache,
+      k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))

@@ -10,7 +10,9 @@ O(q_block·D + k_block·D + q_block·k_block) regardless of context length.
 Layout contract (see ops.py for the (B, L, H, D) adapter):
     q: (B, KV, G, Lq, D)   k, v: (B, KV, Lk, D)   out: like q
 Query positions are aligned to the END of the key axis (decode-style
-continuation): qpos = arange(Lq) + (Lk - Lq).
+continuation): qpos = arange(Lq) + (Lk - Lq).  A caller that pads Lq and
+Lk up to tile multiples passes the true ``q_offset`` and ``lk_valid``;
+keys at or past ``lk_valid`` are masked.
 """
 from __future__ import annotations
 
@@ -26,7 +28,7 @@ NEG_INF = -1e30
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            causal, window, k_block, nk, q_offset):
+            causal, window, k_block, nk, q_offset, lk_valid):
     ik = pl.program_id(4)
     iq = pl.program_id(3)
 
@@ -49,7 +51,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         jnp.int32, (qb, k_block), 0)
     kpos = ik * k_block + jax.lax.broadcasted_iota(
         jnp.int32, (qb, k_block), 1)
-    mask = jnp.ones((qb, k_block), bool)
+    mask = kpos < lk_valid
     if causal:
         mask &= qpos >= kpos
     if window is not None:
@@ -72,10 +74,15 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=None,
-                        q_block=256, k_block=512, interpret=False):
-    """q: (B, KV, G, Lq, D); k, v: (B, KV, Lk, D)."""
+                        q_block=256, k_block=512, q_offset=None,
+                        lk_valid=None, interpret=False):
+    """q: (B, KV, G, Lq, D); k, v: (B, KV, Lk, D).  ``lk_valid`` (default
+    Lk) counts the real keys; ``q_offset`` (default ``lk_valid - Lq``) is
+    the absolute position of query row 0."""
     B, KV, G, Lq, D = q.shape
     Lk = k.shape[2]
+    lk_valid = Lk if lk_valid is None else lk_valid
+    q_offset = lk_valid - Lq if q_offset is None else q_offset
     q_block = min(q_block, Lq)
     k_block = min(k_block, Lk)
     assert Lq % q_block == 0 and Lk % k_block == 0, (Lq, q_block, Lk,
@@ -84,7 +91,8 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
     grid = (B, KV, G, nq, nk)
 
     kernel = functools.partial(_kernel, causal=causal, window=window,
-                               k_block=k_block, nk=nk, q_offset=Lk - Lq)
+                               k_block=k_block, nk=nk, q_offset=q_offset,
+                               lk_valid=lk_valid)
     return pl.pallas_call(
         kernel,
         grid=grid,

@@ -1,10 +1,14 @@
 """Jit'd dispatch wrappers over the Pallas kernels.
 
-These are the entry points the model layer calls when ``cfg.use_pallas``;
-off-TPU they run the kernels in ``interpret=True`` mode (Python execution
-of the kernel body) so correctness is CPU-verifiable.  Layout adaptation
-between the model's (B, L, H, D) convention and the kernels' grouped
-(B, KV, G, ...) convention happens here.
+These are the entry points the model layer calls when
+``cfg.pallas_kernels`` (on a TPU by default); off-TPU they run the
+kernels in ``interpret=True`` mode (Python execution of the kernel body)
+so correctness is CPU-verifiable.  Layout adaptation between the model's
+(B, L, H, D) convention and the kernels' grouped (B, KV, G, ...)
+convention happens here, and so does the choice of TPU-legal tiles: the
+last two dims of every block are the array's own or multiples of
+(8, 128), so a length with no such divisor is zero-padded up to one and
+the pad is masked inside the kernel.
 """
 from __future__ import annotations
 
@@ -17,6 +21,7 @@ from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
 from repro.kernels import flash_attention_bwd as _fab
 from repro.kernels import paged_attention as _paged
+from repro.kernels import ref as _ref
 from repro.kernels import rmsnorm as _rms
 from repro.kernels import ssd_scan as _ssd
 
@@ -25,40 +30,77 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _tile(total: int, preferred: int, align: int) -> tuple[int, int]:
+    """(block, padded_total) for one tiled axis: the whole axis when it
+    fits in ``preferred``, else the largest multiple of ``align`` that
+    divides it, else the axis zero-padded up to a multiple of ``align``."""
+    if total <= preferred:
+        return total, total
+    for blk in range(preferred - preferred % align, 0, -align):
+        if total % blk == 0:
+            return blk, total
+    return _tile(-(-total // align) * align, preferred, align)
+
+
+def _pad_axis(x, axis: int, size: int):
+    pad = size - x.shape[axis]
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths)
+
+
 def flash_attention(q, k, v, *, causal=True, window=None, q_block=256,
                     k_block=512):
-    """q: (B, L, H, D); k, v: (B, Lk, KV, D) -> (B, L, H, D)."""
+    """Differentiable Pallas attention in the model layout.
+
+    q: (B, L, H, D); k, v: (B, Lk, KV, D) -> (B, L, H, D).  Queries are
+    the last L of the Lk key positions.  The query tile is a multiple of
+    128 (it is the lane dim of the backward's lse/delta blocks) and the
+    key tile a multiple of 8, or the whole axis."""
     B, Lq, H, D = q.shape
     Lk, KV = k.shape[1], k.shape[2]
     G = H // KV
+    qb, Lq_p = _tile(Lq, q_block, 128)
+    kb, Lk_p = _tile(Lk, k_block, 8)
     q5 = jnp.moveaxis(q.reshape(B, Lq, KV, G, D), 1, 3)   # (B,KV,G,Lq,D)
     k4 = jnp.moveaxis(k, 1, 2)                            # (B,KV,Lk,D)
     v4 = jnp.moveaxis(v, 1, 2)
-    qb = _pick_block(Lq, q_block)
-    kb = _pick_block(Lk, k_block)
-    o = _fa.flash_attention_fwd(q5, k4, v4, causal=causal, window=window,
-                                q_block=qb, k_block=kb,
-                                interpret=_interpret())
+    meta = (bool(causal), window, qb, kb, Lk - Lq, Lk)
+    o = _flash_pallas(meta, _pad_axis(q5, 3, Lq_p), _pad_axis(k4, 2, Lk_p),
+                      _pad_axis(v4, 2, Lk_p))[:, :, :, :Lq]
     return jnp.moveaxis(o, 3, 1).reshape(B, Lq, H, D)
 
 
-def decode_attention(q, k_cache, v_cache, valid):
-    """q: (B, H, D); caches: (B, S, KV, D); valid: (B, S)."""
+def decode_block(S: int) -> int:
+    """Sequence tile of the dense decode kernels: the lane dim of the
+    int8 scale blocks, so a multiple of 128 or the whole slab."""
+    if S <= 512:
+        return S
+    for blk in range(512, 0, -128):
+        if S % blk == 0:
+            return blk
+    return S
+
+
+def decode_attention(q, k_cache, v_cache, lens):
+    """q: (B, H, D); caches: (B, S, KV, D); lens: (B,) valid-row counts
+    (rows ``0..lens-1`` of each slot are attended)."""
     B, H, D = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
     q4 = q.reshape(B, KV, G, D)
     k4 = jnp.moveaxis(k_cache, 1, 2)
     v4 = jnp.moveaxis(v_cache, 1, 2)
-    sb = _pick_block(S, 512)
-    o = _dec.decode_attention_fwd(q4, k4, v4, valid, s_block=sb,
+    o = _dec.decode_attention_fwd(q4, k4, v4, lens, s_block=decode_block(S),
                                   interpret=_interpret())
     return o.reshape(B, H, D)
 
 
-def decode_attention_quant(q, k_cache, v_cache, k_scale, v_scale, valid):
+def decode_attention_quant(q, k_cache, v_cache, k_scale, v_scale, lens):
     """Int8-KV decode.  q: (B, H, D) fp; caches: (B, S, KV, D) int8;
-    scales: (B, S, KV) fp32; valid: (B, S).  Dequant is fused into the
+    scales: (B, S, KV) fp32; lens: (B,).  Dequant is fused into the
     kernel's online softmax — K/V tiles cross HBM as int8 bytes."""
     B, H, D = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
@@ -68,22 +110,21 @@ def decode_attention_quant(q, k_cache, v_cache, k_scale, v_scale, valid):
     v4 = jnp.moveaxis(v_cache, 1, 2)
     ks = jnp.moveaxis(k_scale, 1, 2)
     vs = jnp.moveaxis(v_scale, 1, 2)
-    sb = _pick_block(S, 512)
-    o = _dec.decode_attention_quant_fwd(q4, k4, v4, ks, vs, valid,
-                                        s_block=sb, interpret=_interpret())
+    o = _dec.decode_attention_quant_fwd(q4, k4, v4, ks, vs, lens,
+                                        s_block=decode_block(S),
+                                        interpret=_interpret())
     return o.reshape(B, H, D)
 
 
 def paged_decode_attention_quant(q, k_pool, v_pool, k_scale, v_scale,
                                  block_tables, lens):
-    """Int8-KV paged decode.  q: (B, H, D) fp; pools: (nblocks, bs, KV, D)
-    int8 consumed without a transpose; scale pools: (nblocks, bs, KV)
+    """Int8-KV paged decode.  q: (B, H, D) fp; pools: (nblocks, KV, bs, D)
+    int8 consumed without a transpose; scale pools: (nblocks, KV, bs)
     fp32 riding the same block-table indirection; block_tables: (B, nb)
     int32; lens: (B,) int32."""
     B, H, D = q.shape
-    KV = k_pool.shape[2]
-    G = H // KV
-    q4 = q.reshape(B, KV, G, D)
+    KV = k_pool.shape[1]
+    q4 = q.reshape(B, KV, H // KV, D)
     o = _paged.paged_decode_attention_quant_fwd(
         q4, k_pool, v_pool, k_scale, v_scale, block_tables, lens,
         interpret=_interpret())
@@ -91,29 +132,53 @@ def paged_decode_attention_quant(q, k_pool, v_pool, k_scale, v_scale,
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lens):
-    """q: (B, H, D); pools: (nblocks, bs, KV, D) — the model-side paged
+    """q: (B, H, D); pools: (nblocks, KV, bs, D) — the model-side paged
     cache layout, consumed without a transpose (the kernel's BlockSpec
-    slices one (bs, D) tile per KV head straight out of the pool);
+    takes one (bs, D) tile per KV head straight out of the pool);
     block_tables: (B, nb) int32; lens: (B,) int32 valid-row counts."""
     B, H, D = q.shape
-    KV = k_pool.shape[2]
-    G = H // KV
-    q4 = q.reshape(B, KV, G, D)
+    KV = k_pool.shape[1]
+    q4 = q.reshape(B, KV, H // KV, D)
     o = _paged.paged_decode_attention_fwd(q4, k_pool, v_pool, block_tables,
                                           lens, interpret=_interpret())
     return o.reshape(B, H, D)
 
 
 def rmsnorm(x, w, *, eps=1e-5):
+    """Fused RMSNorm over the last axis.  Differentiable: the backward is
+    the autodiff of the jnp reference (``ref.rmsnorm_ref``), recomputed
+    from the saved input."""
+    return _rmsnorm(eps, x, w)
+
+
+def _rmsnorm_kernel(eps, x, w):
     shape = x.shape
     R = 1
     for d in shape[:-1]:
         R *= d
-    x2d = x.reshape(R, shape[-1])
-    rb = _pick_block(R, 256)
+    rb, R_p = _tile(R, 256, 8)
+    x2d = _pad_axis(x.reshape(R, shape[-1]), 0, R_p)
     o = _rms.rmsnorm_fwd(x2d, w, eps=eps, row_block=rb,
                          interpret=_interpret())
-    return o.reshape(shape)
+    return o[:R].reshape(shape)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rmsnorm(eps, x, w):
+    return _rmsnorm_kernel(eps, x, w)
+
+
+def _rmsnorm_fwd(eps, x, w):
+    return _rmsnorm_kernel(eps, x, w), (x, w)
+
+
+def _rmsnorm_bwd(eps, res, g):
+    x, w = res
+    _, vjp = jax.vjp(lambda x, w: _ref.rmsnorm_ref(x, w, eps), x, w)
+    return vjp(g)
+
+
+_rmsnorm.defvjp(_rmsnorm_fwd, _rmsnorm_bwd)
 
 
 def ssd_scan(X, dt, A, B, C, chunk, initial_state=None):
@@ -169,14 +234,16 @@ def _pick_block(total: int, preferred: int) -> int:
 
 # ===================================================================== #
 # Differentiable Pallas attention (fwd + bwd kernels, custom VJP) — the
-# TPU TRAINING path.  Grouped layout: q (B, KV, G, Lq, D), k/v
-# (B, KV, Lk, D).
+# TPU training path.  Grouped layout: q (B, KV, G, Lq, D), k/v
+# (B, KV, Lk, D).  ``meta`` = (causal, window, q_block, k_block,
+# q_offset, lk_valid): query row 0 sits at absolute position q_offset and
+# keys at or past lk_valid are padding.
 # ===================================================================== #
 def flash_attention_grouped(q, k, v, *, causal=True, window=None,
                             q_block=256, k_block=256):
-    meta = (bool(causal), window,
-            _pick_block(q.shape[3], q_block),
-            _pick_block(k.shape[2], k_block))
+    Lq, Lk = q.shape[3], k.shape[2]
+    meta = (bool(causal), window, _pick_block(Lq, q_block),
+            _pick_block(Lk, k_block), Lk - Lq, Lk)
     return _flash_pallas(meta, q, k, v)
 
 
@@ -185,17 +252,17 @@ def _fwd_with_lse(meta, q, k, v):
     scratch; for the residual we recompute lse with the jnp oracle's
     blocked pass (cheap relative to bwd, avoids a second kernel output
     plumbing in interpret mode)."""
-    causal, window, qb, kb = meta
+    causal, window, qb, kb, q_offset, lk_valid = meta
     out = _fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                  q_block=qb, k_block=kb,
-                                  interpret=_interpret())
+                                  q_block=qb, k_block=kb, q_offset=q_offset,
+                                  lk_valid=lk_valid, interpret=_interpret())
     B, KV, G, Lq, D = q.shape
     from repro.models.modules import _flash_fwd_impl
     qm = jnp.moveaxis(q, 3, 1).reshape(B, Lq, KV * G, D)
-    km = jnp.moveaxis(k, 2, 1)
-    vm = jnp.moveaxis(v, 2, 1)
-    _, lse = _flash_fwd_impl((causal, window, qb, kb,
-                              k.shape[2] - Lq), qm, km, vm)
+    km = jnp.moveaxis(k[:, :, :lk_valid], 2, 1)
+    vm = jnp.moveaxis(v[:, :, :lk_valid], 2, 1)
+    _, lse = _flash_fwd_impl((causal, window, qb, min(kb, lk_valid),
+                              q_offset), qm, km, vm)
     return out, lse
 
 
@@ -210,12 +277,13 @@ def _flash_pallas_fwd(meta, q, k, v):
 
 
 def _flash_pallas_bwd(meta, res, g):
-    causal, window, qb, kb = meta
+    causal, window, qb, kb, q_offset, lk_valid = meta
     q, k, v, out, lse = res
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), -1)
     dq, dk, dv = _fab.flash_attention_bwd(
         q, k, v, g, lse, delta, causal=causal, window=window,
-        q_block=qb, k_block=kb, interpret=_interpret())
+        q_block=qb, k_block=kb, q_offset=q_offset, lk_valid=lk_valid,
+        interpret=_interpret())
     return dq, dk, dv
 
 

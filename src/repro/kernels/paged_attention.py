@@ -20,12 +20,14 @@ per-sequence lengths arrive as scalar-prefetch operands, so the
 (``table[b, ib]``) before the kernel body runs — the TPU analogue of
 vLLM's PagedAttention gather.
 
-Layout: q: (B, KV, G, D); k/v pool: (nblocks, bs, KV, D) — the pool's
-row layout matches the model-side cache convention ``(slot, S, KV, D)``
-with ``(slot, S)`` replaced by ``(block, offset)``; block_tables:
-(B, nb) int32 (entries past a sequence's allocated prefix point at the
-trash block 0); lens: (B,) int32 = number of valid rows (``pos + 1``).
-RoPE is pre-applied to cached keys, so block order is free.
+Layout: q: (B, KV, G, D); k/v pool: (nblocks, KV, bs, D) — the KV head
+axis sits ahead of the block's rows so that one grid step's tile is a
+``(bs, D)`` slab whose last two dims are the array's own (the TPU tiling
+rule; a ``(bs, 1, D)`` slice of a ``(bs, KV, D)`` block is refused);
+block_tables: (B, nb) int32 (entries past a sequence's allocated prefix
+point at the trash block 0); lens: (B,) int32 = number of valid rows
+(``pos + 1``).  RoPE is pre-applied to cached keys, so block order is
+free.
 """
 from __future__ import annotations
 
@@ -33,193 +35,77 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.decode_attention import decode_body
 
 
-def _kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-            acc_ref, *, bs, nb):
-    b = pl.program_id(0)
-    ib = pl.program_id(2)
-
-    @pl.when(ib == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    n_valid = lens_ref[b]
-
-    # blocks wholly past the sequence length contribute nothing: skip the
-    # dot-products (their table entries point at the trash block anyway)
-    @pl.when(ib * bs < n_valid)
-    def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)       # (bs, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        G, D = q.shape
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * (1.0 / np.sqrt(D))                   # (G, bs)
-        rows = ib * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(rows < n_valid, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(ib == nb - 1)
-    def _done():
-        l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+def _kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, *rest, bs, nb, quant):
+    decode_body(lens_ref[pl.program_id(0)], q_ref, k_ref, v_ref, rest,
+                blk=bs, n_steps=nb, quant=quant)
 
 
 # ===================================================================== #
 # Int8-KV paged variant: the pool stores int8 K/V blocks plus per-row
-# fp32 scale planes (nblocks, bs, KV); dequant is fused into the
+# fp32 scale planes (nblocks, KV, bs); dequant is fused into the
 # online-softmax accumulation exactly as in the dense int8 kernel
 # (k_scale multiplies the score tile, v_scale the probability tile), so
 # the DMA per cached token stays at 2*D int8 + 2 fp32 scales — fp K/V is
 # never materialized.  The block-table walk (scalar-prefetch index_map)
-# is identical to the fp kernel; scale tiles ride the same indirection.
+# is identical to the fp kernel; a scale tile holds every KV head's row
+# of the block and rides the same indirection.
 # ===================================================================== #
-def _quant_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, m_ref, l_ref, acc_ref, *, bs, nb):
-    b = pl.program_id(0)
-    ib = pl.program_id(2)
+def _paged_call(q, k_pool, v_pool, scales, block_tables, lens, interpret):
+    B, KV, G, D = q.shape
+    bs = k_pool.shape[2]
+    nb = block_tables.shape[1]
 
-    @pl.when(ib == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def q_map(b, h, ib, tbl, lens):
+        return (b, h, 0, 0)
 
-    n_valid = lens_ref[b]
+    def kv_map(b, h, ib, tbl, lens):
+        return (tbl[b, ib], h, 0, 0)
 
-    @pl.when(ib * bs < n_valid)
-    def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)       # (bs, D) int8 widened
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        ks = ks_ref[0, :, 0]                         # (bs,) fp32
-        vs = vs_ref[0, :, 0]
-        G, D = q.shape
+    def scale_map(b, h, ib, tbl, lens):
+        return (tbl[b, ib], 0, 0)
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * ks[None, :] * (1.0 / np.sqrt(D))     # dequant K on scores
-        rows = ib * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(rows < n_valid, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1)
-        pv = p * vs[None, :]                         # dequant V on probs
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            pv, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(ib == nb - 1)
-    def _done():
-        l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    in_specs = [pl.BlockSpec((1, 1, G, D), q_map),
+                pl.BlockSpec((1, 1, bs, D), kv_map),
+                pl.BlockSpec((1, 1, bs, D), kv_map)]
+    in_specs += [pl.BlockSpec((1, KV, bs), scale_map)] * len(scales)
+    kernel = functools.partial(_kernel, bs=bs, nb=nb, quant=bool(scales))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, KV, nb),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, G, D), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, D), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32),
+      q, k_pool, v_pool, *[x.astype(jnp.float32) for x in scales])
 
 
 def paged_decode_attention_quant_fwd(q, k_pool, v_pool, k_scale, v_scale,
                                      block_tables, lens, *, interpret=False):
-    """q: (B, KV, G, D) fp; k/v pool: (nblocks, bs, KV, D) int8;
-    k/v_scale: (nblocks, bs, KV) fp32; block_tables: (B, nb) int32;
+    """q: (B, KV, G, D) fp; k/v pool: (nblocks, KV, bs, D) int8;
+    k/v_scale: (nblocks, KV, bs) fp32; block_tables: (B, nb) int32;
     lens: (B,) int32."""
-    B, KV, G, D = q.shape
-    nblocks, bs = k_pool.shape[0], k_pool.shape[1]
-    nb = block_tables.shape[1]
-    grid = (B, KV, nb)
-
-    def q_map(b, h, ib, tbl, lens):
-        return (b, h, 0, 0)
-
-    def kv_map(b, h, ib, tbl, lens):
-        return (tbl[b, ib], 0, h, 0)
-
-    def scale_map(b, h, ib, tbl, lens):
-        return (tbl[b, ib], 0, h)
-
-    kernel = functools.partial(_quant_kernel, bs=bs, nb=nb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), q_map),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-            pl.BlockSpec((1, bs, 1), scale_map),
-            pl.BlockSpec((1, bs, 1), scale_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32),
-      q, k_pool, v_pool, k_scale.astype(jnp.float32),
-      v_scale.astype(jnp.float32))
+    return _paged_call(q, k_pool, v_pool, (k_scale, v_scale), block_tables,
+                       lens, interpret)
 
 
 def paged_decode_attention_fwd(q, k_pool, v_pool, block_tables, lens, *,
                                interpret=False):
-    """q: (B, KV, G, D); k/v pool: (nblocks, bs, KV, D);
+    """q: (B, KV, G, D); k/v pool: (nblocks, KV, bs, D);
     block_tables: (B, nb) int32; lens: (B,) int32."""
-    B, KV, G, D = q.shape
-    nblocks, bs = k_pool.shape[0], k_pool.shape[1]
-    nb = block_tables.shape[1]
-    grid = (B, KV, nb)
-
-    def q_map(b, h, ib, tbl, lens):
-        return (b, h, 0, 0)
-
-    def kv_map(b, h, ib, tbl, lens):
-        return (tbl[b, ib], 0, h, 0)
-
-    kernel = functools.partial(_kernel, bs=bs, nb=nb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), q_map),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32),
-      q, k_pool, v_pool)
+    return _paged_call(q, k_pool, v_pool, (), block_tables, lens, interpret)

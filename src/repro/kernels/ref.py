@@ -27,9 +27,15 @@ def flash_attention_ref(q, k, v, *, causal=True, window=None):
     return o.astype(q.dtype)
 
 
-def decode_attention_ref(q, k_cache, v_cache, valid):
-    """q: (B, KV, G, D); caches: (B, KV, S, D); valid: (B, S) bool."""
+def _valid(lens, S):
+    return jnp.arange(S)[None, :] < lens[:, None]
+
+
+def decode_attention_ref(q, k_cache, v_cache, lens):
+    """q: (B, KV, G, D); caches: (B, KV, S, D); lens: (B,) valid-row
+    counts."""
     D = q.shape[-1]
+    valid = _valid(lens, k_cache.shape[2])
     s = jnp.einsum("bkgd,bksd->bkgs", q.astype(jnp.float32),
                    k_cache.astype(jnp.float32)) / np.sqrt(D)
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
@@ -38,29 +44,29 @@ def decode_attention_ref(q, k_cache, v_cache, valid):
     return o.astype(q.dtype)
 
 
+def _gather_blocks(pool, block_tables):
+    """(nblocks, KV, bs, ...) pool -> (B, KV, nb * bs, ...) dense cache."""
+    B, nb = block_tables.shape
+    g = jnp.moveaxis(pool[block_tables], 2, 1)    # (B, KV, nb, bs, ...)
+    return g.reshape((B, g.shape[1], nb * g.shape[3]) + g.shape[4:])
+
+
 def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lens):
-    """q: (B, KV, G, D); pools: (nblocks, bs, KV, D); block_tables:
+    """q: (B, KV, G, D); pools: (nblocks, KV, bs, D); block_tables:
     (B, nb) int32; lens: (B,) int32.  Gathers each sequence's blocks
     into a dense virtual cache and reuses the dense decode oracle."""
-    B = q.shape[0]
-    nb, bs = block_tables.shape[1], k_pool.shape[1]
-    kv = k_pool[block_tables]                     # (B, nb, bs, KV, D)
-    vv = v_pool[block_tables]
-    S = nb * bs
-    k_virt = kv.reshape(B, S, *k_pool.shape[2:])
-    v_virt = vv.reshape(B, S, *v_pool.shape[2:])
-    valid = jnp.arange(S)[None, :] < lens[:, None]
-    return decode_attention_ref(q, jnp.moveaxis(k_virt, 1, 2),
-                                jnp.moveaxis(v_virt, 1, 2), valid)
+    return decode_attention_ref(q, _gather_blocks(k_pool, block_tables),
+                                _gather_blocks(v_pool, block_tables), lens)
 
 
 def decode_attention_quant_ref(q, k_cache, v_cache, k_scale, v_scale,
-                               valid):
+                               lens):
     """Int8-KV decode oracle.  q: (B, KV, G, D) fp; caches: (B, KV, S, D)
-    int8; scales: (B, KV, S) fp32; valid: (B, S) bool.  Mirrors the fused
-    kernel's algebra: scales are applied to the score/probability
-    matrices, never to a dequantized K/V copy."""
+    int8; scales: (B, KV, S) fp32; lens: (B,) valid-row counts.  Mirrors
+    the fused kernel's algebra: scales are applied to the
+    score/probability matrices, never to a dequantized K/V copy."""
     D = q.shape[-1]
+    valid = _valid(lens, k_cache.shape[2])
     s = jnp.einsum("bkgd,bksd->bkgs", q.astype(jnp.float32),
                    k_cache.astype(jnp.float32)) / np.sqrt(D)
     s = s * k_scale.astype(jnp.float32)[:, :, None, :]
@@ -74,20 +80,14 @@ def decode_attention_quant_ref(q, k_cache, v_cache, k_scale, v_scale,
 def paged_decode_attention_quant_ref(q, k_pool, v_pool, k_scale, v_scale,
                                      block_tables, lens):
     """Int8-KV paged decode oracle.  q: (B, KV, G, D) fp; pools:
-    (nblocks, bs, KV, D) int8; scale pools: (nblocks, bs, KV) fp32;
+    (nblocks, KV, bs, D) int8; scale pools: (nblocks, KV, bs) fp32;
     block_tables: (B, nb) int32; lens: (B,) int32.  Gathers blocks and
     scale rows into a dense virtual cache and reuses the dense oracle."""
-    B = q.shape[0]
-    nb, bs = block_tables.shape[1], k_pool.shape[1]
-    S = nb * bs
-    k_virt = k_pool[block_tables].reshape(B, S, *k_pool.shape[2:])
-    v_virt = v_pool[block_tables].reshape(B, S, *v_pool.shape[2:])
-    ks_virt = k_scale[block_tables].reshape(B, S, k_scale.shape[2])
-    vs_virt = v_scale[block_tables].reshape(B, S, v_scale.shape[2])
-    valid = jnp.arange(S)[None, :] < lens[:, None]
     return decode_attention_quant_ref(
-        q, jnp.moveaxis(k_virt, 1, 2), jnp.moveaxis(v_virt, 1, 2),
-        jnp.moveaxis(ks_virt, 1, 2), jnp.moveaxis(vs_virt, 1, 2), valid)
+        q, _gather_blocks(k_pool, block_tables),
+        _gather_blocks(v_pool, block_tables),
+        _gather_blocks(k_scale, block_tables),
+        _gather_blocks(v_scale, block_tables), lens)
 
 
 def rmsnorm_ref(x, w, eps=1e-5):

@@ -21,13 +21,10 @@ HBM_BYTES = 16 * 2 ** 30     # per chip
 
 
 def _mesh(shape, axes, devices=None):
-    # jax.sharding.AxisType only exists on newer jax; older versions
-    # default every axis to Auto anyway, so omit the kwarg there
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is not None:
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(at.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
+    # every axis Auto: the model code places activations with GSPMD
+    # sharding constraints, which explicit-sharding axes would reject
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -115,9 +115,10 @@ def run_rlhf(args, cfg):
               f"max_depth={q['max_depth']} dropped={q['dropped']} "
               f"fallbacks={pipe.async_stats['lockstep_fallbacks']} "
               f"publishes={pipe.async_stats['publisher']['publishes']}")
+    return pipe, out
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -177,13 +178,19 @@ def main():
                          "(--rlhf): the generation engine stores K/V as "
                          "int8 + per-row fp32 scales, training forwards "
                          "are untouched")
+    return ap
+
+
+def main():
+    ap = build_parser()
     args = ap.parse_args()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
     if args.rlhf:
-        return run_rlhf(args, cfg)
+        run_rlhf(args, cfg)
+        return
     mesh = None
     if args.mesh:
         if args.lora:
@@ -288,4 +295,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -13,6 +13,7 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 # Layer kinds.
@@ -111,7 +112,10 @@ class ModelConfig:
     rms_eps: float = 1e-5
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
-    use_pallas: bool = False           # TPU path; CPU/dry-run uses jnp path
+    # Pallas kernels for attention, decode and norms.  None (the default)
+    # chooses by platform (see ``pallas_kernels``); tests set True to run
+    # the kernels in interpret mode on the CPU.
+    use_pallas: Optional[bool] = None
     remat: bool = True                 # activation checkpointing per unit
     logit_chunk: int = 0               # chunked loss: 0 = off
 
@@ -127,6 +131,18 @@ class ModelConfig:
     @property
     def cdtype(self):
         return jnp.dtype(self.compute_dtype)
+
+    @property
+    def pallas_kernels(self) -> bool:
+        """Whether the model layers dispatch to the Pallas kernels: the
+        one place the kernel/jnp choice is made.  By default the kernels
+        run on a TPU and the jnp code elsewhere.  GSPMD cannot partition
+        a Mosaic kernel, so a config whose activations are sharded over a
+        mesh (``batch_axes`` / ``tp_axis`` set) runs the jnp code."""
+        if self.use_pallas is not None:
+            return self.use_pallas
+        sharded = bool(self.batch_axes or self.tp_axis)
+        return jax.default_backend() == "tpu" and not sharded
 
     @property
     def d_inner(self) -> int:

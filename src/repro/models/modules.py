@@ -10,8 +10,8 @@ Attention is implemented flash-style (block-wise online softmax via
 ``lax.scan`` over KV blocks) in pure jnp — the full L×L score matrix is
 never materialized, which is what lets the 32k-prefill and 4k×256-batch
 training graphs compile within per-chip memory on the production mesh.  On
-TPU (``cfg.use_pallas``) the same math dispatches to the Pallas kernels in
-``repro.kernels``.
+a TPU (``cfg.pallas_kernels``) the same math dispatches to the Pallas
+kernels in ``repro.kernels``; the jnp code here is then their reference.
 """
 from __future__ import annotations
 
@@ -26,29 +26,6 @@ import numpy as np
 from repro.models.config import ModelConfig
 
 NEG_INF = -1e30
-
-
-@jax.custom_vjp
-def opt_barrier(x):
-    """``lax.optimization_barrier`` with a gradient rule.
-
-    Some jax versions ship no differentiation rule for the barrier
-    primitive; training graphs differentiate through the barriered
-    weight-gather and layer-scan carries, so we define the obvious one:
-    barrier in both directions (the cotangent benefits from the same
-    no-hoisting guarantee as the primal)."""
-    return jax.lax.optimization_barrier(x)
-
-
-def _opt_barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _opt_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
 
 
 def constrain_batch(x, batch_axes):
@@ -88,7 +65,7 @@ def wgather(w, cfg, axes):
     # barrier pins the f32->bf16 convert BEFORE the gather so the
     # collective moves half the bytes (XLA otherwise reorders to
     # gather-f32-then-convert)
-    w = opt_barrier(w.astype(cfg.cdtype))
+    w = jax.lax.optimization_barrier(w.astype(cfg.cdtype))
     return jax.lax.with_sharding_constraint(w, P(*entries))
 
 
@@ -371,23 +348,26 @@ def _flash_bwd(meta, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def decode_attention(q, k_cache, v_cache, valid_mask, use_pallas=False):
+def decode_attention(q, k_cache, v_cache, lens, use_pallas=False):
     """Single-token attention over a (possibly ring-buffer) KV cache.
 
-    q: (B, H, D); k_cache/v_cache: (B, S, KV, D); valid_mask: (B, S) bool.
-    Returns (B, H, D).  RoPE is pre-applied to cached keys, so slot order
-    inside the ring buffer is irrelevant (softmax is order-invariant).
+    q: (B, H, D); k_cache/v_cache: (B, S, KV, D); lens: (B,) number of
+    valid rows, which are always the slot prefix ``0..lens-1`` (a ring
+    buffer fills its slots in order).  Returns (B, H, D).  RoPE is
+    pre-applied to cached keys, so slot order inside the ring buffer is
+    irrelevant (softmax is order-invariant).
     """
     if use_pallas:
         from repro.kernels import ops as kops
-        return kops.decode_attention(q, k_cache, v_cache, valid_mask)
+        return kops.decode_attention(q, k_cache, v_cache, lens)
     B, H, D = q.shape
+    valid_mask = jnp.arange(k_cache.shape[1])[None, :] < lens[:, None]
     KV = k_cache.shape[2]
     G = H // KV
     # barrier: stops XLA hoisting a convert(f32) of the FULL stacked
     # per-layer cache out of the layer scan (a cache-sized f32 temp)
-    k_cache = opt_barrier(k_cache)
-    v_cache = opt_barrier(v_cache)
+    k_cache = jax.lax.optimization_barrier(k_cache)
+    v_cache = jax.lax.optimization_barrier(v_cache)
     qs = q.reshape(B, KV, G, D)
     s = jnp.einsum("bkgd,bskd->bkgs", qs, k_cache,
                    preferred_element_type=jnp.float32) / np.sqrt(D)
@@ -430,26 +410,35 @@ def paged_attn_cache_shape(cfg: ModelConfig, num_blocks: int,
                            block_size: int):
     """Paged layout: a shared pool of ``num_blocks`` fixed-size KV blocks
     (block 0 reserved as the trash block) instead of a per-slot
-    ``(batch, S)`` arena.  Row layout inside a block matches the dense
-    arena's ``(S, KV, D)`` convention with ``S -> block_size``.  With
+    ``(batch, S)`` arena.  A block is KV-head-major, ``(KV, bs, D)``:
+    the decode kernel's tile is one head's ``(bs, D)`` rows, and TPU
+    tiling refuses a one-head slice of a ``(bs, KV, D)`` block.  With
     ``cfg.kv_quant`` the pool stores int8 K/V plus per-row fp32 scale
-    planes ``(num_blocks, bs, KV)`` that travel with their blocks
+    planes ``(num_blocks, KV, bs)`` that travel with their blocks
     through every scatter/gather (admission, preemption, prefix cache).
     Full-context GQA only (no MLA / sliding-window)."""
     assert not cfg.mla, "paged KV: GQA only (MLA caches latents)"
-    out = dict(k=(num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim),
-               v=(num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim))
+    out = dict(k=(num_blocks, cfg.n_kv_heads, block_size, cfg.head_dim),
+               v=(num_blocks, cfg.n_kv_heads, block_size, cfg.head_dim))
     if cfg.kv_quant:
-        out["k_scale"] = (num_blocks, block_size, cfg.n_kv_heads)
-        out["v_scale"] = (num_blocks, block_size, cfg.n_kv_heads)
+        out["k_scale"] = (num_blocks, cfg.n_kv_heads, block_size)
+        out["v_scale"] = (num_blocks, cfg.n_kv_heads, block_size)
     return out
+
+
+def _virtual_cache(pool, block_tables):
+    """Gather each row's blocks out of a ``(nblocks, KV, bs, ...)`` pool
+    into the dense ``(B, nb * bs, KV, ...)`` cache layout."""
+    B, nb = block_tables.shape
+    g = jnp.moveaxis(pool[block_tables], 2, 3)        # (B, nb, bs, KV, ...)
+    return g.reshape((B, nb * g.shape[2]) + g.shape[3:])
 
 
 def decode_attention_paged(q, k_pool, v_pool, block_tables, lens,
                            use_pallas=False):
     """Single-token attention over a block-pooled KV cache.
 
-    q: (B, H, D); k/v pool: (nblocks, bs, KV, D); block_tables: (B, nb)
+    q: (B, H, D); k/v pool: (nblocks, KV, bs, D); block_tables: (B, nb)
     int32 (entries past a sequence's allocated prefix point at trash
     block 0); lens: (B,) valid-row counts.  The jnp path gathers each
     sequence's blocks into a dense (B, nb*bs, KV, D) virtual cache and
@@ -460,14 +449,11 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, lens,
         from repro.kernels import ops as kops
         return kops.paged_decode_attention(q, k_pool, v_pool, block_tables,
                                            lens)
-    B = q.shape[0]
-    nb, bs = block_tables.shape[1], k_pool.shape[1]
-    k_pool = opt_barrier(k_pool)
-    v_pool = opt_barrier(v_pool)
-    k_virt = k_pool[block_tables].reshape(B, nb * bs, *k_pool.shape[2:])
-    v_virt = v_pool[block_tables].reshape(B, nb * bs, *v_pool.shape[2:])
-    valid = jnp.arange(nb * bs)[None, :] < lens[:, None]
-    return decode_attention(q, k_virt, v_virt, valid)
+    k_pool = jax.lax.optimization_barrier(k_pool)
+    v_pool = jax.lax.optimization_barrier(v_pool)
+    k_virt = _virtual_cache(k_pool, block_tables)
+    v_virt = _virtual_cache(v_pool, block_tables)
+    return decode_attention(q, k_virt, v_virt, lens)
 
 
 def _kv_quant(x):
@@ -485,7 +471,7 @@ def _kv_quant(x):
     return xi, scale.astype(jnp.float32)
 
 
-def decode_attention_quant(q, k_i8, v_i8, k_scale, v_scale, valid_mask,
+def decode_attention_quant(q, k_i8, v_i8, k_scale, v_scale, lens,
                            use_pallas=False):
     """Flash-decode over an int8 KV cache: the dots consume int8 operands
     (XLA fuses the widening convert, so HBM traffic is the int8 bytes);
@@ -494,16 +480,19 @@ def decode_attention_quant(q, k_i8, v_i8, k_scale, v_scale, valid_mask,
     into the online-softmax decode kernel (see
     :func:`repro.kernels.decode_attention.decode_attention_quant_fwd`).
 
-    q: (B, H, D); k_i8/v_i8: (B, S, KV, D) int8; scales: (B, S, KV)."""
+    q: (B, H, D); k_i8/v_i8: (B, S, KV, D) int8; scales: (B, S, KV);
+    lens: (B,) valid-row counts (a slot prefix, as in
+    :func:`decode_attention`)."""
     if use_pallas:
         from repro.kernels import ops as kops
         return kops.decode_attention_quant(q, k_i8, v_i8, k_scale, v_scale,
-                                           valid_mask)
+                                           lens)
     B, H, D = q.shape
+    valid_mask = jnp.arange(k_i8.shape[1])[None, :] < lens[:, None]
     KV = k_i8.shape[2]
     G = H // KV
-    k_i8 = opt_barrier(k_i8)
-    v_i8 = opt_barrier(v_i8)
+    k_i8 = jax.lax.optimization_barrier(k_i8)
+    v_i8 = jax.lax.optimization_barrier(v_i8)
     qs = q.reshape(B, KV, G, D)
     s = jnp.einsum("bkgd,bskd->bkgs", qs.astype(jnp.float32),
                    k_i8.astype(jnp.float32)) / np.sqrt(D)
@@ -519,8 +508,8 @@ def decode_attention_paged_quant(q, k_pool, v_pool, k_scale, v_scale,
                                  block_tables, lens, use_pallas=False):
     """Single-token attention over an int8 block-pooled KV cache.
 
-    q: (B, H, D); k/v pool: (nblocks, bs, KV, D) int8; scale pools:
-    (nblocks, bs, KV) fp32; block_tables: (B, nb) int32; lens: (B,).
+    q: (B, H, D); k/v pool: (nblocks, KV, bs, D) int8; scale pools:
+    (nblocks, KV, bs) fp32; block_tables: (B, nb) int32; lens: (B,).
     The jnp path gathers blocks *and their scale rows* into a dense
     virtual cache and reuses :func:`decode_attention_quant` — bit-
     identical to the dense int8 arena when ``nb*bs`` equals the arena
@@ -530,16 +519,13 @@ def decode_attention_paged_quant(q, k_pool, v_pool, k_scale, v_scale,
         from repro.kernels import ops as kops
         return kops.paged_decode_attention_quant(
             q, k_pool, v_pool, k_scale, v_scale, block_tables, lens)
-    B = q.shape[0]
-    nb, bs = block_tables.shape[1], k_pool.shape[1]
-    k_pool = opt_barrier(k_pool)
-    v_pool = opt_barrier(v_pool)
-    k_virt = k_pool[block_tables].reshape(B, nb * bs, *k_pool.shape[2:])
-    v_virt = v_pool[block_tables].reshape(B, nb * bs, *v_pool.shape[2:])
-    ks_virt = k_scale[block_tables].reshape(B, nb * bs, k_scale.shape[2])
-    vs_virt = v_scale[block_tables].reshape(B, nb * bs, v_scale.shape[2])
-    valid = jnp.arange(nb * bs)[None, :] < lens[:, None]
-    return decode_attention_quant(q, k_virt, v_virt, ks_virt, vs_virt, valid)
+    k_pool = jax.lax.optimization_barrier(k_pool)
+    v_pool = jax.lax.optimization_barrier(v_pool)
+    return decode_attention_quant(
+        q, _virtual_cache(k_pool, block_tables),
+        _virtual_cache(v_pool, block_tables),
+        _virtual_cache(k_scale, block_tables),
+        _virtual_cache(v_scale, block_tables), lens)
 
 
 def attn_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
@@ -577,31 +563,32 @@ def attn_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
         assert cache is not None
         assert window is None, \
             "paged KV supports full-context GQA only"
-        bs = cache["k"].shape[1]
+        bs = cache["k"].shape[2]
         nb = block_tables.shape[1]
         pos = positions[:, 0]                       # (B,)
         bi = jnp.minimum(pos // bs, nb - 1)
         blk = jnp.take_along_axis(block_tables, bi[:, None], axis=1)[:, 0]
         off = pos % bs
         lens = jnp.minimum(pos + 1, nb * bs)
+        barrier = jax.lax.optimization_barrier
         if cfg.kv_quant:
             ki, ks = _kv_quant(k[:, 0])             # (B,KV,hd),(B,KV)
             vi, vs = _kv_quant(v[:, 0])
-            k_pool = opt_barrier(cache["k"]).at[blk, off].set(ki)
-            v_pool = opt_barrier(cache["v"]).at[blk, off].set(vi)
-            ks_pool = cache["k_scale"].at[blk, off].set(ks)
-            vs_pool = cache["v_scale"].at[blk, off].set(vs)
+            k_pool = barrier(cache["k"]).at[blk, :, off].set(ki)
+            v_pool = barrier(cache["v"]).at[blk, :, off].set(vi)
+            ks_pool = cache["k_scale"].at[blk, :, off].set(ks)
+            vs_pool = cache["v_scale"].at[blk, :, off].set(vs)
             o = decode_attention_paged_quant(
                 q[:, 0], k_pool, v_pool, ks_pool, vs_pool, block_tables,
-                lens, use_pallas=cfg.use_pallas)
+                lens, use_pallas=cfg.pallas_kernels)
             new_cache = dict(k=k_pool, v=v_pool, k_scale=ks_pool,
                              v_scale=vs_pool)
         else:
-            k_pool = opt_barrier(cache["k"]).at[blk, off].set(k[:, 0])
-            v_pool = opt_barrier(cache["v"]).at[blk, off].set(v[:, 0])
+            k_pool = barrier(cache["k"]).at[blk, :, off].set(k[:, 0])
+            v_pool = barrier(cache["v"]).at[blk, :, off].set(v[:, 0])
             o = decode_attention_paged(q[:, 0], k_pool, v_pool,
                                        block_tables, lens,
-                                       use_pallas=cfg.use_pallas)
+                                       use_pallas=cfg.pallas_kernels)
             new_cache = dict(k=k_pool, v=v_pool)
         o = o[:, None]                              # (B,1,H,hd)
     elif mode == "decode":
@@ -609,8 +596,8 @@ def attn_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
         S = cache["k"].shape[1]
         pos = positions[:, 0]                       # (B,)
         slot = pos % S                              # ring-buffer slot
-        ck = opt_barrier(cache["k"])
-        cv = opt_barrier(cache["v"])
+        ck = jax.lax.optimization_barrier(cache["k"])
+        cv = jax.lax.optimization_barrier(cache["v"])
         if cfg.kv_quant:
             ki, ks = _kv_quant(k[:, 0])             # (B,KV,hd),(B,KV)
             vi, vs = _kv_quant(v[:, 0])
@@ -620,11 +607,10 @@ def attn_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
             v_cache = upd(cv, slot, vi)
             ks_cache = upd(cache["k_scale"], slot, ks)
             vs_cache = upd(cache["v_scale"], slot, vs)
-            n_valid = jnp.minimum(pos + 1, S)
-            valid = jnp.arange(S)[None, :] < n_valid[:, None]
             o = decode_attention_quant(q[:, 0], k_cache, v_cache,
-                                       ks_cache, vs_cache, valid,
-                                       use_pallas=cfg.use_pallas)
+                                       ks_cache, vs_cache,
+                                       jnp.minimum(pos + 1, S),
+                                       use_pallas=cfg.pallas_kernels)
             new_cache = dict(k=k_cache, v=v_cache, k_scale=ks_cache,
                              v_scale=vs_cache)
         else:
@@ -632,10 +618,9 @@ def attn_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
                 ck, slot, k[:, 0])
             v_cache = jax.vmap(lambda c, i, u: c.at[i].set(u))(
                 cv, slot, v[:, 0])
-            n_valid = jnp.minimum(pos + 1, S)
-            valid = jnp.arange(S)[None, :] < n_valid[:, None]
-            o = decode_attention(q[:, 0], k_cache, v_cache, valid,
-                                 use_pallas=cfg.use_pallas)
+            o = decode_attention(q[:, 0], k_cache, v_cache,
+                                 jnp.minimum(pos + 1, S),
+                                 use_pallas=cfg.pallas_kernels)
             new_cache = dict(k=k_cache, v=v_cache)
         o = o[:, None]                              # (B,1,H,hd)
     else:
@@ -660,7 +645,7 @@ def attn_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
                       * cache["hv_scale"][..., None]).astype(v.dtype)
             k_att = jnp.concatenate([hk, k], axis=1)
             v_att = jnp.concatenate([hv, v], axis=1)
-        if cfg.use_pallas:
+        if cfg.pallas_kernels:
             from repro.kernels import ops as kops
             o = kops.flash_attention(q, k_att, v_att, causal=True,
                                      window=window)
@@ -1070,7 +1055,7 @@ def ssm_apply(cfg: ModelConfig, p, x, *, mode, cache=None):
         xi = constrain_axis(xi, cfg, 2)
         dt = constrain_axis(dt, cfg, 2)
         Y, final = ssd_chunked(xi, dt, A, Bv, Cv, cfg.ssm_chunk,
-                               use_pallas=cfg.use_pallas)
+                               use_pallas=cfg.pallas_kernels)
         Y = Y + p["D_skip"][None, None, :, None] * xi
         y = Y.reshape(B, L, di)
         if mode == "prefill":

@@ -115,7 +115,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int):
 
 def paged_cache_struct(cfg: ModelConfig, num_blocks: int, block_size: int):
     """ShapeDtypeStructs of the **paged** cache pytree: each attention
-    layer holds a shared ``(num_blocks, block_size, KV, D)`` block pool
+    layer holds a shared ``(num_blocks, KV, block_size, D)`` block pool
     instead of a per-slot arena (block 0 is the reserved trash block;
     see :mod:`repro.serving.block_pool`).  The per-slot *block tables*
     are not part of this tree — they are layer-invariant and threaded
@@ -123,7 +123,7 @@ def paged_cache_struct(cfg: ModelConfig, num_blocks: int, block_size: int):
     (no MLA / SSM / cross / sliding-window) — the serving engine
     validates this before choosing the paged layout.  With
     ``cfg.kv_quant`` the pool leaves are int8 plus fp32 ``k_scale`` /
-    ``v_scale`` planes ``(num_blocks, bs, KV)``."""
+    ``v_scale`` planes ``(num_blocks, KV, bs)``."""
     segs = []
     for seg in cfg.segments():
         unit = []
@@ -154,20 +154,20 @@ def _unit_apply(cfg: ModelConfig, unit_spec, uparams, x, positions, mode,
     # barrier: stops XLA promoting the whole stacked scan carry / cache to
     # f32 outside the loop (it hoists `convert` of loop-invariant stacks,
     # materializing layer-count-sized f32 temps)
-    x = M.opt_barrier(x)
+    x = jax.lax.optimization_barrier(x)
     aux = jnp.zeros((), jnp.float32)
     new_caches = []
     for i, spec in enumerate(unit_spec):
         lp = uparams[i]
         lc = ucache[i] if ucache is not None else None
         if spec.kind == ATTN:
-            h = M.rmsnorm(x, lp["ln1"], cfg.rms_eps, cfg.use_pallas)
+            h = M.rmsnorm(x, lp["ln1"], cfg.rms_eps, cfg.pallas_kernels)
             win = spec.sliding_window or cfg.sliding_window
             fn = M.mla_apply if cfg.mla else M.attn_apply
             att, nc = fn(cfg, lp["attn"], h, positions=positions, mode=mode,
                          cache=lc, window=win, block_tables=block_tables)
             x = x + att
-            h2 = M.rmsnorm(x, lp["ln2"], cfg.rms_eps, cfg.use_pallas)
+            h2 = M.rmsnorm(x, lp["ln2"], cfg.rms_eps, cfg.pallas_kernels)
             if spec.moe:
                 m, a = M.moe_apply(cfg, lp["mlp"], h2)
                 aux = aux + a
@@ -175,11 +175,11 @@ def _unit_apply(cfg: ModelConfig, unit_spec, uparams, x, positions, mode,
                 m = M.mlp_apply(lp["mlp"], h2, cfg)
             x = x + m
         elif spec.kind == CROSS:
-            h = M.rmsnorm(x, lp["ln1"], cfg.rms_eps, cfg.use_pallas)
+            h = M.rmsnorm(x, lp["ln1"], cfg.rms_eps, cfg.pallas_kernels)
             att, nc = M.cross_attn_apply(cfg, lp["xattn"], h, enc, mode=mode,
                                          cache=lc)
             x = x + att
-            h2 = M.rmsnorm(x, lp["ln2"], cfg.rms_eps, cfg.use_pallas)
+            h2 = M.rmsnorm(x, lp["ln2"], cfg.rms_eps, cfg.pallas_kernels)
             if spec.moe:
                 m, a = M.moe_apply(cfg, lp["mlp"], h2)
                 aux = aux + a
@@ -187,7 +187,7 @@ def _unit_apply(cfg: ModelConfig, unit_spec, uparams, x, positions, mode,
                 m = M.mlp_apply(lp["mlp"], h2, cfg)
             x = x + m
         elif spec.kind == SSM:
-            h = M.rmsnorm(x, lp["ln1"], cfg.rms_eps, cfg.use_pallas)
+            h = M.rmsnorm(x, lp["ln1"], cfg.rms_eps, cfg.pallas_kernels)
             s, nc = M.ssm_apply(cfg, lp["ssm"], h, mode=mode, cache=lc)
             x = x + s
         else:
@@ -264,7 +264,7 @@ def forward(cfg: ModelConfig, params, *, tokens=None, embeds=None,
                                   positions, mode, sc, enc, block_tables)
         aux = aux + a
         new_segs.append(nc)
-    x = M.rmsnorm(x, params["final_norm"], cfg.rms_eps, cfg.use_pallas)
+    x = M.rmsnorm(x, params["final_norm"], cfg.rms_eps, cfg.pallas_kernels)
     new_cache = tuple(new_segs) if cache is not None else None
     return x, new_cache, aux
 

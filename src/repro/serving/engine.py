@@ -194,7 +194,8 @@ class StepEvent:
       for it is invalid and will be regenerated from scratch.
     """
     uid: int
-    new_tokens: np.ndarray = _NO_TOKENS
+    new_tokens: np.ndarray = dataclasses.field(
+        default_factory=lambda: _NO_TOKENS)
     finished: bool = False
     finish_reason: Optional[str] = None
     preempted: bool = False
@@ -228,7 +229,8 @@ def _scatter_row_blocks(pool, row, blk_ids, bs: int):
         if pad:
             r = jnp.pad(r, ((0, 0), (0, pad)) + ((0, 0),) * (r.ndim - 2))
         r = r.reshape((r.shape[0], nbp, bs) + r.shape[2:])
-        return pool_leaf.at[:, blk_ids].set(r)
+        # pool blocks are KV-head-major: (n_units, nbp, KV, bs, hd)
+        return pool_leaf.at[:, blk_ids].set(jnp.moveaxis(r, 3, 2))
 
     return jax.tree_util.tree_map(scatter, pool, row)
 
@@ -260,13 +262,17 @@ class GenerationEngine:
                  chunk: int = 32, kv_layout: str = "dense",
                  block_size: int = 16, prefix_cache: bool = False,
                  mesh=None):
-        self.cfg = cfg
         # Hybrid-Engine generation layout: with a (multi-device) mesh the
         # engine consumes TP/replicated params and lays its KV cache out
         # per-device — batch rows over the `data` axis, KV length over
         # `model` where divisible (see sharding.strategy.cache_pspecs).
         # The paged block pool stays replicated (block tables are
-        # host-side); None keeps every graph single-device.
+        # host-side); None keeps every graph single-device.  GSPMD cannot
+        # partition a Mosaic kernel, so a multi-device engine runs the
+        # jnp attention (see ModelConfig.pallas_kernels).
+        if mesh is not None and mesh.size > 1 and cfg.use_pallas is None:
+            cfg = cfg.replace(use_pallas=False)
+        self.cfg = cfg
         self.mesh = mesh
         self.max_new_tokens = int(max_new_tokens)
         self.temperature = float(temperature)
@@ -499,7 +505,8 @@ class GenerationEngine:
         P0 = n_pre * bs                       # static: cached prefix rows
 
         def gather(pool_leaf):                # -> (n_units, 1, P0, KV, hd)
-            h = pool_leaf[:, prefix_ids]      # (n_units, n_pre, bs, KV, hd)
+            h = pool_leaf[:, prefix_ids]      # (n_units, n_pre, KV, bs, hd)
+            h = jnp.moveaxis(h, 3, 2)         # (n_units, n_pre, bs, KV, hd)
             return h.reshape((h.shape[0], 1, P0) + h.shape[3:])
 
         def merge(row_t, hist_t):

@@ -176,17 +176,12 @@ def cross_mesh_put(tree, shardings):
     """Place ``tree`` onto ``shardings`` that may live on a DIFFERENT
     (disjoint) device set than the inputs — the disaggregated weight
     push from the training mesh to the rollout mesh.  ``shardings=None``
-    is the single-device zero-copy case.  jax's ``device_put`` handles
-    the cross-mesh transfer directly on every backend we target; if a
-    backend refuses (committed-array placement rules vary by version),
-    fall back to a host roundtrip — slower, never wrong."""
+    is the single-device zero-copy case.  ``device_put`` moves the
+    arrays device to device; a placement it refuses is an error, not a
+    reason to copy through the host."""
     if shardings is None:
         return tree
-    try:
-        return jax.device_put(tree, shardings)
-    except Exception:
-        host = jax.tree_util.tree_map(lambda x: np.asarray(x), tree)
-        return jax.device_put(host, shardings)
+    return jax.device_put(tree, shardings)
 
 
 def shard_batch(tree, mesh: Mesh):

@@ -51,9 +51,8 @@ def test_decode_attention_kernel(B, KV, G, S, D, sb, dtype):
     kc = jax.random.normal(k2, (B, KV, S, D), dtype)
     vc = jax.random.normal(k3, (B, KV, S, D), dtype)
     nv = jax.random.randint(k4, (B,), 1, S)
-    valid = jnp.arange(S)[None] < nv[:, None]
-    o = decode_attention_fwd(q, kc, vc, valid, s_block=sb, interpret=True)
-    r = ref.decode_attention_ref(q, kc, vc, valid)
+    o = decode_attention_fwd(q, kc, vc, nv, s_block=sb, interpret=True)
+    r = ref.decode_attention_ref(q, kc, vc, nv)
     tol = 1e-4 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(r, np.float32),
@@ -86,10 +85,9 @@ def test_decode_attention_quant_kernel(B, KV, G, S, D, sb):
     kc, ks = _quant_cache(k2, (B, KV, S, D))
     vc, vs = _quant_cache(k3, (B, KV, S, D))
     nv = jax.random.randint(k4, (B,), 1, S)         # ragged lens
-    valid = jnp.arange(S)[None] < nv[:, None]
-    o = decode_attention_quant_fwd(q, kc, vc, ks, vs, valid, s_block=sb,
+    o = decode_attention_quant_fwd(q, kc, vc, ks, vs, nv, s_block=sb,
                                    interpret=True)
-    r = ref.decode_attention_quant_ref(q, kc, vc, ks, vs, valid)
+    r = ref.decode_attention_quant_ref(q, kc, vc, ks, vs, nv)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
                                rtol=1e-4, atol=1e-4)
 
@@ -106,8 +104,8 @@ def test_paged_attention_quant_kernel(B, KV, G, D, bs, nb, nblocks):
     from repro.kernels.paged_attention import paged_decode_attention_quant_fwd
     k1, k2, k3, k4, k5 = jax.random.split(KEY, 5)
     q = jax.random.normal(k1, (B, KV, G, D), jnp.float32)
-    kp, ks = _quant_cache(k2, (nblocks, bs, KV, D))
-    vp, vs = _quant_cache(k3, (nblocks, bs, KV, D))
+    kp, ks = _quant_cache(k2, (nblocks, KV, bs, D))
+    vp, vs = _quant_cache(k3, (nblocks, KV, bs, D))
     # trash block 0 as the allocator initializes it: all-zero
     kp = kp.at[0].set(0); ks = ks.at[0].set(0.0)
     vp = vp.at[0].set(0); vs = vs.at[0].set(0.0)
@@ -137,15 +135,15 @@ def test_ops_quant_adapters_match_jnp_paths():
     # model layout: (B, S, KV, D) caches, (B, S, KV) scales
     km, vm = jnp.moveaxis(kc, 2, 1), jnp.moveaxis(vc, 2, 1)
     ksm, vsm = jnp.moveaxis(ks, 2, 1), jnp.moveaxis(vs, 2, 1)
-    valid = jnp.arange(S)[None] < jnp.asarray([S, 19])[:, None]
-    o = ops.decode_attention_quant(qd, km, vm, ksm, vsm, valid)
-    r = decode_attention_quant(qd, km, vm, ksm, vsm, valid)
+    lens = jnp.asarray([S, 19])
+    o = ops.decode_attention_quant(qd, km, vm, ksm, vsm, lens)
+    r = decode_attention_quant(qd, km, vm, ksm, vsm, lens)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
                                rtol=1e-4, atol=1e-4)
 
     bs, nb, nblocks = 8, 4, 7
-    kp, ksp = _quant_cache(k2, (nblocks, bs, KV, D))
-    vp, vsp = _quant_cache(k3, (nblocks, bs, KV, D))
+    kp, ksp = _quant_cache(k2, (nblocks, KV, bs, D))
+    vp, vsp = _quant_cache(k3, (nblocks, KV, bs, D))
     tbl = jax.random.randint(k1, (B, nb), 0, nblocks)
     lens = jnp.asarray([nb * bs, 13])
     op = ops.paged_decode_attention_quant(qd, kp, vp, ksp, vsp, tbl, lens)
@@ -305,9 +303,9 @@ def test_ops_layout_adapters():
                                atol=1e-4)
 
     qd = jax.random.normal(k1, (B, H, D))
-    valid = jnp.ones((B, L), bool)
-    od = ops.decode_attention(qd, k, v, valid)
+    lens = jnp.asarray([L, L // 2])
+    od = ops.decode_attention(qd, k, v, lens)
     from repro.models.modules import decode_attention as jnp_dec
-    rd = jnp_dec(qd, k, v, valid)
+    rd = jnp_dec(qd, k, v, lens)
     np.testing.assert_allclose(np.asarray(od), np.asarray(rd), rtol=1e-4,
                                atol=1e-4)

@@ -1,10 +1,4 @@
-"""Mesh construction: the version-gated AxisType path and explicit
-DP×TP meshes.
-
-``launch.mesh._mesh`` branches on ``jax.sharding.AxisType`` (newer jax
-requires every-axis Auto to keep GSPMD auto-sharding; older jax has no
-such kwarg).  These tests pin BOTH branches with fakes so the next jax
-bump cannot silently break mesh construction on either side."""
+"""Mesh construction: all-Auto axes and explicit DP×TP meshes."""
 import jax
 import numpy as np
 import pytest
@@ -12,44 +6,14 @@ import pytest
 from repro.launch import mesh as M
 
 
-def test_axistype_absent_branch(monkeypatch):
-    """Old-jax branch: no AxisType attribute -> make_mesh must be called
-    WITHOUT axis_types (the kwarg does not exist there)."""
-    seen = {}
-    real = jax.make_mesh
-
-    def fake(shape, axes, *, devices=None, **kw):
-        seen.update(kw)
-        return real(shape, axes, devices=devices)
-
-    monkeypatch.setattr(jax, "make_mesh", fake)
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
+def test_axistype_present_branch():
+    """Every mesh axis is Auto under the installed ``AxisType``
+    (explicit-sharding axes would break the GSPMD constraints this repo
+    relies on)."""
     m = M.make_mesh(1, 1)
-    assert "axis_types" not in seen
+    assert m.axis_types == (jax.sharding.AxisType.Auto,) * 2
     assert dict(m.shape) == {"data": 1, "model": 1}
     assert m.axis_names == ("data", "model")
-
-
-def test_axistype_present_branch(monkeypatch):
-    """New-jax branch: AxisType exists -> every axis must be passed as
-    Auto (explicit-sharding axes would break the GSPMD constraints this
-    repo relies on)."""
-    real = jax.make_mesh
-    seen = {}
-
-    class FakeAxisType:
-        Auto = object()
-
-    def fake(shape, axes, *, devices=None, axis_types=None):
-        seen["axis_types"] = axis_types
-        return real(shape, axes, devices=devices)
-
-    monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType,
-                        raising=False)
-    monkeypatch.setattr(jax, "make_mesh", fake)
-    m = M.make_mesh(1, 1)
-    assert seen["axis_types"] == (FakeAxisType.Auto, FakeAxisType.Auto)
-    assert dict(m.shape) == {"data": 1, "model": 1}
 
 
 def test_make_mesh_slices_devices():

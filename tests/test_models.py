@@ -260,3 +260,40 @@ def test_chunked_loss_matches_unchunked():
                                    toks)
     np.testing.assert_allclose(np.asarray(lp), np.asarray(lp_full),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_optimization_barrier_gradients_match_custom_vjp_barrier(
+        monkeypatch):
+    """The model differentiates straight through
+    ``lax.optimization_barrier`` (weight gathers, layer-scan carries,
+    cache reads).  Its gradients must equal those of a barrier with an
+    explicit identity VJP that re-barriers the cotangent."""
+    from functools import partial
+    from repro.training.steps import lm_loss_fn
+
+    plain = jax.lax.optimization_barrier
+
+    @jax.custom_vjp
+    def vjp_barrier(x):
+        return plain(x)
+
+    vjp_barrier.defvjp(lambda x: (plain(x), None),
+                       lambda _, g: (plain(g),))
+
+    cfg = ModelConfig(name="bar", arch_type="dense", n_layers=2, d_model=32,
+                      n_heads=4, n_kv_heads=2, d_ff=64, vocab_size=64,
+                      compute_dtype="float32")
+    params = T.init_params(cfg, KEY)
+    toks = jax.random.randint(KEY, (2, 12), 0, 64)
+    batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1),
+             "mask": jnp.ones((2, 12), jnp.float32)}
+
+    def grads():
+        return jax.grad(lambda p: lm_loss_fn(cfg, p, batch)[0])(params)
+
+    g_plain = grads()
+    monkeypatch.setattr(jax.lax, "optimization_barrier", vjp_barrier)
+    g_vjp = grads()
+    for a, b in zip(jax.tree.leaves(g_plain), jax.tree.leaves(g_vjp)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-7)

@@ -1,0 +1,125 @@
+"""Ahead-of-time compiles of every Pallas kernel for a described TPU v5e.
+
+Interpret mode on the CPU accepts block shapes and kernel bodies that the
+TPU compiler refuses (tiling of the last two block dims, Mosaic vector
+layouts), so the interpret-mode parity suites cannot catch those.  Here
+each kernel is lowered and compiled for one chip of a ``v5e:2x2``
+topology that is described, not attached, at the widths of the models it
+serves: smollm-135m for attention and norms (3 KV heads, G=3, head_dim 64,
+bf16, batch 8, S=1024, paged block 16) and mamba2-370m for the SSD
+intra-chunk kernel (32 heads of 64, state 128, chunk 256).  Each test
+asserts that the kernel is in the compiled program as a
+``tpu_custom_call``.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may load the TPU library, and pytest
+workers that each import this file must all collect the same tests.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import decode_attention as dec
+from repro.kernels import flash_attention as fa
+from repro.kernels import flash_attention_bwd as fab
+from repro.kernels import ops
+from repro.kernels import paged_attention as paged
+from repro.kernels import rmsnorm as rms
+from repro.kernels import ssd_scan as ssd
+
+# smollm-135m attention widths
+B, KV, G, D, S = 8, 3, 3, 64, 1024
+L = 512                                   # train / prefill length
+BS, NB, NBLOCKS = 16, 64, 8 * 64 + 1      # paged: block, table width, pool
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(shp, dt, sharding=sharding)
+            for shp, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def test_flash_attention_fwd_compiles(one_chip):
+    _compile(lambda q, k, v: fa.flash_attention_fwd(q, k, v, q_block=256,
+                                                    k_block=512),
+             one_chip, ((B, KV, G, L, D), BF16), ((B, KV, L, D), BF16),
+             ((B, KV, L, D), BF16))
+
+
+def test_flash_attention_bwd_compiles(one_chip):
+    def bwd(q, k, v, g, lse, delta):
+        return fab.flash_attention_bwd(q, k, v, g, lse, delta, q_block=256,
+                                       k_block=256)
+
+    _compile(bwd, one_chip, ((B, KV, G, L, D), BF16), ((B, KV, L, D), BF16),
+             ((B, KV, L, D), BF16), ((B, KV, G, L, D), BF16),
+             ((B, KV, G, L), jnp.float32), ((B, KV, G, L), jnp.float32))
+
+
+def test_decode_attention_compiles(one_chip):
+    _compile(lambda q, k, v, n: dec.decode_attention_fwd(
+                 q, k, v, n, s_block=ops.decode_block(S)),
+             one_chip, ((B, KV, G, D), BF16), ((B, KV, S, D), BF16),
+             ((B, KV, S, D), BF16), ((B,), jnp.int32))
+
+
+def test_decode_attention_quant_compiles(one_chip):
+    _compile(lambda q, k, v, ks, vs, n: dec.decode_attention_quant_fwd(
+                 q, k, v, ks, vs, n, s_block=ops.decode_block(S)),
+             one_chip, ((B, KV, G, D), BF16), ((B, KV, S, D), jnp.int8),
+             ((B, KV, S, D), jnp.int8), ((B, KV, S), jnp.float32),
+             ((B, KV, S), jnp.float32), ((B,), jnp.int32))
+
+
+def test_paged_decode_attention_compiles(one_chip):
+    _compile(paged.paged_decode_attention_fwd, one_chip,
+             ((B, KV, G, D), BF16), ((NBLOCKS, KV, BS, D), BF16),
+             ((NBLOCKS, KV, BS, D), BF16), ((B, NB), jnp.int32),
+             ((B,), jnp.int32))
+
+
+def test_paged_decode_attention_quant_compiles(one_chip):
+    _compile(paged.paged_decode_attention_quant_fwd, one_chip,
+             ((B, KV, G, D), BF16), ((NBLOCKS, KV, BS, D), jnp.int8),
+             ((NBLOCKS, KV, BS, D), jnp.int8),
+             ((NBLOCKS, KV, BS), jnp.float32),
+             ((NBLOCKS, KV, BS), jnp.float32), ((B, NB), jnp.int32),
+             ((B,), jnp.int32))
+
+
+def test_rmsnorm_compiles(one_chip):
+    _compile(lambda x, w: rms.rmsnorm_fwd(x, w, row_block=256), one_chip,
+             ((B * L, 576), BF16), ((576,), jnp.float32))
+
+
+def test_ssd_intra_compiles(one_chip):
+    # mamba2-370m: d_inner 2048 = 32 heads x 64, state 128, chunk 256
+    b, nc, q, h, p, n = 2, 2, 256, 32, 64, 128
+    _compile(ssd.ssd_intra_fwd, one_chip, ((b, nc, q, h, p), BF16),
+             ((b, nc, q, h), jnp.float32), ((h,), jnp.float32),
+             ((b, nc, q, n), BF16), ((b, nc, q, n), BF16))
