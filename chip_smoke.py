@@ -22,9 +22,12 @@ KV heads, head_dim 64, vocab 49152), random weights from a seed:
 
 Four chips: 2 PPO iterations on a 2x2 DP x TP mesh against the same
 iterations on one of the four chips, and a lockstep disaggregated run
-(rollout 2 | train 2) against the sync run on the same split.  These
-programs run the jnp model (GSPMD cannot partition a Mosaic kernel) in
-fp32, and compile afresh (see ``repro.launch.compile_cache``).
+(rollout 2 | train 2) against the sync run on the same split.  The model
+runs in fp32 with the kernel choice left to ``ModelConfig.pallas_kernels``:
+the partitioned programs (sharded PPO steps, multi-device generation
+engines) must come out on the jnp code, which the phase checks, while the
+unpartitioned SFT and reward steps keep the kernels.  These processes
+compile afresh (see ``repro.launch.compile_cache``).
 
 Wall times printed are bring-up timings, not benchmark results.  The last
 line of standard output is one JSON object naming the device; the script
@@ -311,12 +314,23 @@ def server_phase(jax, cfg):
 # four chips: sharded PPO vs one chip, disaggregated lockstep vs sync
 # ---------------------------------------------------------------- #
 def _mesh_cfgs(cfg):
-    # GSPMD cannot partition Mosaic kernels, so the sharded programs run
-    # the jnp model; the one-chip reference runs the same code.  fp32
-    # compute keeps the cross-layout comparison at reduction-order noise.
-    actor = cfg.replace(n_layers=4, compute_dtype="float32",
-                        use_pallas=False)
+    # fp32 compute keeps the cross-layout comparison at reduction-order
+    # noise; use_pallas stays unset so the platform rule decides
+    actor = cfg.replace(n_layers=4, compute_dtype="float32")
     return actor, actor.replace(name=actor.name + "-critic")
+
+
+def _check_partitioned_on_jnp(trainer, where):
+    """The sharded step configs and the multi-device generation engine's
+    config must resolve to the jnp code (GSPMD cannot partition a Mosaic
+    kernel)."""
+    got = {"actor step": trainer._step_actor_cfg.pallas_kernels,
+           "critic step": trainer._step_critic_cfg.pallas_kernels,
+           "generation engine": trainer.gen_engine.cfg.pallas_kernels}
+    print(f"  {where} pallas_kernels: " + "  ".join(
+        f"{k}={v}" for k, v in got.items()))
+    check(not any(got.values()),
+          f"{where}: a partitioned config chose the Pallas kernels")
 
 
 def mesh_ppo_phase(jax):
@@ -334,7 +348,7 @@ def mesh_ppo_phase(jax):
     prompts = jax.random.randint(jax.random.PRNGKey(9), (8, 64), 0,
                                  actor.vocab_size)
 
-    def run(engine):
+    def run(engine, actor, critic):
         tr = PPOTrainer(actor_cfg=actor, critic_cfg=critic,
                         actor_params=T.init_params(actor, ks[0]),
                         critic_params=R.init_params(critic, ks[1]),
@@ -354,9 +368,13 @@ def mesh_ppo_phase(jax):
     devs = [d.id for d in mesh.devices.flat]
     print(f"  2x2 mesh devices: {devs}")
     check(len(set(devs)) == 4, "mesh: fewer than four distinct devices")
+    # the one-chip reference is pinned to the jnp code the sharded
+    # programs resolve to, so both sides compute the same math
     with jax.default_matmul_precision("highest"):
-        ref_tr, ref = run(None)
-        tr, got = run(HybridEngine(actor, mesh))
+        _, ref = run(None, actor.replace(use_pallas=False),
+                     critic.replace(use_pallas=False))
+        tr, got = run(HybridEngine(actor, mesh), actor, critic)
+    _check_partitioned_on_jnp(tr, "2x2")
     leaf = tr.actor.params["embed"]
     print(f"  actor embed: sharding={leaf.sharding.spec} "
           f"shards={len(leaf.addressable_shards)} on "
@@ -419,6 +437,10 @@ def disaggregated_phase(jax):
 
     out_s, p_s = run(None)
     out_a, p_a = run(AsyncConfig.lockstep())
+    print(f"  SFT/reward steps (unpartitioned) pallas_kernels="
+          f"{actor.pallas_kernels}")
+    for p in (p_s, p_a):
+        _check_partitioned_on_jnp(p.trainer, "split")
     same_scores = out_s["ppo_scores"] == out_a["ppo_scores"]
     same_metrics = all(
         {k: v for k, v in ms.items() if k not in wall}

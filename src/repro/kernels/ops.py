@@ -33,7 +33,9 @@ def _interpret() -> bool:
 def _tile(total: int, preferred: int, align: int) -> tuple[int, int]:
     """(block, padded_total) for one tiled axis: the whole axis when it
     fits in ``preferred``, else the largest multiple of ``align`` that
-    divides it, else the axis zero-padded up to a multiple of ``align``."""
+    divides it, else the axis zero-padded up to a multiple of ``align``.
+    A ``preferred`` below ``align`` is raised to ``align``."""
+    preferred = max(preferred, align)
     if total <= preferred:
         return total, total
     for blk in range(preferred - preferred % align, 0, -align):
@@ -224,29 +226,13 @@ def ssd_scan(X, dt, A, B, C, chunk, initial_state=None):
     return Y.astype(X.dtype), final
 
 
-def _pick_block(total: int, preferred: int) -> int:
-    """Largest divisor of ``total`` that is <= preferred."""
-    blk = min(preferred, total)
-    while total % blk:
-        blk -= 1
-    return blk
-
-
 # ===================================================================== #
 # Differentiable Pallas attention (fwd + bwd kernels, custom VJP) — the
-# TPU training path.  Grouped layout: q (B, KV, G, Lq, D), k/v
-# (B, KV, Lk, D).  ``meta`` = (causal, window, q_block, k_block,
-# q_offset, lk_valid): query row 0 sits at absolute position q_offset and
-# keys at or past lk_valid are padding.
+# TPU training path behind ``flash_attention``.  Grouped layout: q
+# (B, KV, G, Lq, D), k/v (B, KV, Lk, D).  ``meta`` = (causal, window,
+# q_block, k_block, q_offset, lk_valid): query row 0 sits at absolute
+# position q_offset and keys at or past lk_valid are padding.
 # ===================================================================== #
-def flash_attention_grouped(q, k, v, *, causal=True, window=None,
-                            q_block=256, k_block=256):
-    Lq, Lk = q.shape[3], k.shape[2]
-    meta = (bool(causal), window, _pick_block(Lq, q_block),
-            _pick_block(Lk, k_block), Lk - Lq, Lk)
-    return _flash_pallas(meta, q, k, v)
-
-
 def _fwd_with_lse(meta, q, k, v):
     """Forward kernel + lse recovery.  The fwd kernel keeps (m, l) in
     scratch; for the residual we recompute lse with the jnp oracle's

@@ -8,11 +8,14 @@ named by ``JAX_COMPILATION_CACHE_DIR`` when that is set (JAX reads the
 variable itself, and nothing here overrides it), otherwise ``.jax_cache``
 at the root of the checkout.  Tests do not call this.
 
-A process that sees more than one device compiles afresh instead: on a
-four-chip TPU v5e host, executables of the disaggregated rollout/train
-split loaded back from the cache halted the training chips at their
-launch barrier ("Invalid logical z: enhanced-barrier-parent-phase-1"),
-while the same programs compiled in-process ran and matched.
+A process that sees more than one device compiles afresh instead.  This
+is a workaround whose cause is open: on a four-chip TPU v5e host, runs of
+the disaggregated rollout/train split that could load executables back
+from the cache halted the training chips at their launch barrier
+("Invalid logical z: enhanced-barrier-parent-phase-1"), while runs with
+the cache off, or with the enhanced launch barrier off, completed.  No
+run has isolated which of the two is at fault, so the rule is kept wide
+until one does.
 """
 from __future__ import annotations
 

@@ -103,6 +103,10 @@ class ModelConfig:
     # small weight instead of partial-sum + all-reducing the large
     # activation, which is what GSPMD otherwise emits)
     weight_gather: bool = False
+    # the config's programs run partitioned over a multi-device mesh.
+    # ``batch_axes`` / ``tp_axis`` imply it; a multi-device generation
+    # engine, which places its arrays itself, sets it alone.
+    partitioned: bool = False
 
     # --- numerics / misc ---
     # int8 KV cache (beyond-paper §Perf optimization): halves the decode
@@ -138,10 +142,11 @@ class ModelConfig:
         one place the kernel/jnp choice is made.  By default the kernels
         run on a TPU and the jnp code elsewhere.  GSPMD cannot partition
         a Mosaic kernel, so a config whose activations are sharded over a
-        mesh (``batch_axes`` / ``tp_axis`` set) runs the jnp code."""
+        mesh (``partitioned``, or ``batch_axes`` / ``tp_axis`` set) runs
+        the jnp code."""
         if self.use_pallas is not None:
             return self.use_pallas
-        sharded = bool(self.batch_axes or self.tp_axis)
+        sharded = self.partitioned or bool(self.batch_axes or self.tp_axis)
         return jax.default_backend() == "tpu" and not sharded
 
     @property

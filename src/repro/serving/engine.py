@@ -267,11 +267,11 @@ class GenerationEngine:
         # per-device — batch rows over the `data` axis, KV length over
         # `model` where divisible (see sharding.strategy.cache_pspecs).
         # The paged block pool stays replicated (block tables are
-        # host-side); None keeps every graph single-device.  GSPMD cannot
-        # partition a Mosaic kernel, so a multi-device engine runs the
-        # jnp attention (see ModelConfig.pallas_kernels).
-        if mesh is not None and mesh.size > 1 and cfg.use_pallas is None:
-            cfg = cfg.replace(use_pallas=False)
+        # host-side); None keeps every graph single-device.  A
+        # multi-device engine's config is marked partitioned, which
+        # ModelConfig.pallas_kernels reads.
+        if mesh is not None and mesh.size > 1:
+            cfg = cfg.replace(partitioned=True)
         self.cfg = cfg
         self.mesh = mesh
         self.max_new_tokens = int(max_new_tokens)

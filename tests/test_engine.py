@@ -219,3 +219,15 @@ def test_hybrid_engine_factory_and_ppo_metrics():
     for k in ("gen_tok_s", "decode_steps", "gen_len", "reward_score"):
         assert k in gm
     assert gm["decode_steps"] == 4.0
+
+
+@pytest.mark.parametrize("size,partitioned", [(1, False), (4, True)])
+def test_engine_marks_multi_device_config_partitioned(size, partitioned):
+    """A generation engine on a multi-device mesh marks its config
+    partitioned, so ModelConfig.pallas_kernels keeps it on the jnp code;
+    a one-device mesh leaves the config as given."""
+    from types import SimpleNamespace
+    eng = GenerationEngine(CFG, max_new_tokens=4,
+                           mesh=SimpleNamespace(size=size))
+    assert eng.cfg.partitioned is partitioned
+    assert eng.cfg.replace(partitioned=False) == CFG

@@ -207,24 +207,37 @@ def test_ops_ssd_full_matches_jnp_path():
                                rtol=1e-4, atol=1e-4)
 
 
+def _flash_grouped(q, k, v, causal, win, qb, kb):
+    """``ops.flash_attention`` (the entry the model trains through) on
+    grouped operands: q (B, KV, G, Lq, D), k/v (B, KV, Lk, D), moved to
+    the model layout and back."""
+    B, KV, G, Lq, D = q.shape
+    qm = jnp.moveaxis(q, 3, 1).reshape(B, Lq, KV * G, D)
+    o = ops.flash_attention(qm, jnp.moveaxis(k, 2, 1), jnp.moveaxis(v, 2, 1),
+                            causal=causal, window=win, q_block=qb,
+                            k_block=kb)
+    return jnp.moveaxis(o.reshape(B, Lq, KV, G, D), 1, 3)
+
+
 @pytest.mark.parametrize("B,KV,G,Lq,Lk,D,causal,win,qb,kb", [
-    (2, 2, 2, 64, 64, 32, True, None, 32, 32),
-    (1, 1, 4, 128, 128, 64, True, 48, 64, 32),
-    (1, 2, 2, 64, 64, 32, False, None, 32, 16),
+    (2, 2, 2, 64, 64, 32, True, None, 128, 32),
+    (1, 1, 4, 128, 128, 64, True, 48, 128, 32),
+    (1, 2, 2, 64, 64, 32, False, None, 128, 16),
+    # lengths no legal tile divides: queries padded to 384 (3 tiles of
+    # 128), keys to 304 (tiles of 16); padded keys must get no weight
+    (1, 1, 2, 300, 300, 32, True, None, 128, 64),
+    (1, 2, 2, 300, 300, 16, False, None, 128, 64),
 ])
 def test_flash_bwd_kernel_matches_autodiff(B, KV, G, Lq, Lk, D, causal,
                                            win, qb, kb):
     """Pallas fwd+bwd kernels through custom_vjp == autodiff of the naive
     reference (GQA grads sum over the query-head group)."""
-    from repro.kernels.ops import flash_attention_grouped
-
     def naive_loss(q, k, v):
         o = ref.flash_attention_ref(q, k, v, causal=causal, window=win)
         return (o.astype(jnp.float32) ** 2).sum()
 
     def kernel_loss(q, k, v):
-        o = flash_attention_grouped(q, k, v, causal=causal, window=win,
-                                    q_block=qb, k_block=kb)
+        o = _flash_grouped(q, k, v, causal, win, qb, kb)
         return (o.astype(jnp.float32) ** 2).sum()
 
     k1, k2, k3 = jax.random.split(KEY, 3)
@@ -241,13 +254,16 @@ def test_flash_bwd_kernel_matches_autodiff(B, KV, G, Lq, Lk, D, causal,
 @pytest.mark.parametrize("B,KV,G,Lq,Lk,D,causal,win,qb,kb", [
     # rectangular causal (Lq < Lk): queries are the LAST Lq of Lk
     # positions — the suffix-prefill shape the prefix cache dispatches
-    (1, 2, 4, 32, 96, 32, True, None, 16, 32),
-    (2, 1, 2, 16, 80, 16, True, None, 16, 16),
+    (1, 2, 4, 32, 96, 32, True, None, 128, 32),
+    (2, 1, 2, 16, 80, 16, True, None, 128, 16),
     # ragged masks: sliding window on top of the causal offset
-    (2, 2, 2, 48, 96, 16, True, 32, 16, 32),
-    (1, 3, 2, 96, 96, 32, True, 48, 32, 32),
-    # GQA with uneven tail tiles (Lk not a multiple of kb)
-    (1, 1, 8, 32, 96, 64, True, None, 32, 64),
+    (2, 2, 2, 48, 96, 16, True, 32, 128, 32),
+    (1, 3, 2, 96, 96, 32, True, 48, 128, 32),
+    # GQA with a key length the preferred tile does not divide
+    (1, 1, 8, 32, 96, 64, True, None, 128, 64),
+    # rectangular, windowed, and both lengths padded (200 -> 256 queries,
+    # 300 -> 304 keys)
+    (1, 2, 2, 200, 300, 16, True, 64, 128, 32),
 ])
 def test_flash_bwd_kernel_gqa_ragged_grad_check(B, KV, G, Lq, Lk, D,
                                                 causal, win, qb, kb):
@@ -257,8 +273,6 @@ def test_flash_bwd_kernel_gqa_ragged_grad_check(B, KV, G, Lq, Lk, D,
     reference for dq, dk and dv — including the masked-out regions
     (grads there must be exactly zero, not garbage) and the GQA
     sum-over-group reduction into dk/dv."""
-    from repro.kernels.ops import flash_attention_grouped
-
     k1, k2, k3, k4 = jax.random.split(KEY, 4)
     q = jax.random.normal(k1, (B, KV, G, Lq, D), jnp.float32)
     k = jax.random.normal(k2, (B, KV, Lk, D), jnp.float32)
@@ -267,8 +281,7 @@ def test_flash_bwd_kernel_gqa_ragged_grad_check(B, KV, G, Lq, Lk, D,
     cot = jax.random.normal(k4, (B, KV, G, Lq, D), jnp.float32)
 
     def kernel_loss(q, k, v):
-        o = flash_attention_grouped(q, k, v, causal=causal, window=win,
-                                    q_block=qb, k_block=kb)
+        o = _flash_grouped(q, k, v, causal, win, qb, kb)
         return (o.astype(jnp.float32) * cot).sum()
 
     def naive_loss(q, k, v):
@@ -309,3 +322,18 @@ def test_ops_layout_adapters():
     rd = jnp_dec(qd, k, v, lens)
     np.testing.assert_allclose(np.asarray(od), np.asarray(rd), rtol=1e-4,
                                atol=1e-4)
+
+
+@pytest.mark.parametrize("total,preferred,align,want", [
+    (96, 256, 128, (96, 96)),        # fits: the whole axis
+    (512, 256, 128, (256, 512)),     # largest aligned divisor
+    (300, 128, 128, (128, 384)),     # no divisor: padded up to align
+    (300, 64, 8, (16, 304)),
+    (300, 32, 128, (128, 384)),      # preferred below align is raised
+    (64, 32, 128, (64, 64)),
+])
+def test_tile_picks_legal_blocks(total, preferred, align, want):
+    blk, padded = ops._tile(total, preferred, align)
+    assert (blk, padded) == want
+    assert padded % blk == 0 and padded >= total
+    assert blk == padded or blk % align == 0

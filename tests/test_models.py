@@ -297,3 +297,23 @@ def test_optimization_barrier_gradients_match_custom_vjp_barrier(
     for a, b in zip(jax.tree.leaves(g_plain), jax.tree.leaves(g_vjp)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("backend,fields,want", [
+    ("tpu", {}, True),
+    ("cpu", {}, False),
+    ("tpu", {"batch_axes": ("data",), "tp_axis": "model"}, False),
+    ("tpu", {"tp_axis": "model"}, False),
+    ("tpu", {"partitioned": True}, False),
+    ("tpu", {"use_pallas": False}, False),
+    ("cpu", {"use_pallas": True}, True),
+    ("tpu", {"partitioned": True, "use_pallas": True}, True),
+])
+def test_pallas_kernels_platform_rule(monkeypatch, backend, fields, want):
+    """Kernels on a TPU for an unpartitioned config, the jnp code
+    elsewhere and under a mesh; an explicit ``use_pallas`` wins."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = ModelConfig(name="rule", arch_type="dense", n_layers=1,
+                      d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
+                      vocab_size=64, **fields)
+    assert cfg.pallas_kernels is want
