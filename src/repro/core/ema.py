@@ -6,16 +6,20 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 
 def init(params):
     return jax.tree.map(lambda p: p.astype(jnp.float32), params)
 
 
-@jax.jit
-def update(ema, params, decay: float = 0.992):
+def _update(ema, params, decay: float = 0.992):
     return jax.tree.map(
         lambda e, p: decay * e + (1.0 - decay) * p.astype(jnp.float32),
         ema, params)
+
+
+update = jax.jit(obs.named("ema_update", _update))
 
 
 def to_params(ema, like):

@@ -19,7 +19,6 @@ analogues.
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -28,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
+from repro import obs
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.sharding import strategy as S
@@ -64,13 +64,15 @@ class HybridEngine:
             lambda p: NamedSharding(self.mesh, p), ps)
         self.train_shardings = ns(self.train_pspecs)
         self.infer_shardings = ns(self.infer_pspecs)
-        self._to_infer = jax.jit(lambda p: p,
+        self._to_infer = jax.jit(obs.named("hybrid_to_inference",
+                                           lambda p: p),
                                  out_shardings=self.infer_shardings)
-        self._to_train = jax.jit(lambda p: p,
+        self._to_train = jax.jit(obs.named("hybrid_to_train", lambda p: p),
                                  out_shardings=self.train_shardings)
         # measured (not estimated) stats of the LAST phase transition:
-        # wall time around block_until_ready plus the per-device byte
-        # delta read off the actual output arrays' shards
+        # wall time of its ``hybrid.<direction>`` span, which ends at
+        # block_until_ready, plus the per-device byte delta read off the
+        # actual output arrays' shards
         self.last_reshard_stats: dict = {}
         self._warm: set = set()        # directions already traced/compiled
 
@@ -80,11 +82,10 @@ class HybridEngine:
     def _reshard(self, fn, params, direction: str):
         in_bytes = _tree_device_bytes(params)
         first = direction not in self._warm
-        t0 = time.perf_counter()
-        with self.mesh:
+        with obs.span(f"hybrid.{direction}") as sp, self.mesh:
             out = fn(params)
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
+            jax.block_until_ready(out)
+        dt = sp.seconds
         self._warm.add(direction)
         out_bytes = _tree_device_bytes(out)
         # an all-gather materializes exactly the replica bytes the input

@@ -11,7 +11,6 @@ is the single-script experience of §2.1.
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 from typing import Optional
 
@@ -19,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import lora as LoRA
 from repro.core.hybrid_engine import HybridEngine
 from repro.core.ppo import PPOConfig, PPOTrainer
@@ -111,13 +111,13 @@ class RLHFPipeline:
         lr = schedules.cosine_warmup(st.sft_lr, st.sft_steps // 10 + 1,
                                      st.sft_steps)
         step_fn = jax.jit(partial(lm_train_step, cfg))
-        t0 = time.perf_counter()
-        for i, batch in enumerate(self.blender.sft_batches(
-                st.sft_batch, st.sft_steps)):
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            state, m = step_fn(state, batch, lr(i))
-            self.log["stage1"].append(float(m["loss"]))
-        self.timings["stage1"] = time.perf_counter() - t0
+        with obs.span("pipeline.stage1") as sp:
+            for i, batch in enumerate(self.blender.sft_batches(
+                    st.sft_batch, st.sft_steps)):
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                state, m = step_fn(state, batch, lr(i))
+                self.log["stage1"].append(float(m["loss"]))
+        self.timings["stage1"] = sp.seconds
         self.e.actor_params = state.params
         self.e.ref_params = jax.tree.map(lambda x: x, state.params)
         if self.ckpt is not None:
@@ -135,14 +135,14 @@ class RLHFPipeline:
                                      st.rm_steps)
         step_fn = jax.jit(partial(reward_train_step, cfg))
         accs = []
-        t0 = time.perf_counter()
-        for i, batch in enumerate(self.blender.reward_batches(
-                st.rm_batch, st.rm_steps)):
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            state, m = step_fn(state, batch, lr(i))
-            self.log["stage2"].append(float(m["rm_loss"]))
-            accs.append(float(m["rm_acc"]))
-        self.timings["stage2"] = time.perf_counter() - t0
+        with obs.span("pipeline.stage2") as sp:
+            for i, batch in enumerate(self.blender.reward_batches(
+                    st.rm_batch, st.rm_steps)):
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                state, m = step_fn(state, batch, lr(i))
+                self.log["stage2"].append(float(m["rm_loss"]))
+                accs.append(float(m["rm_acc"]))
+        self.timings["stage2"] = sp.seconds
         self.e.reward_params = state.params
         self.e.critic_params = jax.tree.map(lambda x: x, state.params)
         self.rm_acc = accs
@@ -181,33 +181,33 @@ class RLHFPipeline:
                                                   st.ppo_steps, skip=start)
                     if self.ppo.ptx_coef > 0 else None)
         scores = [m["reward_score"] for m in self.log["stage3"]]
-        t0 = time.perf_counter()
         elapsed = self.timings.get("stage3", 0.0) if restored else 0.0
-        if self.async_cfg is not None:
-            self._run_ppo_async(trainer, key, start, scores, ptx_iter,
-                                t0, elapsed)
-        else:
-            for i, batch in enumerate(self.blender.prompt_batches(
-                    st.ppo_batch, st.ppo_steps, skip=start), start=start):
-                if self.iter_hook is not None:
-                    self.iter_hook(i)
-                key, k = jax.random.split(key)
-                exp, gm = trainer.generate_experience(
-                    jnp.asarray(batch["prompts"]), k)
-                ptx = None
-                if ptx_iter is not None:
-                    ptx = {k2: jnp.asarray(v)
-                           for k2, v in next(ptx_iter).items()}
-                tm = trainer.train_rlhf(exp, ptx)
-                scores.append(gm["reward_score"])
-                self.log["stage3"].append({**gm, **tm})
-                if (self.ckpt is not None and self.save_every
-                        and ((i + 1) % self.save_every == 0
-                             or i == st.ppo_steps - 1)):
-                    self.timings["stage3"] = (elapsed
-                                              + time.perf_counter() - t0)
-                    self._save_ppo(trainer, key, i + 1)
-        self.timings["stage3"] = elapsed + time.perf_counter() - t0
+        with obs.span("pipeline.stage3") as sp:
+            if self.async_cfg is not None:
+                self._run_ppo_async(trainer, key, start, scores, ptx_iter,
+                                    sp, elapsed)
+            else:
+                for i, batch in enumerate(self.blender.prompt_batches(
+                        st.ppo_batch, st.ppo_steps, skip=start), start=start):
+                    if self.iter_hook is not None:
+                        self.iter_hook(i)
+                    obs.step(i)
+                    key, k = jax.random.split(key)
+                    exp, gm = trainer.generate_experience(
+                        jnp.asarray(batch["prompts"]), k)
+                    ptx = None
+                    if ptx_iter is not None:
+                        ptx = {k2: jnp.asarray(v)
+                               for k2, v in next(ptx_iter).items()}
+                    tm = trainer.train_rlhf(exp, ptx)
+                    scores.append(gm["reward_score"])
+                    self.log["stage3"].append({**gm, **tm})
+                    if (self.ckpt is not None and self.save_every
+                            and ((i + 1) % self.save_every == 0
+                                 or i == st.ppo_steps - 1)):
+                        self.timings["stage3"] = elapsed + sp.seconds
+                        self._save_ppo(trainer, key, i + 1)
+        self.timings["stage3"] = elapsed + sp.seconds
         # serving-grade generation telemetry (engine early-exit decode);
         # kept out of ``timings`` which holds seconds only
         if self.log["stage3"]:
@@ -221,7 +221,7 @@ class RLHFPipeline:
 
     # ------------------- Step 3, async (disaggregated) ------------- #
     def _run_ppo_async(self, trainer, key, start, scores, ptx_iter,
-                       t0, elapsed):
+                       stage_span, elapsed):
         """Overlapped stage 3: a free-running producer thread generates
         batch N+1 on the rollout mesh while this (consumer) thread
         scores + trains batch N on the training mesh.
@@ -260,6 +260,7 @@ class RLHFPipeline:
             for i in range(start, st.ppo_steps):
                 if self.iter_hook is not None:
                     self.iter_hook(i)
+                obs.step(i)
                 # mirror the sync PRNG carry (the producer holds the
                 # live generation chain) so checkpoints stay identical
                 key, _ = jax.random.split(key)
@@ -301,8 +302,7 @@ class RLHFPipeline:
                 if (self.ckpt is not None and self.save_every
                         and ((i + 1) % self.save_every == 0
                              or i == st.ppo_steps - 1)):
-                    self.timings["stage3"] = (elapsed
-                                              + time.perf_counter() - t0)
+                    self.timings["stage3"] = elapsed + stage_span.seconds
                     self._save_ppo(trainer, key, i + 1)
         finally:
             producer.stop()

@@ -13,7 +13,6 @@ clipped value loss for the critic, EMA collection of actor weights.
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 from typing import Any, Optional
 
@@ -24,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import ema as EMA
 from repro.core import experience as X
 from repro.core.hybrid_engine import HybridEngine
@@ -245,6 +245,15 @@ class PPOTrainer:
                                                         **gen_opts)
                                if engine is not None
                                else GenerationEngine(gen_cfg, **gen_opts))
+        # each program runs under a name of its own (``jit_<name>`` on a
+        # profiler trace's XLA Modules line)
+        a_step = obs.named("ppo_actor_step", partial(actor_step, actor_cfg,
+                                                     ppo))
+        c_step = obs.named("ppo_critic_step", partial(critic_step,
+                                                      critic_cfg, ppo))
+        self._mk_exp = jax.jit(obs.named(
+            "ppo_make_experience",
+            partial(make_experience, actor_cfg, critic_cfg, ppo)))
         if self._multi:
             # jit the PPO step AGAINST the mesh: the state pins back to
             # the training layout every step (one compile across steps —
@@ -254,21 +263,14 @@ class PPOTrainer:
             c_sh = engine.train_state_shardings(
                 self.critic_cfg, specs=R.param_specs(self.critic_cfg))
             rep = NamedSharding(self.mesh, P())
-            self._mk_exp = jax.jit(partial(make_experience, actor_cfg,
-                                           critic_cfg, ppo))
-            self._actor_step = jax.jit(partial(actor_step, actor_cfg, ppo),
-                                       out_shardings=(a_sh, rep))
-            self._critic_step = jax.jit(
-                partial(critic_step, critic_cfg, ppo),
-                out_shardings=(c_sh, rep))
+            self._actor_step = jax.jit(a_step, out_shardings=(a_sh, rep))
+            self._critic_step = jax.jit(c_step, out_shardings=(c_sh, rep))
         else:
-            self._mk_exp = jax.jit(partial(make_experience, actor_cfg,
-                                           critic_cfg, ppo))
-            self._actor_step = jax.jit(partial(actor_step, actor_cfg, ppo))
-            self._critic_step = jax.jit(partial(critic_step, critic_cfg,
-                                                ppo))
+            self._actor_step = jax.jit(a_step)
+            self._critic_step = jax.jit(c_step)
         # staleness telemetry (async mode, lag > 0 only)
-        self._guard = jax.jit(partial(staleness_guard_stats, actor_cfg))
+        self._guard = jax.jit(obs.named(
+            "ppo_staleness_guard", partial(staleness_guard_stats, actor_cfg)))
 
     # -------------------------------------------------------------- #
     def _mesh_ctx(self):
@@ -314,30 +316,31 @@ class PPOTrainer:
         sync reshard path runs (``to_inference`` on the hybrid engine,
         or a cross-mesh put when a rollout mesh is configured)."""
         from repro.core.replay import RolloutBatch
-        t0 = time.perf_counter()
-        if self.ppo.n_samples_per_prompt > 1:
-            # best-of-n on the fixed-shape path: tile each prompt row n
-            # times (rows sample independently from the shared key, so
-            # stochastic copies diverge; the request path additionally
-            # reuses each prompt's prefill via the prefix cache)
-            prompts = jnp.repeat(jnp.asarray(prompts),
-                                 self.ppo.n_samples_per_prompt, axis=0)
-        params = gen_params
-        if params is None:
-            params = self.actor.params
-            if self.rollout_mesh is not None:
-                from repro.sharding.strategy import cross_mesh_put
-                params = cross_mesh_put(params, self.publish_shardings())
-            elif self.engine is not None:
-                params = self.engine.to_inference(params)
-        out = self.gen_engine.generate(params, prompts, key)
-        jax.block_until_ready(out["sequences"])
-        gen_s = time.perf_counter() - t0
-        n_gen = float(out["response_mask"].sum())
-        gm = {"gen_len": float(out["response_mask"].sum(1).mean()),
-              "gen_tok_s": n_gen / max(gen_s, 1e-9),
-              "decode_steps": float(
-                  self.gen_engine.last_stats["decode_steps"])}
+        with obs.span("ppo.generate_rollout") as sp:
+            if self.ppo.n_samples_per_prompt > 1:
+                # best-of-n on the fixed-shape path: tile each prompt row
+                # n times (rows sample independently from the shared key,
+                # so stochastic copies diverge; the request path
+                # additionally reuses each prompt's prefill via the
+                # prefix cache)
+                prompts = jnp.repeat(jnp.asarray(prompts),
+                                     self.ppo.n_samples_per_prompt, axis=0)
+            params = gen_params
+            if params is None:
+                params = self.actor.params
+                if self.rollout_mesh is not None:
+                    from repro.sharding.strategy import cross_mesh_put
+                    params = cross_mesh_put(params,
+                                            self.publish_shardings())
+                elif self.engine is not None:
+                    params = self.engine.to_inference(params)
+            # the sampled tokens are on the host when generate returns
+            out = self.gen_engine.generate(params, prompts, key)
+        st = self.gen_engine.last_stats
+        n_gen = float(st["generated_tokens"])
+        gm = {"gen_len": n_gen / out["response_mask"].shape[0],
+              "gen_tok_s": n_gen / max(sp.seconds, 1e-9),
+              "decode_steps": float(st["decode_steps"])}
         if gen_params is None:
             self._add_reshard_metrics(gm)
         return RolloutBatch(sequences=out["sequences"],
@@ -375,12 +378,14 @@ class PPOTrainer:
                  jnp.asarray(rollout.response_mask),
                  jnp.asarray(rollout.attn_mask)))
             extra = (attn,)
-        with self._mesh_ctx():
-            exp, score = self._mk_exp(behavior, self.ref_params,
-                                      self.critic.params,
-                                      self.reward_params, seqs, mask,
-                                      *extra)
-        sm = {"reward_score": float(score.mean())}
+        with obs.span("ppo.score_rollout"):
+            with obs.span("ppo.make_experience"), self._mesh_ctx():
+                exp, score = self._mk_exp(behavior, self.ref_params,
+                                          self.critic.params,
+                                          self.reward_params, seqs, mask,
+                                          *extra)
+            with obs.span("ppo.score_read"):
+                sm = {"reward_score": float(score.mean())}
         if policy_lag is not None:
             sm["policy_lag"] = float(policy_lag)
             if policy_lag > 0:
@@ -436,15 +441,14 @@ class PPOTrainer:
         stable width for the jitted scorer.  Padding is excluded from
         the response mask and from the reward model's end-score position
         via the attention mask."""
-        t0 = time.perf_counter()
-        params = self.actor.params
-        if self.engine is not None:
-            params = self.engine.to_inference(params)
         eng = self.gen_engine
-        requests = self._expand_samples(requests)
-        outs = {c.uid: c for c in eng.serve(
-            params, requests, key, slots=min(slots, len(requests)))}
-        gen_s = time.perf_counter() - t0
+        with obs.span("ppo.generate_requests") as sp:
+            params = self.actor.params
+            if self.engine is not None:
+                params = self.engine.to_inference(params)
+            requests = self._expand_samples(requests)
+            outs = {c.uid: c for c in eng.serve(
+                params, requests, key, slots=min(slots, len(requests)))}
         # stable width across PPO iterations with a fixed budget/geometry
         W = max(len(r.tokens) + eng.resolve(r)[3] for r in requests)
         B = len(requests)
@@ -468,7 +472,7 @@ class PPOTrainer:
         exp, sm = self.score_rollout(rollout)
         gm = {**sm,
               "gen_len": float(response_mask.sum(1).mean()),
-              "gen_tok_s": n_gen / max(gen_s, 1e-9),
+              "gen_tok_s": n_gen / max(sp.seconds, 1e-9),
               "decode_steps": float(eng.last_stats["decode_steps"])}
         if "prefill_hit_rate" in eng.last_stats:     # paged engine
             gm["prefill_hit_rate"] = float(
@@ -524,14 +528,21 @@ class PPOTrainer:
         the updated TrainStates pin back to the training layout)."""
         exp = self._shard_batch(exp)
         ptx_batch = self._shard_batch(ptx_batch)
-        with self._mesh_ctx():
-            self.actor, am = self._actor_step(self.actor, exp, ptx_batch)
-            self.critic, cm = self._critic_step(self.critic, exp)
-        if self.ema is not None:
-            self.ema = EMA.update(self.ema, self.actor.params,
-                                  self.ppo.ema_decay)
-        return {**{k: float(v) for k, v in am.items()},
-                **{k: float(v) for k, v in cm.items()}}
+        with obs.span("ppo.train_rlhf"):
+            with self._mesh_ctx():
+                with obs.span("ppo.actor_step"):
+                    self.actor, am = self._actor_step(self.actor, exp,
+                                                      ptx_batch)
+                with obs.span("ppo.critic_step"):
+                    self.critic, cm = self._critic_step(self.critic, exp)
+            if self.ema is not None:
+                with obs.span("ema.update"):
+                    self.ema = EMA.update(self.ema, self.actor.params,
+                                          self.ppo.ema_decay)
+            # the host's wait for the device: the step metrics' values
+            with obs.span("ppo.train_read"):
+                return {**{k: float(v) for k, v in am.items()},
+                        **{k: float(v) for k, v in cm.items()}}
 
     def ema_params(self):
         return EMA.to_params(self.ema, self.actor.params)

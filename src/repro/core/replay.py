@@ -38,6 +38,8 @@ from typing import Any, Optional
 
 import jax
 
+from repro import obs
+
 
 class ReplayClosed(Exception):
     """The queue/publisher was closed (or cancelled) under a waiter."""
@@ -281,10 +283,10 @@ class WeightPublisher:
 
     def _push(self, params, version: int) -> dict:
         from repro.core.hybrid_engine import _tree_device_bytes
-        t0 = time.perf_counter()
-        rollout_params = self._transfer(params)
-        jax.block_until_ready(rollout_params)
-        dt = time.perf_counter() - t0
+        with obs.span("replay.publish", version=version) as sp:
+            rollout_params = self._transfer(params)
+            jax.block_until_ready(rollout_params)
+        dt = sp.seconds
         nbytes = (_tree_device_bytes(rollout_params)
                   if self._shardings is not None else 0)
         with self._cv:
@@ -484,6 +486,7 @@ class ExperienceProducer:
                                         timeout=self.cfg.publish_wait_s,
                                         stop=self._stop)
                 params, version = self.publisher.latest()
+                obs.step(i)
                 rollout, gm = self.trainer.generate_rollout(
                     jnp.asarray(batch["prompts"]), k,
                     gen_params=params, version=version)

@@ -113,6 +113,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
+from repro import obs
 from repro.models import transformer as T
 from repro.models.config import ATTN, ModelConfig
 from repro.serving.block_pool import (TRASH_BLOCK, BlockAllocator,
@@ -303,7 +304,8 @@ class GenerationEngine:
             T.paged_cache_struct(cfg, 2, self.block_size)
         self.last_stats: dict = {}
 
-        self._prefill_fixed = jax.jit(self._prefill_fixed_impl)
+        self._prefill_fixed = jax.jit(obs.named("gen_prefill",
+                                                self._prefill_fixed_impl))
         self._chunk_fns: dict = {}        # n_steps -> jitted fixed chunk
         # donate the arena + per-slot state: every caller rebinds them from
         # the return value, and without donation each dispatch memcpys the
@@ -376,7 +378,8 @@ class GenerationEngine:
                 return carry, toks, was
             # donate the whole carry (rebound every dispatch) so chunked
             # decode never memcpys the KV cache between chunks
-            self._chunk_fns[n] = jax.jit(fn, donate_argnums=(1, 2, 3, 4, 5))
+            self._chunk_fns[n] = jax.jit(obs.named("gen_decode", fn),
+                                         donate_argnums=(1, 2, 3, 4, 5))
         return self._chunk_fns[n]
 
     def generate(self, params, tokens, key, *, encoder_embeds=None):
@@ -392,47 +395,52 @@ class GenerationEngine:
                                "generated_tokens": 0}
             return {"sequences": tokens,
                     "response_mask": jnp.zeros((B, Lp), bool)}
-        logits, cache = self._prefill_fixed(params, tokens, encoder_embeds)
-        pos = jnp.full((B,), Lp, jnp.int32)
-        done = jnp.zeros((B,), bool)
-        # the chunk fns donate their whole carry; copy the caller's key so
-        # donation never invalidates an array the caller still owns
-        key = jnp.array(key, copy=True)
+        with obs.span("gen.prefill"):
+            logits, cache = self._prefill_fixed(params, tokens,
+                                                encoder_embeds)
+            pos = jnp.full((B,), Lp, jnp.int32)
+            done = jnp.zeros((B,), bool)
+            # the chunk fns donate their whole carry; copy the caller's
+            # key so donation never invalidates an array the caller owns
+            key = jnp.array(key, copy=True)
 
         # without an EOS there is nothing to exit early on — one fused
         # dispatch, no per-chunk host sync (the PPO default)
         chunk = self.chunk if self.eos_id is not None else max_new
         tok_parts, was_parts, steps = [], [], 0
-        while steps < max_new:
-            n = min(chunk, max_new - steps)
-            fn = self._fixed_chunk(n)
-            (logits, cache, key, pos, done), toks, was = fn(
-                params, logits, cache, key, pos, done, encoder_embeds)
-            tok_parts.append(np.asarray(toks))
-            was_parts.append(np.asarray(was))
-            steps += n
-            if (self.eos_id is not None and steps < max_new
-                    and bool(np.asarray(done).all())):
-                break
+        with obs.span("gen.decode"):
+            while steps < max_new:
+                n = min(chunk, max_new - steps)
+                fn = self._fixed_chunk(n)
+                (logits, cache, key, pos, done), toks, was = fn(
+                    params, logits, cache, key, pos, done, encoder_embeds)
+                tok_parts.append(np.asarray(toks))
+                was_parts.append(np.asarray(was))
+                steps += n
+                if (self.eos_id is not None and steps < max_new
+                        and bool(np.asarray(done).all())):
+                    break
+        obs.count("gen.decode_steps", steps)
 
-        gen = np.concatenate(tok_parts, axis=0).T          # (B, steps)
-        was_done = np.concatenate(was_parts, axis=0).T
-        if steps < max_new:                                # early exit: pad
-            pad = max_new - steps
-            gen = np.concatenate(
-                [gen, np.full((B, pad), self.eos_id, gen.dtype)], axis=1)
-            was_done = np.concatenate(
-                [was_done, np.ones((B, pad), bool)], axis=1)
-        sequences = np.concatenate([np.asarray(tokens), gen], axis=1)
-        mask = np.concatenate(
-            [np.zeros((B, Lp), bool), ~was_done], axis=1)
-        self.last_stats = {
-            "decode_steps": steps,
-            "scheduled_tokens": B * steps,
-            "generated_tokens": int(mask.sum()),
-        }
-        return {"sequences": jnp.asarray(sequences),
-                "response_mask": jnp.asarray(mask)}
+        with obs.span("gen.assemble"):
+            gen = np.concatenate(tok_parts, axis=0).T      # (B, steps)
+            was_done = np.concatenate(was_parts, axis=0).T
+            if steps < max_new:                            # early exit: pad
+                pad = max_new - steps
+                gen = np.concatenate(
+                    [gen, np.full((B, pad), self.eos_id, gen.dtype)], axis=1)
+                was_done = np.concatenate(
+                    [was_done, np.ones((B, pad), bool)], axis=1)
+            sequences = np.concatenate([np.asarray(tokens), gen], axis=1)
+            mask = np.concatenate(
+                [np.zeros((B, Lp), bool), ~was_done], axis=1)
+            self.last_stats = {
+                "decode_steps": steps,
+                "scheduled_tokens": B * steps,
+                "generated_tokens": int(mask.sum()),
+            }
+            return {"sequences": jnp.asarray(sequences),
+                    "response_mask": jnp.asarray(mask)}
 
     # ================================================================ #
     # admission bodies shared by both KV layouts
