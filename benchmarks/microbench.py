@@ -37,10 +37,10 @@ def run():
 
     S = 4096
     qd = jax.random.normal(key, (B, H, D))
-    kc = jax.random.normal(key, (B, S, KV, D))
-    vc = jax.random.normal(key, (B, S, KV, D))
+    kc = jax.random.normal(key, (1, B, KV, S, D))
+    vc = jax.random.normal(key, (1, B, KV, S, D))
     lens = jnp.full((B,), S, jnp.int32)
-    dec = jax.jit(lambda q, k, v, n: decode_attention(q, k, v, n))
+    dec = jax.jit(lambda q, k, v, n: decode_attention(q, k, v, n, 0))
     t = _time(dec, qd, kc, vc, lens)
     bytes_ = 2 * B * S * KV * D * 4
     rows.append(("micro_decode_attn_4k", t * 1e6,

@@ -139,37 +139,42 @@ def kernel_phase(jax, cfg):
     for name, a, b in zip(("dq", "dk", "dv"), gk, gr):
         report(f"flash_attention bwd {name}", a, b)
 
-    # dense decode, bf16 and int8
+    # dense decode, bf16 and int8: a stacked (2, B, KV, S, D) arena read at
+    # its second layer
     qd = jax.random.normal(ks[4], (B, H, D), bf16)
-    kc = jax.random.normal(ks[5], (B, S, KV, D), bf16)
-    vc = jax.random.normal(ks[6], (B, S, KV, D), bf16)
+    kc = jax.random.normal(ks[5], (2, B, KV, S, D), bf16)
+    vc = jax.random.normal(ks[6], (2, B, KV, S, D), bf16)
     lens = jax.random.randint(ks[7], (B,), 1, S + 1)
-    report("decode_attention", jax.jit(ops.decode_attention)(qd, kc, vc, lens),
-           jax.jit(M.decode_attention)(qd, kc, vc, lens))
+    layer = jnp.int32(1)
+    report("decode_attention",
+           jax.jit(ops.decode_attention)(qd, kc, vc, lens, layer),
+           jax.jit(M.decode_attention)(qd, kc, vc, lens, layer))
     ki, ksc = M._kv_quant(kc)
     vi, vsc = M._kv_quant(vc)
     report("decode_attention int8",
-           jax.jit(ops.decode_attention_quant)(qd, ki, vi, ksc, vsc, lens),
-           jax.jit(M.decode_attention_quant)(qd, ki, vi, ksc, vsc, lens))
+           jax.jit(ops.decode_attention_quant)(qd, ki, vi, ksc, vsc, lens,
+                                               layer),
+           jax.jit(M.decode_attention_quant)(qd, ki, vi, ksc, vsc, lens,
+                                             layer))
 
-    # paged decode, bf16 and int8: (nblocks, KV, bs, D) pool
+    # paged decode, bf16 and int8: a stacked (2, nblocks, KV, bs, D) pool
     nb = S // bs
     nblocks = B * nb + 1
-    kp = jax.random.normal(ks[8], (nblocks, KV, bs, D), bf16)
-    vp = jax.random.normal(ks[9], (nblocks, KV, bs, D), bf16)
+    kp = jax.random.normal(ks[8], (2, nblocks, KV, bs, D), bf16)
+    vp = jax.random.normal(ks[9], (2, nblocks, KV, bs, D), bf16)
     tbl = (1 + jax.random.permutation(ks[10], B * nb)).reshape(B, nb)
     tbl = jnp.where(jnp.arange(nb)[None] * bs < lens[:, None], tbl, 0)
     tbl = tbl.astype(jnp.int32)
     report("paged_decode_attention",
-           jax.jit(ops.paged_decode_attention)(qd, kp, vp, tbl, lens),
-           jax.jit(M.decode_attention_paged)(qd, kp, vp, tbl, lens))
+           jax.jit(ops.paged_decode_attention)(qd, kp, vp, tbl, lens, layer),
+           jax.jit(M.decode_attention_paged)(qd, kp, vp, tbl, lens, layer))
     kpi, kps = M._kv_quant(kp)
     vpi, vps = M._kv_quant(vp)
     report("paged_decode_attention int8",
            jax.jit(ops.paged_decode_attention_quant)(qd, kpi, vpi, kps, vps,
-                                                     tbl, lens),
+                                                     tbl, lens, layer),
            jax.jit(M.decode_attention_paged_quant)(qd, kpi, vpi, kps, vps,
-                                                   tbl, lens))
+                                                   tbl, lens, layer))
 
     # rmsnorm, forward and its VJP
     x = jax.random.normal(ks[11], (B, L, cfg.d_model), bf16)

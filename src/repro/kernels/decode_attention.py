@@ -12,8 +12,13 @@ one (G, D) tile so each K/V byte loaded serves all G heads (GQA's whole
 point — it multiplies effective bandwidth by G).
 
 Layout — this kernel consumes the **dense** cache layout: each sequence
-owns a contiguous per-slot slab, q: (B, KV, G, D); k/v cache:
-(B, KV, S, D); lens: (B,) int32 number of valid rows.  The valid rows of
+owns a contiguous per-slot slab of each layer, q: (B, KV, G, D); k/v
+cache: the stacked arena of a scan segment, (n, B, KV, S, D), and the
+layer index ``layer``; lens: (B,) int32 number of valid rows.  The
+decode step carries the arena through its layer scan and hands the
+kernel the whole stack: ``layer`` arrives by scalar prefetch and the K/V
+index maps select ``(layer, b, h, tile)``, so a layer's slab is read
+where it lies and never sliced out.  The valid rows of
 a slot are always a prefix of its slab (a ring buffer fills slots
 ``0..S-1`` in order and RoPE is pre-applied, so slot order is free), so
 one length per row replaces a ``(B, S)`` mask.  ``lens`` arrives by
@@ -32,8 +37,8 @@ prefetch) as its sequential grid axis instead of a contiguous S axis.
 TPU tiling: the last two dims of every block are either the array's own
 or multiples of (8, 128).  ``s_block`` must therefore divide S and be a
 multiple of 128 or S itself (see ``ops.decode_block``); the int8 scale
-planes ``(B, KV, S)`` travel as ``(1, KV, s_block)`` blocks holding every
-KV head, and the kernel reads its head's row.
+planes ``(n, B, KV, S)`` travel as ``(1, KV, s_block)`` blocks of the
+layer holding every KV head, and the kernel reads its head's row.
 """
 from __future__ import annotations
 
@@ -102,29 +107,45 @@ def decode_body(n_valid, q_ref, k_ref, v_ref, rest, *, blk, n_steps,
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
-def _kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sb, ns, quant):
+def _kernel(lens_ref, layer_ref, q_ref, k_ref, v_ref, *rest, sb, ns, quant):
     decode_body(lens_ref[pl.program_id(0)], q_ref, k_ref, v_ref, rest,
                 blk=sb, n_steps=ns, quant=quant)
 
 
-def _grid_spec(B, KV, G, D, sb, ns, extra_in_specs=()):
-    """Grid (B, KV, ns) over the dense slab; ``lens`` is the one
-    scalar-prefetch operand."""
-    def q_map(b, h, ik, lens):
-        return (b, h, 0, 0)
+def _decode_call(q, k_cache, v_cache, scales, lens, layer, s_block,
+                 interpret):
+    """Grid (B, KV, ns) over layer ``layer``'s slabs of the stacked arena;
+    ``lens`` and ``layer`` are the scalar-prefetch operands, so the index
+    maps pick the layer's tiles where they lie and no per-layer slab is
+    ever sliced out."""
+    B, KV, G, D = q.shape
+    S = k_cache.shape[3]
+    sb = min(s_block, S)
+    assert S % sb == 0, (S, sb)
+    ns = S // sb
 
-    def kv_map(b, h, ik, lens):
+    def last_tile(b, ik, lens):
         # tiles past the row's length repeat the last needed tile's index,
         # so the pipeline issues no DMA for them
-        return (b, h, jnp.minimum(ik, jnp.maximum(lens[b] - 1, 0) // sb), 0)
+        return jnp.minimum(ik, jnp.maximum(lens[b] - 1, 0) // sb)
 
-    return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+    def q_map(b, h, ik, lens, layer):
+        return (b, h, 0, 0)
+
+    def kv_map(b, h, ik, lens, layer):
+        return (layer[0], b, h, last_tile(b, ik, lens), 0)
+
+    def scale_map(b, h, ik, lens, layer):
+        return (layer[0], b, 0, last_tile(b, ik, lens))
+
+    kv_spec = pl.BlockSpec((None, 1, 1, sb, D), kv_map)
+    scale_spec = pl.BlockSpec((None, 1, KV, sb), scale_map)
+    kernel = functools.partial(_kernel, sb=sb, ns=ns, quant=bool(scales))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(B, KV, ns),
-        in_specs=[pl.BlockSpec((1, 1, G, D), q_map),
-                  pl.BlockSpec((1, 1, sb, D), kv_map),
-                  pl.BlockSpec((1, 1, sb, D), kv_map),
-                  *extra_in_specs],
+        in_specs=[pl.BlockSpec((1, 1, G, D), q_map), kv_spec, kv_spec,
+                  *[scale_spec] * len(scales)],
         out_specs=pl.BlockSpec((1, 1, G, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((G,), jnp.float32),
@@ -132,24 +153,22 @@ def _grid_spec(B, KV, G, D, sb, ns, extra_in_specs=()):
             pltpu.VMEM((G, D), jnp.float32),
         ],
     )
-
-
-def decode_attention_fwd(q, k_cache, v_cache, lens, *, s_block=512,
-                         interpret=False):
-    """q: (B, KV, G, D); k/v: (B, KV, S, D); lens: (B,) int32 valid-row
-    counts (rows ``0..lens-1`` of each slab are attended)."""
-    B, KV, G, D = q.shape
-    S = k_cache.shape[2]
-    s_block = min(s_block, S)
-    assert S % s_block == 0, (S, s_block)
-    ns = S // s_block
-    kernel = functools.partial(_kernel, sb=s_block, ns=ns, quant=False)
     return pl.pallas_call(
         kernel,
-        grid_spec=_grid_spec(B, KV, G, D, s_block, ns),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(lens.astype(jnp.int32), q, k_cache, v_cache)
+    )(lens.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q, k_cache, v_cache, *[x.astype(jnp.float32) for x in scales])
+
+
+def decode_attention_fwd(q, k_cache, v_cache, lens, layer, *, s_block=512,
+                         interpret=False):
+    """q: (B, KV, G, D); k/v: the stacked arena (n, B, KV, S, D), read at
+    layer ``layer`` (an int32 scalar); lens: (B,) int32 valid-row counts
+    (rows ``0..lens-1`` of each slab are attended)."""
+    return _decode_call(q, k_cache, v_cache, (), lens, layer, s_block,
+                        interpret)
 
 
 # ===================================================================== #
@@ -168,25 +187,9 @@ def decode_attention_fwd(q, k_cache, v_cache, lens, *, s_block=512,
 # memory-bandwidth win of int8 KV on this bandwidth-bound kernel.
 # ===================================================================== #
 def decode_attention_quant_fwd(q, k_cache, v_cache, k_scale, v_scale, lens,
-                               *, s_block=512, interpret=False):
-    """q: (B, KV, G, D) fp; k/v: (B, KV, S, D) int8; k/v_scale:
-    (B, KV, S) fp32; lens: (B,) int32 valid-row counts."""
-    B, KV, G, D = q.shape
-    S = k_cache.shape[2]
-    s_block = min(s_block, S)
-    assert S % s_block == 0, (S, s_block)
-    ns = S // s_block
-
-    def scale_map(b, h, ik, lens):
-        return (b, 0, jnp.minimum(ik, jnp.maximum(lens[b] - 1, 0) // s_block))
-
-    scale_spec = pl.BlockSpec((1, KV, s_block), scale_map)
-    kernel = functools.partial(_kernel, sb=s_block, ns=ns, quant=True)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=_grid_spec(B, KV, G, D, s_block, ns,
-                             (scale_spec, scale_spec)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(lens.astype(jnp.int32), q, k_cache, v_cache,
-      k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
+                               layer, *, s_block=512, interpret=False):
+    """q: (B, KV, G, D) fp; k/v: the stacked int8 arena (n, B, KV, S, D);
+    k/v_scale: (n, B, KV, S) fp32; all read at layer ``layer``; lens:
+    (B,) int32 valid-row counts."""
+    return _decode_call(q, k_cache, v_cache, (k_scale, v_scale), lens, layer,
+                        s_block, interpret)

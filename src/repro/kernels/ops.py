@@ -86,63 +86,64 @@ def decode_block(S: int) -> int:
     return S
 
 
-def decode_attention(q, k_cache, v_cache, lens):
-    """q: (B, H, D); caches: (B, S, KV, D); lens: (B,) valid-row counts
-    (rows ``0..lens-1`` of each slot are attended)."""
+def decode_attention(q, k_cache, v_cache, lens, layer):
+    """q: (B, H, D); caches: the stacked arena (n, B, KV, S, D), already in
+    the kernel's KV-head-major layout and read at layer ``layer``; lens:
+    (B,) valid-row counts (rows ``0..lens-1`` of each slot are
+    attended)."""
     B, H, D = q.shape
-    S, KV = k_cache.shape[1], k_cache.shape[2]
-    G = H // KV
-    q4 = q.reshape(B, KV, G, D)
-    k4 = jnp.moveaxis(k_cache, 1, 2)
-    v4 = jnp.moveaxis(v_cache, 1, 2)
-    o = _dec.decode_attention_fwd(q4, k4, v4, lens, s_block=decode_block(S),
+    KV, S = k_cache.shape[2], k_cache.shape[3]
+    q4 = q.reshape(B, KV, H // KV, D)
+    o = _dec.decode_attention_fwd(q4, k_cache, v_cache, lens, layer,
+                                  s_block=decode_block(S),
                                   interpret=_interpret())
     return o.reshape(B, H, D)
 
 
-def decode_attention_quant(q, k_cache, v_cache, k_scale, v_scale, lens):
-    """Int8-KV decode.  q: (B, H, D) fp; caches: (B, S, KV, D) int8;
-    scales: (B, S, KV) fp32; lens: (B,).  Dequant is fused into the
-    kernel's online softmax — K/V tiles cross HBM as int8 bytes."""
+def decode_attention_quant(q, k_cache, v_cache, k_scale, v_scale, lens,
+                           layer):
+    """Int8-KV decode.  q: (B, H, D) fp; caches: the stacked int8 arena
+    (n, B, KV, S, D); scales: (n, B, KV, S) fp32; read at layer
+    ``layer``; lens: (B,).  Dequant is fused into the kernel's online
+    softmax — K/V tiles cross HBM as int8 bytes."""
     B, H, D = q.shape
-    S, KV = k_cache.shape[1], k_cache.shape[2]
-    G = H // KV
-    q4 = q.reshape(B, KV, G, D)
-    k4 = jnp.moveaxis(k_cache, 1, 2)
-    v4 = jnp.moveaxis(v_cache, 1, 2)
-    ks = jnp.moveaxis(k_scale, 1, 2)
-    vs = jnp.moveaxis(v_scale, 1, 2)
-    o = _dec.decode_attention_quant_fwd(q4, k4, v4, ks, vs, lens,
+    KV, S = k_cache.shape[2], k_cache.shape[3]
+    q4 = q.reshape(B, KV, H // KV, D)
+    o = _dec.decode_attention_quant_fwd(q4, k_cache, v_cache, k_scale,
+                                        v_scale, lens, layer,
                                         s_block=decode_block(S),
                                         interpret=_interpret())
     return o.reshape(B, H, D)
 
 
 def paged_decode_attention_quant(q, k_pool, v_pool, k_scale, v_scale,
-                                 block_tables, lens):
-    """Int8-KV paged decode.  q: (B, H, D) fp; pools: (nblocks, KV, bs, D)
-    int8 consumed without a transpose; scale pools: (nblocks, KV, bs)
-    fp32 riding the same block-table indirection; block_tables: (B, nb)
+                                 block_tables, lens, layer):
+    """Int8-KV paged decode.  q: (B, H, D) fp; pools: the stacked
+    (n, nblocks, KV, bs, D) int8 pool consumed without a transpose; scale
+    pools: (n, nblocks, KV, bs) fp32 riding the same block-table
+    indirection; all read at layer ``layer``; block_tables: (B, nb)
     int32; lens: (B,) int32."""
     B, H, D = q.shape
-    KV = k_pool.shape[1]
+    KV = k_pool.shape[2]
     q4 = q.reshape(B, KV, H // KV, D)
     o = _paged.paged_decode_attention_quant_fwd(
-        q4, k_pool, v_pool, k_scale, v_scale, block_tables, lens,
+        q4, k_pool, v_pool, k_scale, v_scale, block_tables, lens, layer,
         interpret=_interpret())
     return o.reshape(B, H, D)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lens):
-    """q: (B, H, D); pools: (nblocks, KV, bs, D) — the model-side paged
-    cache layout, consumed without a transpose (the kernel's BlockSpec
-    takes one (bs, D) tile per KV head straight out of the pool);
-    block_tables: (B, nb) int32; lens: (B,) int32 valid-row counts."""
+def paged_decode_attention(q, k_pool, v_pool, block_tables, lens, layer):
+    """q: (B, H, D); pools: the stacked (n, nblocks, KV, bs, D) pool — the
+    model-side paged cache layout, consumed without a transpose (the
+    kernel's BlockSpec takes one (bs, D) tile per KV head of layer
+    ``layer`` straight out of the pool); block_tables: (B, nb) int32;
+    lens: (B,) int32 valid-row counts."""
     B, H, D = q.shape
-    KV = k_pool.shape[1]
+    KV = k_pool.shape[2]
     q4 = q.reshape(B, KV, H // KV, D)
     o = _paged.paged_decode_attention_fwd(q4, k_pool, v_pool, block_tables,
-                                          lens, interpret=_interpret())
+                                          lens, layer,
+                                          interpret=_interpret())
     return o.reshape(B, H, D)
 
 

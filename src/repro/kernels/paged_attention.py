@@ -20,8 +20,10 @@ per-sequence lengths arrive as scalar-prefetch operands, so the
 (``table[b, ib]``) before the kernel body runs — the TPU analogue of
 vLLM's PagedAttention gather.
 
-Layout: q: (B, KV, G, D); k/v pool: (nblocks, KV, bs, D) — the KV head
-axis sits ahead of the block's rows so that one grid step's tile is a
+Layout: q: (B, KV, G, D); k/v pool: the stacked pool of a scan segment,
+(n, nblocks, KV, bs, D), read at layer ``layer`` (a third scalar-prefetch
+operand, so a layer's pool is never sliced out) — the KV head axis sits
+ahead of the block's rows so that one grid step's tile is a
 ``(bs, D)`` slab whose last two dims are the array's own (the TPU tiling
 rule; a ``(bs, 1, D)`` slice of a ``(bs, KV, D)`` block is refused);
 block_tables: (B, nb) int32 (entries past a sequence's allocated prefix
@@ -41,7 +43,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.decode_attention import decode_body
 
 
-def _kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, *rest, bs, nb, quant):
+def _kernel(tbl_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref, *rest, bs, nb,
+            quant):
     decode_body(lens_ref[pl.program_id(0)], q_ref, k_ref, v_ref, rest,
                 blk=bs, n_steps=nb, quant=quant)
 
@@ -56,27 +59,28 @@ def _kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, *rest, bs, nb, quant):
 # is identical to the fp kernel; a scale tile holds every KV head's row
 # of the block and rides the same indirection.
 # ===================================================================== #
-def _paged_call(q, k_pool, v_pool, scales, block_tables, lens, interpret):
+def _paged_call(q, k_pool, v_pool, scales, block_tables, lens, layer,
+                interpret):
     B, KV, G, D = q.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     nb = block_tables.shape[1]
 
-    def q_map(b, h, ib, tbl, lens):
+    def q_map(b, h, ib, tbl, lens, layer):
         return (b, h, 0, 0)
 
-    def kv_map(b, h, ib, tbl, lens):
-        return (tbl[b, ib], h, 0, 0)
+    def kv_map(b, h, ib, tbl, lens, layer):
+        return (layer[0], tbl[b, ib], h, 0, 0)
 
-    def scale_map(b, h, ib, tbl, lens):
-        return (tbl[b, ib], 0, 0)
+    def scale_map(b, h, ib, tbl, lens, layer):
+        return (layer[0], tbl[b, ib], 0, 0)
 
     in_specs = [pl.BlockSpec((1, 1, G, D), q_map),
-                pl.BlockSpec((1, 1, bs, D), kv_map),
-                pl.BlockSpec((1, 1, bs, D), kv_map)]
-    in_specs += [pl.BlockSpec((1, KV, bs), scale_map)] * len(scales)
+                pl.BlockSpec((None, 1, 1, bs, D), kv_map),
+                pl.BlockSpec((None, 1, 1, bs, D), kv_map)]
+    in_specs += [pl.BlockSpec((None, 1, KV, bs), scale_map)] * len(scales)
     kernel = functools.partial(_kernel, bs=bs, nb=nb, quant=bool(scales))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, KV, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, G, D), q_map),
@@ -92,20 +96,23 @@ def _paged_call(q, k_pool, v_pool, scales, block_tables, lens, interpret):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lens.astype(jnp.int32),
-      q, k_pool, v_pool, *[x.astype(jnp.float32) for x in scales])
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q, k_pool, v_pool,
+      *[x.astype(jnp.float32) for x in scales])
 
 
 def paged_decode_attention_quant_fwd(q, k_pool, v_pool, k_scale, v_scale,
-                                     block_tables, lens, *, interpret=False):
-    """q: (B, KV, G, D) fp; k/v pool: (nblocks, KV, bs, D) int8;
-    k/v_scale: (nblocks, KV, bs) fp32; block_tables: (B, nb) int32;
-    lens: (B,) int32."""
-    return _paged_call(q, k_pool, v_pool, (k_scale, v_scale), block_tables,
-                       lens, interpret)
-
-
-def paged_decode_attention_fwd(q, k_pool, v_pool, block_tables, lens, *,
-                               interpret=False):
-    """q: (B, KV, G, D); k/v pool: (nblocks, KV, bs, D);
+                                     block_tables, lens, layer, *,
+                                     interpret=False):
+    """q: (B, KV, G, D) fp; k/v pool: (n, nblocks, KV, bs, D) int8;
+    k/v_scale: (n, nblocks, KV, bs) fp32; all read at layer ``layer``;
     block_tables: (B, nb) int32; lens: (B,) int32."""
-    return _paged_call(q, k_pool, v_pool, (), block_tables, lens, interpret)
+    return _paged_call(q, k_pool, v_pool, (k_scale, v_scale), block_tables,
+                       lens, layer, interpret)
+
+
+def paged_decode_attention_fwd(q, k_pool, v_pool, block_tables, lens, layer,
+                               *, interpret=False):
+    """q: (B, KV, G, D); k/v pool: (n, nblocks, KV, bs, D), read at layer
+    ``layer``; block_tables: (B, nb) int32; lens: (B,) int32."""
+    return _paged_call(q, k_pool, v_pool, (), block_tables, lens, layer,
+                       interpret)

@@ -348,10 +348,27 @@ def _flash_bwd(meta, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def decode_attention(q, k_cache, v_cache, lens, use_pallas=False):
-    """Single-token attention over a (possibly ring-buffer) KV cache.
+def _attend(q, k, v, lens):
+    """Single-token attention over one layer's (B, KV, S, D) slabs, rows
+    ``0..lens-1`` valid: the jnp decode math of every layout."""
+    B, H, D = q.shape
+    KV, S = k.shape[1], k.shape[2]
+    valid_mask = jnp.arange(S)[None, :] < lens[:, None]
+    qs = q.reshape(B, KV, H // KV, D)
+    s = jnp.einsum("bkgd,bksd->bkgs", qs, k,
+                   preferred_element_type=jnp.float32) / np.sqrt(D)
+    s = jnp.where(valid_mask[:, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bkgs,bksd->bkgd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, H, D).astype(q.dtype)
 
-    q: (B, H, D); k_cache/v_cache: (B, S, KV, D); lens: (B,) number of
+
+def decode_attention(q, k_cache, v_cache, lens, layer, use_pallas=False):
+    """Single-token attention over a (possibly ring-buffer) KV arena.
+
+    q: (B, H, D); k_cache/v_cache: the stacked arena of a scan segment,
+    (n, B, KV, S, D), read at layer ``layer``; lens: (B,) number of
     valid rows, which are always the slot prefix ``0..lens-1`` (a ring
     buffer fills its slots in order).  Returns (B, H, D).  RoPE is
     pre-applied to cached keys, so slot order inside the ring buffer is
@@ -359,23 +376,8 @@ def decode_attention(q, k_cache, v_cache, lens, use_pallas=False):
     """
     if use_pallas:
         from repro.kernels import ops as kops
-        return kops.decode_attention(q, k_cache, v_cache, lens)
-    B, H, D = q.shape
-    valid_mask = jnp.arange(k_cache.shape[1])[None, :] < lens[:, None]
-    KV = k_cache.shape[2]
-    G = H // KV
-    # barrier: stops XLA hoisting a convert(f32) of the FULL stacked
-    # per-layer cache out of the layer scan (a cache-sized f32 temp)
-    k_cache = jax.lax.optimization_barrier(k_cache)
-    v_cache = jax.lax.optimization_barrier(v_cache)
-    qs = q.reshape(B, KV, G, D)
-    s = jnp.einsum("bkgd,bskd->bkgs", qs, k_cache,
-                   preferred_element_type=jnp.float32) / np.sqrt(D)
-    s = jnp.where(valid_mask[:, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", p.astype(v_cache.dtype), v_cache,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(B, H, D).astype(q.dtype)
+        return kops.decode_attention(q, k_cache, v_cache, lens, layer)
+    return _attend(q, k_cache[layer], v_cache[layer], lens)
 
 
 # ===================================================================== #
@@ -397,12 +399,16 @@ def attn_specs(cfg: ModelConfig) -> dict:
 
 def attn_cache_shape(cfg: ModelConfig, batch: int, max_len: int,
                      window: Optional[int]):
+    """Dense arena of one layer, KV-head-major ``(batch, KV, S, D)`` like
+    the paged pool's blocks: the decode kernel reads a head's ``(S, D)``
+    rows where they lie.  With ``cfg.kv_quant`` the arena is int8 plus
+    fp32 ``k_scale`` / ``v_scale`` planes ``(batch, KV, S)``."""
     S = max_len if window is None else min(window, max_len)
-    out = dict(k=(batch, S, cfg.n_kv_heads, cfg.head_dim),
-               v=(batch, S, cfg.n_kv_heads, cfg.head_dim))
+    out = dict(k=(batch, cfg.n_kv_heads, S, cfg.head_dim),
+               v=(batch, cfg.n_kv_heads, S, cfg.head_dim))
     if cfg.kv_quant:
-        out["k_scale"] = (batch, S, cfg.n_kv_heads)
-        out["v_scale"] = (batch, S, cfg.n_kv_heads)
+        out["k_scale"] = (batch, cfg.n_kv_heads, S)
+        out["v_scale"] = (batch, cfg.n_kv_heads, S)
     return out
 
 
@@ -427,33 +433,31 @@ def paged_attn_cache_shape(cfg: ModelConfig, num_blocks: int,
 
 
 def _virtual_cache(pool, block_tables):
-    """Gather each row's blocks out of a ``(nblocks, KV, bs, ...)`` pool
-    into the dense ``(B, nb * bs, KV, ...)`` cache layout."""
+    """Gather each row's blocks out of one layer's ``(nblocks, KV, bs,
+    ...)`` pool into the dense ``(B, KV, nb * bs, ...)`` arena layout."""
     B, nb = block_tables.shape
-    g = jnp.moveaxis(pool[block_tables], 2, 3)        # (B, nb, bs, KV, ...)
-    return g.reshape((B, nb * g.shape[2]) + g.shape[3:])
+    g = jnp.moveaxis(pool[block_tables], 2, 1)        # (B, KV, nb, bs, ...)
+    return g.reshape((B, g.shape[1], nb * g.shape[3]) + g.shape[4:])
 
 
-def decode_attention_paged(q, k_pool, v_pool, block_tables, lens,
+def decode_attention_paged(q, k_pool, v_pool, block_tables, lens, layer,
                            use_pallas=False):
     """Single-token attention over a block-pooled KV cache.
 
-    q: (B, H, D); k/v pool: (nblocks, KV, bs, D); block_tables: (B, nb)
-    int32 (entries past a sequence's allocated prefix point at trash
-    block 0); lens: (B,) valid-row counts.  The jnp path gathers each
-    sequence's blocks into a dense (B, nb*bs, KV, D) virtual cache and
-    reuses the dense decode math — bit-identical to the dense arena when
-    ``nb*bs`` equals the arena length; the Pallas path walks the block
-    table directly (no gather materialization)."""
+    q: (B, H, D); k/v pool: the stacked (n, nblocks, KV, bs, D) pool,
+    read at layer ``layer``; block_tables: (B, nb) int32 (entries past a
+    sequence's allocated prefix point at trash block 0); lens: (B,)
+    valid-row counts.  The jnp path gathers each sequence's blocks into a
+    dense (B, KV, nb*bs, D) virtual cache and reuses the dense decode
+    math — bit-identical to the dense arena when ``nb*bs`` equals the
+    arena length; the Pallas path walks the block table directly (no
+    gather materialization)."""
     if use_pallas:
         from repro.kernels import ops as kops
         return kops.paged_decode_attention(q, k_pool, v_pool, block_tables,
-                                           lens)
-    k_pool = jax.lax.optimization_barrier(k_pool)
-    v_pool = jax.lax.optimization_barrier(v_pool)
-    k_virt = _virtual_cache(k_pool, block_tables)
-    v_virt = _virtual_cache(v_pool, block_tables)
-    return decode_attention(q, k_virt, v_virt, lens)
+                                           lens, layer)
+    return _attend(q, _virtual_cache(k_pool[layer], block_tables),
+                   _virtual_cache(v_pool[layer], block_tables), lens)
 
 
 def _kv_quant(x):
@@ -471,68 +475,74 @@ def _kv_quant(x):
     return xi, scale.astype(jnp.float32)
 
 
-def decode_attention_quant(q, k_i8, v_i8, k_scale, v_scale, lens,
-                           use_pallas=False):
-    """Flash-decode over an int8 KV cache: the dots consume int8 operands
+def _attend_quant(q, k_i8, v_i8, k_scale, v_scale, lens):
+    """Int8 counterpart of :func:`_attend` over one layer's (B, KV, S, D)
+    int8 slabs and (B, KV, S) scales: the dots consume int8 operands
     (XLA fuses the widening convert, so HBM traffic is the int8 bytes);
     per-slot scales are applied to the score/probability matrices, never
-    to the cache-sized tensors.  The Pallas path fuses the same algebra
-    into the online-softmax decode kernel (see
-    :func:`repro.kernels.decode_attention.decode_attention_quant_fwd`).
-
-    q: (B, H, D); k_i8/v_i8: (B, S, KV, D) int8; scales: (B, S, KV);
-    lens: (B,) valid-row counts (a slot prefix, as in
-    :func:`decode_attention`)."""
-    if use_pallas:
-        from repro.kernels import ops as kops
-        return kops.decode_attention_quant(q, k_i8, v_i8, k_scale, v_scale,
-                                           lens)
+    to the cache-sized tensors."""
     B, H, D = q.shape
-    valid_mask = jnp.arange(k_i8.shape[1])[None, :] < lens[:, None]
-    KV = k_i8.shape[2]
-    G = H // KV
-    k_i8 = jax.lax.optimization_barrier(k_i8)
-    v_i8 = jax.lax.optimization_barrier(v_i8)
-    qs = q.reshape(B, KV, G, D)
-    s = jnp.einsum("bkgd,bskd->bkgs", qs.astype(jnp.float32),
+    KV, S = k_i8.shape[1], k_i8.shape[2]
+    valid_mask = jnp.arange(S)[None, :] < lens[:, None]
+    qs = q.reshape(B, KV, H // KV, D)
+    s = jnp.einsum("bkgd,bksd->bkgs", qs.astype(jnp.float32),
                    k_i8.astype(jnp.float32)) / np.sqrt(D)
-    s = s * jnp.moveaxis(k_scale, 1, 2)[:, :, None, :]     # (B,KV,1,S)
+    s = s * k_scale[:, :, None, :]                         # (B,KV,1,S)
     s = jnp.where(valid_mask[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    pv = p * jnp.moveaxis(v_scale, 1, 2)[:, :, None, :]
-    o = jnp.einsum("bkgs,bskd->bkgd", pv, v_i8.astype(jnp.float32))
+    pv = p * v_scale[:, :, None, :]
+    o = jnp.einsum("bkgs,bksd->bkgd", pv, v_i8.astype(jnp.float32))
     return o.reshape(B, H, D).astype(q.dtype)
 
 
+def decode_attention_quant(q, k_i8, v_i8, k_scale, v_scale, lens, layer,
+                           use_pallas=False):
+    """Flash-decode over an int8 KV arena.  The Pallas path fuses the
+    algebra of :func:`_attend_quant` into the online-softmax decode
+    kernel (see
+    :func:`repro.kernels.decode_attention.decode_attention_quant_fwd`).
+
+    q: (B, H, D); k_i8/v_i8: the stacked int8 arena (n, B, KV, S, D);
+    scales: (n, B, KV, S); all read at layer ``layer``; lens: (B,)
+    valid-row counts (a slot prefix, as in :func:`decode_attention`)."""
+    if use_pallas:
+        from repro.kernels import ops as kops
+        return kops.decode_attention_quant(q, k_i8, v_i8, k_scale, v_scale,
+                                           lens, layer)
+    return _attend_quant(q, k_i8[layer], v_i8[layer], k_scale[layer],
+                         v_scale[layer], lens)
+
+
 def decode_attention_paged_quant(q, k_pool, v_pool, k_scale, v_scale,
-                                 block_tables, lens, use_pallas=False):
+                                 block_tables, lens, layer, use_pallas=False):
     """Single-token attention over an int8 block-pooled KV cache.
 
-    q: (B, H, D); k/v pool: (nblocks, KV, bs, D) int8; scale pools:
-    (nblocks, KV, bs) fp32; block_tables: (B, nb) int32; lens: (B,).
-    The jnp path gathers blocks *and their scale rows* into a dense
-    virtual cache and reuses :func:`decode_attention_quant` — bit-
-    identical to the dense int8 arena when ``nb*bs`` equals the arena
-    length; the Pallas path walks the block table with dequant fused
-    into the online softmax (no gather, no fp materialization)."""
+    q: (B, H, D); k/v pool: the stacked (n, nblocks, KV, bs, D) int8
+    pool; scale pools: (n, nblocks, KV, bs) fp32; all read at layer
+    ``layer``; block_tables: (B, nb) int32; lens: (B,).  The jnp path
+    gathers blocks *and their scale rows* into a dense virtual cache and
+    reuses the dense int8 math — bit-identical to the dense int8 arena
+    when ``nb*bs`` equals the arena length; the Pallas path walks the
+    block table with dequant fused into the online softmax (no gather,
+    no fp materialization)."""
     if use_pallas:
         from repro.kernels import ops as kops
         return kops.paged_decode_attention_quant(
-            q, k_pool, v_pool, k_scale, v_scale, block_tables, lens)
-    k_pool = jax.lax.optimization_barrier(k_pool)
-    v_pool = jax.lax.optimization_barrier(v_pool)
-    return decode_attention_quant(
-        q, _virtual_cache(k_pool, block_tables),
-        _virtual_cache(v_pool, block_tables),
-        _virtual_cache(k_scale, block_tables),
-        _virtual_cache(v_scale, block_tables), lens)
+            q, k_pool, v_pool, k_scale, v_scale, block_tables, lens, layer)
+    return _attend_quant(
+        q, *(_virtual_cache(x[layer], block_tables)
+             for x in (k_pool, v_pool, k_scale, v_scale)), lens)
 
 
 def attn_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
-               window=None, block_tables=None):
+               window=None, block_tables=None, layer=None):
     """mode: 'full' (train / full prefill) | 'prefill' (also fills cache) |
     'decode' (x is (B,1,D), cache holds history).
 
+    In decode, ``cache`` is the stacked cache of the whole scan segment
+    and ``layer`` this layer's index into it: the new K/V row is written
+    at ``[layer, ...]`` where the stack lies and the decode kernel reads
+    the layer there, so no slab of it is sliced out or copied.
     ``block_tables`` selects the **paged** decode path: ``cache`` is then
     the shared block pool (see :func:`paged_attn_cache_shape`) and each
     row's KV is read/written through its block table instead of a dense
@@ -551,78 +561,56 @@ def attn_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
     k = apply_rope(k, positions, cfg.rope_theta)
 
     new_cache = None
-    if mode == "decode" and block_tables is not None:
-        # paged: write the new KV row at (table[pos // bs], pos % bs) and
-        # attend through the block table.  Rows past a slot's allocated
-        # prefix resolve to the trash block (table padding = 0); with a
-        # FULLY allocated table the clamped index instead wraps post-EOS
-        # writes into the slot's own last block — dead for decode (a
-        # finished slot's output is masked until harvest), and the
-        # prefix cache never indexes that last block, so its possibly
-        # stale rows are never reused as a cached prefix.
-        assert cache is not None
-        assert window is None, \
-            "paged KV supports full-context GQA only"
-        bs = cache["k"].shape[2]
-        nb = block_tables.shape[1]
+    if mode == "decode":
+        assert cache is not None and layer is not None
         pos = positions[:, 0]                       # (B,)
-        bi = jnp.minimum(pos // bs, nb - 1)
-        blk = jnp.take_along_axis(block_tables, bi[:, None], axis=1)[:, 0]
-        off = pos % bs
-        lens = jnp.minimum(pos + 1, nb * bs)
-        barrier = jax.lax.optimization_barrier
-        if cfg.kv_quant:
-            ki, ks = _kv_quant(k[:, 0])             # (B,KV,hd),(B,KV)
-            vi, vs = _kv_quant(v[:, 0])
-            k_pool = barrier(cache["k"]).at[blk, :, off].set(ki)
-            v_pool = barrier(cache["v"]).at[blk, :, off].set(vi)
-            ks_pool = cache["k_scale"].at[blk, :, off].set(ks)
-            vs_pool = cache["v_scale"].at[blk, :, off].set(vs)
-            o = decode_attention_paged_quant(
-                q[:, 0], k_pool, v_pool, ks_pool, vs_pool, block_tables,
-                lens, use_pallas=cfg.pallas_kernels)
-            new_cache = dict(k=k_pool, v=v_pool, k_scale=ks_pool,
-                             v_scale=vs_pool)
+        if block_tables is not None:
+            # paged: write the new KV row at (table[pos // bs], pos % bs)
+            # and attend through the block table.  Rows past a slot's
+            # allocated prefix resolve to the trash block (table padding
+            # = 0); with a FULLY allocated table the clamped index instead
+            # wraps post-EOS writes into the slot's own last block — dead
+            # for decode (a finished slot's output is masked until
+            # harvest), and the prefix cache never indexes that last
+            # block, so its possibly stale rows are never reused as a
+            # cached prefix.
+            assert window is None, \
+                "paged KV supports full-context GQA only"
+            bs = cache["k"].shape[3]
+            nb = block_tables.shape[1]
+            bi = jnp.minimum(pos // bs, nb - 1)
+            row = jnp.take_along_axis(block_tables, bi[:, None], axis=1)[:, 0]
+            col = pos % bs
+            lens = jnp.minimum(pos + 1, nb * bs)
         else:
-            k_pool = barrier(cache["k"]).at[blk, :, off].set(k[:, 0])
-            v_pool = barrier(cache["v"]).at[blk, :, off].set(v[:, 0])
-            o = decode_attention_paged(q[:, 0], k_pool, v_pool,
-                                       block_tables, lens,
-                                       use_pallas=cfg.pallas_kernels)
-            new_cache = dict(k=k_pool, v=v_pool)
-        o = o[:, None]                              # (B,1,H,hd)
-    elif mode == "decode":
-        assert cache is not None
-        S = cache["k"].shape[1]
-        pos = positions[:, 0]                       # (B,)
-        slot = pos % S                              # ring-buffer slot
-        ck = jax.lax.optimization_barrier(cache["k"])
-        cv = jax.lax.optimization_barrier(cache["v"])
+            # dense: row b's entry for position t lives at slot t % S of
+            # its slab (a ring buffer under a sliding window)
+            S = cache["k"].shape[3]
+            row, col = jnp.arange(B), pos % S
+            lens = jnp.minimum(pos + 1, S)
+        # one index per (row, head): the update window is the minor head
+        # dim alone, so the stack keeps the row-major layout the decode
+        # kernel reads (a (KV, D) window makes the TPU compiler lay the
+        # stack out KV-minor and copy it back for the kernel every layer)
+        heads = jnp.arange(KV)[None, :]
+
+        def put(a, u):
+            return a.at[layer, row[:, None], heads, col[:, None]].set(
+                u, unique_indices=block_tables is None)
+
+        rows = dict(k=k[:, 0], v=v[:, 0])           # (B, KV, hd) each
         if cfg.kv_quant:
-            ki, ks = _kv_quant(k[:, 0])             # (B,KV,hd),(B,KV)
-            vi, vs = _kv_quant(v[:, 0])
-            upd = lambda c, i, u: jax.vmap(
-                lambda cc, ii, uu: cc.at[ii].set(uu))(c, i, u)
-            k_cache = upd(ck, slot, ki)
-            v_cache = upd(cv, slot, vi)
-            ks_cache = upd(cache["k_scale"], slot, ks)
-            vs_cache = upd(cache["v_scale"], slot, vs)
-            o = decode_attention_quant(q[:, 0], k_cache, v_cache,
-                                       ks_cache, vs_cache,
-                                       jnp.minimum(pos + 1, S),
-                                       use_pallas=cfg.pallas_kernels)
-            new_cache = dict(k=k_cache, v=v_cache, k_scale=ks_cache,
-                             v_scale=vs_cache)
+            rows["k"], rows["k_scale"] = _kv_quant(k[:, 0])
+            rows["v"], rows["v_scale"] = _kv_quant(v[:, 0])
+        new_cache = {n: put(cache[n], r) for n, r in rows.items()}
+        if block_tables is None:
+            fn = decode_attention_quant if cfg.kv_quant else decode_attention
         else:
-            k_cache = jax.vmap(lambda c, i, u: c.at[i].set(u))(
-                ck, slot, k[:, 0])
-            v_cache = jax.vmap(lambda c, i, u: c.at[i].set(u))(
-                cv, slot, v[:, 0])
-            o = decode_attention(q[:, 0], k_cache, v_cache,
-                                 jnp.minimum(pos + 1, S),
-                                 use_pallas=cfg.pallas_kernels)
-            new_cache = dict(k=k_cache, v=v_cache)
-        o = o[:, None]                              # (B,1,H,hd)
+            fn = partial(decode_attention_paged_quant if cfg.kv_quant
+                         else decode_attention_paged,
+                         block_tables=block_tables)
+        o = fn(q[:, 0], *new_cache.values(), lens=lens, layer=layer,
+               use_pallas=cfg.pallas_kernels)[:, None]   # (B,1,H,hd)
     else:
         # prefix-cache suffix prefill: the cache dict may carry a
         # read-only KV history ("hk"/"hv", gathered from shared pool
@@ -654,32 +642,21 @@ def attn_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
                                 qpos0=k_att.shape[1] - q.shape[1])
         if mode == "prefill":
             assert cache is not None
-            S = cache["k"].shape[1]
+            S = cache["k"].shape[2]
             L = k.shape[1]
-            kq, vq, ksq, vsq = k, v, None, None
+            rows = dict(k=k, v=v)
             if cfg.kv_quant:
-                kq, ksq = _kv_quant(k)
-                vq, vsq = _kv_quant(v)
-            if L <= S:
-                k_cache = cache["k"].at[:, :L].set(kq)
-                v_cache = cache["v"].at[:, :L].set(vq)
-            else:                                   # keep last S (window)
-                # ring layout: entry for pos t lives at slot t % S
-                t0 = L - S
-                roll = (-t0) % S
-                k_cache = jnp.roll(kq[:, -S:], shift=-roll, axis=1)
-                v_cache = jnp.roll(vq[:, -S:], shift=-roll, axis=1)
-            new_cache = dict(k=k_cache, v=v_cache)
-            if cfg.kv_quant:
-                if L <= S:
-                    new_cache["k_scale"] = cache["k_scale"].at[:, :L].set(
-                        ksq)
-                    new_cache["v_scale"] = cache["v_scale"].at[:, :L].set(
-                        vsq)
-                else:
-                    roll = (-(L - S)) % S
-                    new_cache["k_scale"] = jnp.roll(ksq[:, -S:], -roll, 1)
-                    new_cache["v_scale"] = jnp.roll(vsq[:, -S:], -roll, 1)
+                rows["k"], rows["k_scale"] = _kv_quant(k)
+                rows["v"], rows["v_scale"] = _kv_quant(v)
+            # one transpose into the arena's KV-head-major layout; the
+            # entry for position t lives at slot t % S, so past S (a
+            # sliding window) the last S rows are rolled into place
+            new_cache = {}
+            for name, r in rows.items():
+                r = jnp.swapaxes(r, 1, 2)           # (B, KV, L, ...)
+                new_cache[name] = (
+                    cache[name].at[:, :, :L].set(r) if L <= S else
+                    jnp.roll(r[:, :, -S:], (L - S) % S, axis=2))
     out = o.reshape(B, -1, H * hd) @ wgather(p["wo"], cfg,
                                             ("heads", "embed"))
     return out, new_cache

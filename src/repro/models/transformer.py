@@ -3,10 +3,13 @@
 ``param_specs(cfg)`` is the single source of truth for shapes + logical
 sharding axes; ``init_params`` materializes it; ``forward`` runs any of the
 three phases (``full`` train/eval, ``prefill``, ``decode``) with KV/SSM
-caches threaded *through* the layer scan so depth never unrolls in HLO.
+caches threaded *through* the layer scan so depth never unrolls in HLO:
+prefill scans them as ``xs`` -> ``ys``, decode carries the stacked caches
+so a step writes one row per layer where the stack lies.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -149,8 +152,28 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int):
 # ===================================================================== #
 # Forward
 # ===================================================================== #
+def _in_place(cfg: ModelConfig, spec) -> bool:
+    """Whether decode reads and writes this layer's cache where it lies in
+    the carried stack: GQA attention arenas and paged pools.  MLA latents,
+    SSM state and cross-attention K/V go through a slice of the stack."""
+    return spec.kind == ATTN and not cfg.mla
+
+
+def decode_layer_split(cfg: ModelConfig) -> tuple[int, int]:
+    """(layers whose cache decode reads and writes in place, layers whose
+    cache decode slices out and writes back)."""
+    n_in = n_all = 0
+    for seg in cfg.segments():
+        n_in += seg.n_units * sum(_in_place(cfg, ls) for ls in seg.unit_spec)
+        n_all += seg.n_units * len(seg.unit_spec)
+    return n_in, n_all - n_in
+
+
 def _unit_apply(cfg: ModelConfig, unit_spec, uparams, x, positions, mode,
-                ucache, enc, block_tables=None):
+                ucache, enc, block_tables=None, layer=None):
+    """One scan unit.  In decode ``ucache`` holds the segment's stacked
+    caches and ``layer`` the unit's index into them (see
+    :func:`_segment_apply`); otherwise ``ucache`` is this unit's own."""
     # barrier: stops XLA promoting the whole stacked scan carry / cache to
     # f32 outside the loop (it hoists `convert` of loop-invariant stacks,
     # materializing layer-count-sized f32 temps)
@@ -160,10 +183,16 @@ def _unit_apply(cfg: ModelConfig, unit_spec, uparams, x, positions, mode,
     for i, spec in enumerate(unit_spec):
         lp = uparams[i]
         lc = ucache[i] if ucache is not None else None
+        sliced = layer is not None and not _in_place(cfg, spec)
+        if sliced:
+            lc = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False),
+                lc)
         if spec.kind == ATTN:
             h = M.rmsnorm(x, lp["ln1"], cfg.rms_eps, cfg.pallas_kernels)
             win = spec.sliding_window or cfg.sliding_window
-            fn = M.mla_apply if cfg.mla else M.attn_apply
+            fn = M.mla_apply if cfg.mla else partial(M.attn_apply,
+                                                     layer=layer)
             att, nc = fn(cfg, lp["attn"], h, positions=positions, mode=mode,
                          cache=lc, window=win, block_tables=block_tables)
             x = x + att
@@ -193,12 +222,34 @@ def _unit_apply(cfg: ModelConfig, unit_spec, uparams, x, positions, mode,
         else:
             raise ValueError(spec.kind)
         x = M.constrain_batch(x, cfg.batch_axes)
+        if sliced:
+            nc = jax.tree.map(
+                lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, layer,
+                                                                 0),
+                ucache[i], nc)
         new_caches.append(nc if nc is not None else {})
     return x, aux, tuple(new_caches)
 
 
 def _segment_apply(cfg: ModelConfig, seg: Segment, sparams, x, positions,
                    mode, scache, enc, block_tables=None):
+    if mode == "decode":
+        # the segment's stacked caches ride in the scan carry, not in
+        # xs -> ys: a layer writes its new row into the stack and reads it
+        # there, so a step moves one row per layer, never a whole slab or
+        # the whole stack (see _in_place for the layers that still slice)
+        def step(carry, xs):
+            xc, aux, sc = carry
+            up, li = xs
+            xc, a, sc = _unit_apply(cfg, seg.unit_spec, up, xc, positions,
+                                    mode, sc, enc, block_tables, layer=li)
+            return (xc, aux + a, sc), None
+
+        (x, aux, scache), _ = jax.lax.scan(
+            step, (x, jnp.zeros((), jnp.float32), scache),
+            (sparams, jnp.arange(seg.n_units, dtype=jnp.int32)))
+        return x, aux, scache
+
     has_cache = scache is not None
 
     def body(carry, xs):

@@ -217,7 +217,7 @@ def _pad_bucket(tokens: np.ndarray, width: int) -> np.ndarray:
 
 
 def _scatter_row_blocks(pool, row, blk_ids, bs: int):
-    """Scatter a single-row prefill cache (leaves ``(n_units, 1, Lb,
+    """Scatter a single-row prefill cache (leaves ``(n_units, 1, KV, Lb,
     ...)``) block-wise into the paged pool at ``blk_ids`` — the one
     pool-write primitive shared by both paged admission paths.  Rows
     past the last real block land in the trash entries ``blk_ids`` is
@@ -225,13 +225,14 @@ def _scatter_row_blocks(pool, row, blk_ids, bs: int):
     nbp = blk_ids.shape[0]
 
     def scatter(pool_leaf, row_leaf):
-        r = row_leaf[:, 0]                    # (n_units, Lb, KV, hd)
-        pad = nbp * bs - r.shape[1]
+        r = row_leaf[:, 0]                    # (n_units, KV, Lb, ...)
+        pad = nbp * bs - r.shape[2]
         if pad:
-            r = jnp.pad(r, ((0, 0), (0, pad)) + ((0, 0),) * (r.ndim - 2))
-        r = r.reshape((r.shape[0], nbp, bs) + r.shape[2:])
-        # pool blocks are KV-head-major: (n_units, nbp, KV, bs, hd)
-        return pool_leaf.at[:, blk_ids].set(jnp.moveaxis(r, 3, 2))
+            r = jnp.pad(r, ((0, 0), (0, 0), (0, pad))
+                        + ((0, 0),) * (r.ndim - 3))
+        r = r.reshape(r.shape[:2] + (nbp, bs) + r.shape[3:])
+        # pool blocks are KV-head-major too: (n_units, nbp, KV, bs, ...)
+        return pool_leaf.at[:, blk_ids].set(jnp.moveaxis(r, 2, 1))
 
     return jax.tree_util.tree_map(scatter, pool, row)
 
@@ -408,12 +409,15 @@ class GenerationEngine:
         # dispatch, no per-chunk host sync (the PPO default)
         chunk = self.chunk if self.eos_id is not None else max_new
         tok_parts, was_parts, steps = [], [], 0
+        n_in, n_sliced = T.decode_layer_split(self.cfg)
         with obs.span("gen.decode"):
             while steps < max_new:
                 n = min(chunk, max_new - steps)
                 fn = self._fixed_chunk(n)
                 (logits, cache, key, pos, done), toks, was = fn(
                     params, logits, cache, key, pos, done, encoder_embeds)
+                obs.count("gen.decode.inplace_layers", n_in)
+                obs.count("gen.decode.copied_layers", n_sliced)
                 tok_parts.append(np.asarray(toks))
                 was_parts.append(np.asarray(was))
                 steps += n

@@ -218,17 +218,17 @@ def cache_pspecs(cache_struct_tree, mesh: Mesh, batch: int):
     bshard = (dp[0] if len(dp) == 1 else dp) if (
         dp and batch % _mesh_size(mesh, dp) == 0) else None
 
+    def length(n):
+        return "model" if n % mesh.shape["model"] == 0 else None
+
     def leaf(path, s):
         key = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
         shape = s.shape
-        if key in ("k_scale", "v_scale"):          # (u, B, S, KV)
-            s_ok = shape[2] % mesh.shape["model"] == 0
-            return P(None, bshard, "model" if s_ok else None, None)
-        if key in ("k", "v", "ckv", "krope"):
-            s_ok = shape[2] % mesh.shape["model"] == 0
-            rest = len(shape) - 3
-            return P(None, bshard, "model" if s_ok else None,
-                     *([None] * rest))
+        if key in ("k", "v", "k_scale", "v_scale"):  # (u, B, KV, S, ...)
+            return P(None, bshard, None, length(shape[3]),
+                     *([None] * (len(shape) - 4)))
+        if key in ("ckv", "krope"):                  # (u, B, S, r)
+            return P(None, bshard, length(shape[2]), None)
         if key == "conv":
             c_ok = shape[3] % mesh.shape["model"] == 0
             return P(None, bshard, None, "model" if c_ok else None)

@@ -38,6 +38,16 @@ def test_flash_attention_kernel(B, KV, G, Lq, Lk, D, causal, win, qb, kb,
                                rtol=tol, atol=tol)
 
 
+N_LAYERS = 3
+
+
+def _lens_cases(B, S, sb, key):
+    """Ragged valid-row counts, then the edges of the length axis: one row,
+    a full tile, and the whole slab."""
+    return [jax.random.randint(key, (B,), 1, S + 1)] + [
+        jnp.full((B,), n, jnp.int32) for n in (1, sb, S)]
+
+
 @pytest.mark.parametrize("B,KV,G,S,D,sb", [
     (2, 2, 2, 128, 32, 64),
     (1, 4, 1, 64, 64, 32),
@@ -46,17 +56,22 @@ def test_flash_attention_kernel(B, KV, G, Lq, Lk, D, causal, win, qb, kb,
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_kernel(B, KV, G, S, D, sb, dtype):
+    """The layer-indexed kernel reads every layer of a stacked arena where
+    it lies, with ragged lengths and lengths at 1, at a tile boundary and
+    at S."""
     k1, k2, k3, k4 = jax.random.split(KEY, 4)
     q = jax.random.normal(k1, (B, KV, G, D), dtype)
-    kc = jax.random.normal(k2, (B, KV, S, D), dtype)
-    vc = jax.random.normal(k3, (B, KV, S, D), dtype)
-    nv = jax.random.randint(k4, (B,), 1, S)
-    o = decode_attention_fwd(q, kc, vc, nv, s_block=sb, interpret=True)
-    r = ref.decode_attention_ref(q, kc, vc, nv)
+    kc = jax.random.normal(k2, (N_LAYERS, B, KV, S, D), dtype)
+    vc = jax.random.normal(k3, (N_LAYERS, B, KV, S, D), dtype)
     tol = 1e-4 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(r, np.float32),
-                               rtol=tol, atol=tol)
+    for layer in range(N_LAYERS):
+        for nv in _lens_cases(B, S, sb, k4):
+            o = decode_attention_fwd(q, kc, vc, nv, jnp.int32(layer),
+                                     s_block=sb, interpret=True)
+            r = ref.decode_attention_ref(q, kc[layer], vc[layer], nv)
+            np.testing.assert_allclose(np.asarray(o, np.float32),
+                                       np.asarray(r, np.float32),
+                                       rtol=tol, atol=tol)
 
 
 # ------------------------------------------------------------------ #
@@ -82,14 +97,17 @@ def test_decode_attention_quant_kernel(B, KV, G, S, D, sb):
     from repro.kernels.decode_attention import decode_attention_quant_fwd
     k1, k2, k3, k4 = jax.random.split(KEY, 4)
     q = jax.random.normal(k1, (B, KV, G, D), jnp.float32)
-    kc, ks = _quant_cache(k2, (B, KV, S, D))
-    vc, vs = _quant_cache(k3, (B, KV, S, D))
-    nv = jax.random.randint(k4, (B,), 1, S)         # ragged lens
-    o = decode_attention_quant_fwd(q, kc, vc, ks, vs, nv, s_block=sb,
-                                   interpret=True)
-    r = ref.decode_attention_quant_ref(q, kc, vc, ks, vs, nv)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(r),
-                               rtol=1e-4, atol=1e-4)
+    kc, ks = _quant_cache(k2, (N_LAYERS, B, KV, S, D))
+    vc, vs = _quant_cache(k3, (N_LAYERS, B, KV, S, D))
+    for layer in range(N_LAYERS):
+        for nv in _lens_cases(B, S, sb, k4):
+            o = decode_attention_quant_fwd(q, kc, vc, ks, vs, nv,
+                                           jnp.int32(layer), s_block=sb,
+                                           interpret=True)
+            r = ref.decode_attention_quant_ref(q, kc[layer], vc[layer],
+                                               ks[layer], vs[layer], nv)
+            np.testing.assert_allclose(np.asarray(o), np.asarray(r),
+                                       rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("B,KV,G,D,bs,nb,nblocks", [
@@ -114,8 +132,11 @@ def test_paged_attention_quant_kernel(B, KV, G, D, bs, nb, nblocks):
     # entries past each sequence's allocated prefix -> trash block
     nb_used = -(-lens[:, None] // bs)               # ceil-div, (B,1)
     tbl = jnp.where(jnp.arange(nb)[None] < nb_used, tbl, 0)
-    o = paged_decode_attention_quant_fwd(q, kp, vp, ks, vs, tbl, lens,
-                                         interpret=True)
+    # the pool as the second layer of a stacked pool, read where it lies
+    stack = lambda x: jnp.stack([jnp.zeros_like(x), x])
+    o = paged_decode_attention_quant_fwd(
+        q, stack(kp), stack(vp), stack(ks), stack(vs), tbl, lens,
+        jnp.int32(1), interpret=True)
     r = ref.paged_decode_attention_quant_ref(q, kp, vp, ks, vs, tbl, lens)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r),
                                rtol=1e-4, atol=1e-4)
@@ -123,33 +144,35 @@ def test_paged_attention_quant_kernel(B, KV, G, D, bs, nb, nblocks):
 
 def test_ops_quant_adapters_match_jnp_paths():
     """ops.decode_attention_quant / paged_decode_attention_quant accept
-    model-layout tensors and match the jnp model paths in
+    the model's stacked caches and match the jnp model paths in
     repro.models.modules (the use_pallas dispatch contract)."""
     from repro.models.modules import (decode_attention_paged_quant,
                                       decode_attention_quant)
     k1, k2, k3 = jax.random.split(KEY, 3)
     B, H, KV, D, S = 2, 4, 2, 16, 32
     qd = jax.random.normal(k1, (B, H, D))
-    kc, ks = _quant_cache(k2, (B, KV, S, D))
-    vc, vs = _quant_cache(k3, (B, KV, S, D))
-    # model layout: (B, S, KV, D) caches, (B, S, KV) scales
-    km, vm = jnp.moveaxis(kc, 2, 1), jnp.moveaxis(vc, 2, 1)
-    ksm, vsm = jnp.moveaxis(ks, 2, 1), jnp.moveaxis(vs, 2, 1)
+    # model layout: a stacked (n, B, KV, S, D) arena, (n, B, KV, S) scales
+    kc, ks = _quant_cache(k2, (2, B, KV, S, D))
+    vc, vs = _quant_cache(k3, (2, B, KV, S, D))
     lens = jnp.asarray([S, 19])
-    o = ops.decode_attention_quant(qd, km, vm, ksm, vsm, lens)
-    r = decode_attention_quant(qd, km, vm, ksm, vsm, lens)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(r),
-                               rtol=1e-4, atol=1e-4)
+    for layer in range(2):
+        o = ops.decode_attention_quant(qd, kc, vc, ks, vs, lens, layer)
+        r = decode_attention_quant(qd, kc, vc, ks, vs, lens, layer)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(r),
+                                   rtol=1e-4, atol=1e-4)
 
     bs, nb, nblocks = 8, 4, 7
-    kp, ksp = _quant_cache(k2, (nblocks, KV, bs, D))
-    vp, vsp = _quant_cache(k3, (nblocks, KV, bs, D))
+    kp, ksp = _quant_cache(k2, (2, nblocks, KV, bs, D))
+    vp, vsp = _quant_cache(k3, (2, nblocks, KV, bs, D))
     tbl = jax.random.randint(k1, (B, nb), 0, nblocks)
     lens = jnp.asarray([nb * bs, 13])
-    op = ops.paged_decode_attention_quant(qd, kp, vp, ksp, vsp, tbl, lens)
-    rp = decode_attention_paged_quant(qd, kp, vp, ksp, vsp, tbl, lens)
-    np.testing.assert_allclose(np.asarray(op), np.asarray(rp),
-                               rtol=1e-4, atol=1e-4)
+    for layer in range(2):
+        op = ops.paged_decode_attention_quant(qd, kp, vp, ksp, vsp, tbl,
+                                              lens, layer)
+        rp = decode_attention_paged_quant(qd, kp, vp, ksp, vsp, tbl, lens,
+                                          layer)
+        np.testing.assert_allclose(np.asarray(op), np.asarray(rp),
+                                   rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("R,D,rb", [(512, 64, 128), (96, 256, 32),
@@ -315,13 +338,17 @@ def test_ops_layout_adapters():
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), rtol=1e-4,
                                atol=1e-4)
 
+    # decode reads the stacked KV-head-major arena (n, B, KV, S, D)
     qd = jax.random.normal(k1, (B, H, D))
     lens = jnp.asarray([L, L // 2])
-    od = ops.decode_attention(qd, k, v, lens)
+    ka = jnp.stack([v, k]).swapaxes(2, 3)
+    va = jnp.stack([k, v]).swapaxes(2, 3)
     from repro.models.modules import decode_attention as jnp_dec
-    rd = jnp_dec(qd, k, v, lens)
-    np.testing.assert_allclose(np.asarray(od), np.asarray(rd), rtol=1e-4,
-                               atol=1e-4)
+    for layer in range(2):
+        od = ops.decode_attention(qd, ka, va, lens, layer)
+        rd = jnp_dec(qd, ka, va, lens, layer)
+        np.testing.assert_allclose(np.asarray(od), np.asarray(rd),
+                                   rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("total,preferred,align,want", [

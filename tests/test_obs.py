@@ -226,10 +226,38 @@ def test_trainer_fields_from_spans(trainer, on):
                   "ppo.train_read"):
         assert parent[child] == "ppo.train_rlhf"
     assert {s.step for s in snap["spans"]} == {3}
-    assert snap["counters"] == {"gen.decode_steps": GEN}
+    # one decode dispatch; the actor's one attention layer is read in place
+    assert snap["counters"] == {"gen.decode_steps": GEN,
+                                "gen.decode.inplace_layers": 1,
+                                "gen.decode.copied_layers": 0}
     reshard = [s for s in snap["spans"] if s.name == "hybrid.to_inference"]
     assert rs["seconds"] == pytest.approx(
         (reshard[-1].end_ns - reshard[-1].start_ns) * 1e-9)
+
+
+@pytest.mark.parametrize("arch,extra,split", [
+    # every layer an attention arena: all read and written in place
+    ("dense", {"n_layers": 3}, (3, 0)),
+    # ssm, attn units: the SSM state goes through a slice of the stack
+    ("hybrid", {"n_layers": 4, "attn_every": 2, "ssm_state": 16,
+                "ssm_headdim": 16, "ssm_chunk": 4}, (2, 2)),
+])
+def test_decode_layer_counters(arch, extra, split):
+    """``gen.decode.inplace_layers`` / ``copied_layers`` count, on each
+    ``gen_decode`` dispatch, the layers whose cache the step reads where
+    it lies and those it slices out and writes back."""
+    from repro.serving.engine import GenerationEngine
+    cfg = ACTOR.replace(name=arch, arch_type=arch, **extra)
+    eng = GenerationEngine(cfg, max_new_tokens=GEN, eos_id=V - 1, chunk=2)
+    obs.enable()
+    eng.generate(T.init_params(cfg, jax.random.PRNGKey(0)), PROMPTS,
+                 jax.random.PRNGKey(1))
+    n = obs.snapshot()["counters"]
+    dispatches = eng.last_stats["decode_steps"] // 2
+    assert dispatches >= 1
+    assert (n["gen.decode.inplace_layers"],
+            n["gen.decode.copied_layers"]) == tuple(
+                dispatches * x for x in split)
 
 
 @pytest.mark.parametrize("on", [False, True])

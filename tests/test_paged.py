@@ -110,7 +110,10 @@ def test_paged_kernel_matches_ref(B, KV, G, D, bs, nb, nblocks, dtype):
     vp = jax.random.normal(k3, (nblocks, KV, bs, D), dtype)
     tbl = jax.random.randint(k4, (B, nb), 0, nblocks)
     lens = jax.random.randint(k5, (B,), 1, nb * bs + 1)
-    o = paged_decode_attention_fwd(q, kp, vp, tbl, lens, interpret=True)
+    # the pool as the second layer of a stacked pool, read where it lies
+    stack = lambda x: jnp.stack([jnp.zeros_like(x), x])
+    o = paged_decode_attention_fwd(q, stack(kp), stack(vp), tbl, lens,
+                                   jnp.int32(1), interpret=True)
     r = ref.paged_decode_attention_ref(q, kp, vp, tbl, lens)
     tol = 1e-4 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(o, np.float32),

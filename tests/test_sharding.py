@@ -179,7 +179,8 @@ def test_train_state_pspecs_structure():
 
 def test_cache_pspecs_batch_axis():
     """KV cache layout: batch shards over data when divisible (else
-    replicates), the KV length axis shards over model when divisible."""
+    replicates), the KV length axis (after the KV heads) shards over
+    model when divisible."""
     import jax as _jax
     from repro.models.config import ModelConfig
     from repro.models import transformer as T
@@ -192,10 +193,10 @@ def test_cache_pspecs_batch_axis():
     for leaf in _jax.tree_util.tree_leaves(
             ps, is_leaf=lambda x: isinstance(x, P)):
         assert leaf[1] == "data"
-        assert leaf[2] == "model"
+        assert leaf[3] == "model"
     # indivisible batch replicates rows; odd S replicates the length
     ps = S.cache_pspecs(T.cache_struct(cfg, 3, 65), SINGLE, 3)
     for leaf in _jax.tree_util.tree_leaves(
             ps, is_leaf=lambda x: isinstance(x, P)):
         assert leaf[1] is None
-        assert leaf[2] is None
+        assert leaf[3] is None

@@ -9,13 +9,16 @@ serves: smollm-135m for attention and norms (3 KV heads, G=3, head_dim 64,
 bf16, batch 8, S=1024, paged block 16) and mamba2-370m for the SSD
 intra-chunk kernel (32 heads of 64, state 128, chunk 256).  Each test
 asserts that the kernel is in the compiled program as a
-``tpu_custom_call``.
+``tpu_custom_call``.  One more compiles the whole decode program of the
+generation engine at a small size and reads its HLO: the KV arena is
+written and read in place inside the decode loop.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library, and pytest
 workers that each import this file must all collect the same tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +33,8 @@ from repro.kernels import paged_attention as paged
 from repro.kernels import rmsnorm as rms
 from repro.kernels import ssd_scan as ssd
 
-# smollm-135m attention widths
-B, KV, G, D, S = 8, 3, 3, 64, 1024
+# smollm-135m attention widths; decode reads a stacked arena of N layers
+B, KV, G, D, S, N = 8, 3, 3, 64, 1024, 2
 L = 512                                   # train / prefill length
 BS, NB, NBLOCKS = 16, 64, 8 * 64 + 1      # paged: block, table width, pool
 BF16 = jnp.bfloat16
@@ -82,34 +85,35 @@ def test_flash_attention_bwd_compiles(one_chip):
 
 
 def test_decode_attention_compiles(one_chip):
-    _compile(lambda q, k, v, n: dec.decode_attention_fwd(
-                 q, k, v, n, s_block=ops.decode_block(S)),
-             one_chip, ((B, KV, G, D), BF16), ((B, KV, S, D), BF16),
-             ((B, KV, S, D), BF16), ((B,), jnp.int32))
+    _compile(lambda q, k, v, n, i: dec.decode_attention_fwd(
+                 q, k, v, n, i, s_block=ops.decode_block(S)),
+             one_chip, ((B, KV, G, D), BF16), ((N, B, KV, S, D), BF16),
+             ((N, B, KV, S, D), BF16), ((B,), jnp.int32), ((), jnp.int32))
 
 
 def test_decode_attention_quant_compiles(one_chip):
-    _compile(lambda q, k, v, ks, vs, n: dec.decode_attention_quant_fwd(
-                 q, k, v, ks, vs, n, s_block=ops.decode_block(S)),
-             one_chip, ((B, KV, G, D), BF16), ((B, KV, S, D), jnp.int8),
-             ((B, KV, S, D), jnp.int8), ((B, KV, S), jnp.float32),
-             ((B, KV, S), jnp.float32), ((B,), jnp.int32))
+    _compile(lambda q, k, v, ks, vs, n, i: dec.decode_attention_quant_fwd(
+                 q, k, v, ks, vs, n, i, s_block=ops.decode_block(S)),
+             one_chip, ((B, KV, G, D), BF16), ((N, B, KV, S, D), jnp.int8),
+             ((N, B, KV, S, D), jnp.int8), ((N, B, KV, S), jnp.float32),
+             ((N, B, KV, S), jnp.float32), ((B,), jnp.int32),
+             ((), jnp.int32))
 
 
 def test_paged_decode_attention_compiles(one_chip):
     _compile(paged.paged_decode_attention_fwd, one_chip,
-             ((B, KV, G, D), BF16), ((NBLOCKS, KV, BS, D), BF16),
-             ((NBLOCKS, KV, BS, D), BF16), ((B, NB), jnp.int32),
-             ((B,), jnp.int32))
+             ((B, KV, G, D), BF16), ((N, NBLOCKS, KV, BS, D), BF16),
+             ((N, NBLOCKS, KV, BS, D), BF16), ((B, NB), jnp.int32),
+             ((B,), jnp.int32), ((), jnp.int32))
 
 
 def test_paged_decode_attention_quant_compiles(one_chip):
     _compile(paged.paged_decode_attention_quant_fwd, one_chip,
-             ((B, KV, G, D), BF16), ((NBLOCKS, KV, BS, D), jnp.int8),
-             ((NBLOCKS, KV, BS, D), jnp.int8),
-             ((NBLOCKS, KV, BS), jnp.float32),
-             ((NBLOCKS, KV, BS), jnp.float32), ((B, NB), jnp.int32),
-             ((B,), jnp.int32))
+             ((B, KV, G, D), BF16), ((N, NBLOCKS, KV, BS, D), jnp.int8),
+             ((N, NBLOCKS, KV, BS, D), jnp.int8),
+             ((N, NBLOCKS, KV, BS), jnp.float32),
+             ((N, NBLOCKS, KV, BS), jnp.float32), ((B, NB), jnp.int32),
+             ((B,), jnp.int32), ((), jnp.int32))
 
 
 def test_rmsnorm_compiles(one_chip):
@@ -123,3 +127,76 @@ def test_ssd_intra_compiles(one_chip):
     _compile(ssd.ssd_intra_fwd, one_chip, ((b, nc, q, h, p), BF16),
              ((b, nc, q, h), jnp.float32), ((h,), jnp.float32),
              ((b, nc, q, n), BF16), ((b, nc, q, n), BF16))
+
+
+# ------------------------------------------------------------------ #
+# the decode step reads and writes the KV arena where it lies
+# ------------------------------------------------------------------ #
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%\S+ = (\w+)\[([0-9,]*)\]\S* "
+                     r"([\w-]+)\((.*)$")
+
+
+def _arena_moves(text, shapes):
+    """``(computation, op)`` of every copy, buffer allocation or
+    dynamic-update-slice of one of ``shapes`` outside the entry
+    computation: the while bodies of the decode and layer loops and what
+    they call.  Moves between memory spaces (``copy-start``) are the
+    compiler's prefetches and count too."""
+    found, comp = [], None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY ")) and line.rstrip().endswith("{"):
+            comp = None if line.startswith("ENTRY ") else line.split()[0]
+            continue
+        m = _HLO_OP.match(line)
+        if comp is None or not m:
+            continue
+        shape = tuple(int(x) for x in m.group(2).split(",") if x)
+        op = m.group(3)
+        if op == "custom-call" and "AllocateBuffer" in m.group(4):
+            op = "AllocateBuffer"
+        if shape in shapes and op in ("copy", "copy-start", "AllocateBuffer",
+                                      "dynamic-update-slice"):
+            found.append((comp, op))
+    return found
+
+
+@pytest.mark.parametrize("kv_quant", [False, True])
+def test_decode_step_moves_no_arena(one_chip, monkeypatch, kv_quant):
+    """``gen_decode`` at a small dense config, compiled with the Pallas
+    kernels: inside its loops no op copies, allocates or stacks the KV
+    arena or a layer's slab (the layer scan carries the arena, the new
+    row is written in place and the kernel reads the layer where it
+    lies), and the arena is aliased from input to output."""
+    from repro.models import transformer as T
+    from repro.models.config import ModelConfig
+    from repro.serving.engine import GenerationEngine
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = ModelConfig(name="t", arch_type="dense", n_layers=3, d_model=256,
+                      n_heads=4, n_kv_heads=4, d_ff=512, vocab_size=512,
+                      compute_dtype="bfloat16", use_pallas=True,
+                      kv_quant=kv_quant)
+    batch, prompt, gen = 8, 128, 128
+    eng = GenerationEngine(cfg, max_new_tokens=gen)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.tree.map(on_chip, T.cache_struct(cfg, batch, prompt + gen))
+    logits, key, pos, done = (
+        on_chip(jax.ShapeDtypeStruct(shape, dtype)) for shape, dtype in (
+            ((batch, cfg.vocab_size), jnp.float32), ((2,), jnp.uint32),
+            ((batch,), jnp.int32), ((batch,), jnp.bool_)))
+    compiled = eng._fixed_chunk(gen).lower(
+        params, logits, cache, key, pos, done, None).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    arena = [x for seg in cache for unit in seg for n, x in unit.items()
+             if n in ("k", "v")]
+    shapes = {x.shape for x in arena} | {x.shape[1:] for x in arena}
+    assert _arena_moves(text, shapes) == []
+    arena_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= arena_bytes
