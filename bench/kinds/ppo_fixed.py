@@ -10,12 +10,25 @@ compile every program the window runs; the window then runs further
 iterations on the same trainer until ``--seconds`` have passed, and
 ``ppo_iter_s`` is the window's seconds over the iterations it completed.
 
+The configuration file's model entries build the system's
+``ModelConfig``s (``bench/lib/models.py``); its ``reference`` names the
+plain reference of the models (``bench/references/<name>.py``), which
+makes the weights from the seed and counts the layers' FLOPs.  Every key
+of the traffic file's ``ppo`` block is a ``PPOConfig`` field or one of
+``REFERENCE_ONLY``.
+
+With ``--trace 1``, after the window, ``trace_iterations`` more
+iterations run under the profiler with the program's tracer
+(``repro.obs``) on; its spans and counters over them are the record's
+``program``.
+
 ``correct`` compares those first iterations, once the window has closed
 and the trainer is freed, with the plain float32 reference following the
 same sequences (``bench/references/ppo.py``).
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import time
 from typing import Dict
@@ -26,22 +39,31 @@ from bench.lib import compare as C
 from bench.lib import flops as F
 from bench.lib import weights as W
 from bench.lib.models import model_config
-from bench.lib.spec import load_reference
+from bench.lib.spec import SpecError, load_reference
 
 HEADS = {"actor": "lm", "critic": "value"}
+# keys of the ``ppo`` block that only the reference reads: the program's
+# optimizer (``repro.training.optimizer.update``) has these as defaults
+REFERENCE_ONLY = ("adam_b1", "adam_b2", "adam_eps", "grad_clip")
 
 
-def ppo_config(tr, kv_quant=False):
+def ppo_config(tr, **over):
+    """The program's ``PPOConfig``: the ``ppo`` block's fields by name
+    (``use_ema`` on unless it says otherwise), the sampling settings from
+    the traffic's top level, then ``over``."""
     from repro.core.ppo import PPOConfig
+    top = {"max_new_tokens": tr["gen_len"], "temperature": tr["temperature"],
+           "eos_id": None}
+    fields = {f.name for f in dataclasses.fields(PPOConfig)} - set(top)
     hp = tr["ppo"]
-    return PPOConfig(max_new_tokens=tr["gen_len"], kv_quant=kv_quant,
-                     temperature=tr["temperature"], eos_id=None,
-                     kl_coef=hp["kl_coef"], clip_eps=hp["clip_eps"],
-                     value_clip=hp["value_clip"],
-                     clip_reward=hp["clip_reward"], gamma=hp["gamma"],
-                     lam=hp["lam"], lr_actor=hp["lr_actor"],
-                     lr_critic=hp["lr_critic"], ema_decay=hp["ema_decay"],
-                     use_ema=True)
+    unknown = sorted(set(hp) - fields - set(REFERENCE_ONLY))
+    if unknown:
+        raise SpecError(f"traffic ppo keys {unknown} are neither PPOConfig "
+                        f"fields (less {sorted(top)}, set from the top "
+                        f"level) nor read by the reference "
+                        f"({list(REFERENCE_ONLY)})")
+    kw = {"use_ema": True, **{k: v for k, v in hp.items() if k in fields}}
+    return PPOConfig(**{**kw, **top, **over})
 
 
 def _leaf_norms(tree, scale=1.0) -> Dict[str, float]:
@@ -77,15 +99,16 @@ def rollout_inputs(tr, vocab: int, seed: int):
 class Program:
     """The system under test, built once from the seed."""
 
-    def __init__(self, cell, seed: int, kv_quant: bool = False):
+    def __init__(self, cell, seed: int, **ppo_over):
         from repro.core.hybrid_engine import HybridEngine
         from repro.core.ppo import PPOTrainer
         from repro.launch.mesh import make_mesh
         tr = cell.traffic
         self.cell, self.seed = cell, seed
+        self.D = load_reference(cell.config["reference"], cell.root)
         self.cfgs = {r: model_config(f"{cell.config['name']}-{r}", m)
                      for r, m in cell.config["models"].items()}
-        w = W.make(cell.config["models"], HEADS, seed)
+        w = W.make(cell.config["models"], HEADS, seed, self.D.init)
         engine = HybridEngine(self.cfgs["actor"], make_mesh(1, 1))
         # the frozen reference and reward models start as the actor and
         # critic: the same initial arrays, which the trainer never writes
@@ -93,11 +116,13 @@ class Program:
             actor_cfg=self.cfgs["actor"], critic_cfg=self.cfgs["critic"],
             actor_params=w["actor"], critic_params=w["critic"],
             ref_params=w["actor"], reward_params=w["critic"],
-            ppo=ppo_config(tr, kv_quant), engine=engine)
+            ppo=ppo_config(tr, **ppo_over), engine=engine)
         self._inputs = rollout_inputs(tr, self.cfgs["actor"].vocab_size, seed)
 
     def iteration(self, i: int, annotate):
         import jax
+        from repro import obs
+        obs.step(i)
         t = self.trainer
         prompts, key = self._inputs(np.int32(i))
         t0 = time.perf_counter()
@@ -135,7 +160,7 @@ def check_steps(prog: Program, annotate, n: int) -> dict:
                 "actor": _leaf_norms(t.actor.opt.m, 1.0 / (1.0 - b1)),
                 "critic": _leaf_norms(t.critic.opt.m, 1.0 / (1.0 - b1))}
         del rollout, exp
-    w0 = W.make(prog.cell.config["models"], HEADS, prog.seed)
+    w0 = W.make(prog.cell.config["models"], HEADS, prog.seed, prog.D.init)
     rec["delta"] = {"actor": _delta_norms(prog.trainer.actor.params,
                                           w0["actor"]),
                     "critic": _delta_norms(prog.trainer.critic.params,
@@ -158,7 +183,7 @@ def reference_record(cell, seed: int, rec: dict, *, mode="f32",
     with jax.default_matmul_precision("highest"):
         ref = PPOReference(D, models["actor"], models["critic"], hp,
                            mode=mode, rows=rows)
-        w = W.make(models, HEADS, seed)
+        w = W.make(models, HEADS, seed, D.init)
         pa, pc = w.pop("actor"), w.pop("critic")
         pa0 = jax.tree.map(lambda x: jnp.array(x, copy=True), pa)
         pc0 = jax.tree.map(lambda x: jnp.array(x, copy=True), pc)
@@ -182,6 +207,25 @@ def reference_record(cell, seed: int, rec: dict, *, mode="f32",
         out["delta"] = {"actor": _delta_norms(pa, pa0),
                         "critic": _delta_norms(pc, pc0)}
     return out
+
+
+def traced(prog: Program, i: int, n: int, tracing, annotate):
+    """Iterations ``i .. i + n - 1`` under the profiler (``tracing()``, a
+    context that yields a box whose ``summary`` it fills on exit) with the
+    program's tracer on: the trace's summary, and the spans and counters
+    the tracer recorded over those iterations alone."""
+    from repro import obs
+    with tracing() as box:
+        obs.reset()
+        obs.enable()
+        try:
+            for k in range(i, i + n):
+                prog.iteration(k, annotate)
+        finally:
+            obs.disable()
+    snap = obs.snapshot()
+    return box.summary, {"spans": snap["spans"],
+                         "counters": snap["counters"]}
 
 
 def numbers(prog: dict, ref: dict) -> Dict[str, float]:
@@ -245,18 +289,16 @@ def run(ctx) -> dict:
     window_s = time.perf_counter() - t0
     ctx.window_done()
 
-    trace = None
+    trace = program = None
     if ctx.trace:
-        with ctx.tracing() as tr_box:
-            for _ in range(tr["trace_iterations"]):
-                prog.iteration(i, ctx.annotate)
-                i += 1
-        trace = tr_box.summary
+        trace, program = traced(prog, i, tr["trace_iterations"],
+                                ctx.tracing, ctx.annotate)
     ctx.read_memory()
+    D = prog.D
     del prog
     gc.collect()
 
-    fl = F.ppo_iteration(cell.config["models"]["actor"],
+    fl = F.ppo_iteration(D, cell.config["models"]["actor"],
                          cell.config["models"]["critic"], tr["batch"],
                          tr["prompt_len"], tr["gen_len"])
     ref = reference_record(cell, ctx.seed, rec)
@@ -265,7 +307,7 @@ def run(ctx) -> dict:
         "attempted": n, "failed": 0,
         "end_to_end": {"ppo_iter_s": window_s / n},
         "record": {"spans": spans, "window_s": window_s, "iterations": n,
-                   "flops": fl, "trace": trace,
+                   "flops": fl, "trace": trace, "program": program,
                    "trace_iterations": tr["trace_iterations"]},
         "numbers": nums,
     }

@@ -4,18 +4,13 @@ Model FLOPs count the forward and backward matmuls and attention that
 the algorithm needs, never recomputation (rematerialisation) or padding.
 A multiply-add is 2 FLOPs.  Backward is twice the forward.
 
-``m`` is a model-size dict as the configuration files hold it:
-``n_layers, d_model, n_heads, n_kv_heads, head_dim, d_ff, vocab_size``.
+``m`` is a model-size dict as the configuration files hold it.  The
+FLOPs of a model's layers come from the configuration's plain reference
+(``bench/references/<name>.py``: ``fwd_flops(m, n_tokens, n_ctx)``),
+which knows its architecture; the vocabulary and value heads, which
+every model here has, are counted here (``d_model``, ``vocab_size``).
 """
 from __future__ import annotations
-
-
-def body_params(m) -> int:
-    """Matmul weights of the decoder layers (attention and SwiGLU MLP)."""
-    D, H, KV, hd, F = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                       m["head_dim"], m["d_ff"])
-    per_layer = D * H * hd + 2 * D * KV * hd + H * hd * D + 3 * D * F
-    return m["n_layers"] * per_layer
 
 
 def causal_pairs(n_q: int, n_k: int) -> int:
@@ -25,29 +20,17 @@ def causal_pairs(n_q: int, n_k: int) -> int:
     return n_q * off + n_q * (n_q + 1) // 2
 
 
-def attn_flops(m, pairs: int) -> int:
-    """Scores and weighted values over ``pairs`` query-key pairs, all
-    layers and heads: 2 matmuls of ``head_dim`` each, 2 FLOPs a MAC."""
-    return 4 * m["n_layers"] * m["n_heads"] * m["head_dim"] * pairs
-
-
-def body_fwd(m, n_tokens: int, n_ctx: int | None = None) -> int:
-    """Forward FLOPs of the layers for ``n_tokens`` new tokens that follow
-    ``n_ctx - n_tokens`` tokens already in the cache (causal)."""
-    n_ctx = n_tokens if n_ctx is None else n_ctx
-    return (2 * n_tokens * body_params(m)
-            + attn_flops(m, causal_pairs(n_tokens, n_ctx)))
-
-
 def head_fwd(m, n_positions: int, width: int | None = None) -> int:
     """Output head matmul: vocabulary logits, or a value head of width 1."""
     width = m["vocab_size"] if width is None else width
     return 2 * n_positions * m["d_model"] * width
 
 
-def ppo_iteration(actor, critic, batch: int, prompt: int, gen: int) -> dict:
+def ppo_iteration(D, actor, critic, batch: int, prompt: int, gen: int
+                  ) -> dict:
     """Required FLOPs of one stage-3 iteration on a fixed-shape batch,
-    split by phase.
+    split by phase; ``D`` is the configuration's reference module, which
+    counts the layers (``D.fwd_flops``).
 
     - generate: the layers over the prompt and the first ``gen - 1``
       sampled tokens (the last token's forward feeds nothing), logits at
@@ -59,10 +42,11 @@ def ppo_iteration(actor, critic, batch: int, prompt: int, gen: int) -> dict:
       positions their losses read.
     """
     T = prompt + gen
-    gen_f = batch * (body_fwd(actor, T - 1) + head_fwd(actor, gen))
-    logp = body_fwd(actor, T - 1) + head_fwd(actor, T - 1)
-    vals = body_fwd(critic, T - 1) + head_fwd(critic, T - 1, 1)
-    rew = body_fwd(critic, T) + head_fwd(critic, 1, 1)
+    body = D.fwd_flops
+    gen_f = batch * (body(actor, T - 1) + head_fwd(actor, gen))
+    logp = body(actor, T - 1) + head_fwd(actor, T - 1)
+    vals = body(critic, T - 1) + head_fwd(critic, T - 1, 1)
+    rew = body(critic, T) + head_fwd(critic, 1, 1)
     score = batch * (2 * logp + vals + rew)
     train = batch * 3 * (logp + vals)
     return {"generate": gen_f, "score": score, "train": train,
@@ -72,22 +56,28 @@ def ppo_iteration(actor, critic, batch: int, prompt: int, gen: int) -> dict:
 # ---------------------------------------------------------------- #
 # kernels: (flops, bytes) per call
 # ---------------------------------------------------------------- #
-def flash_fwd(batch, n_q, n_k, heads, kv_heads, head_dim, itemsize=2):
-    """Causal flash attention forward: QK^T and PV over the kept pairs;
-    reads q, k, v and writes o once."""
-    flops = 4 * batch * heads * head_dim * causal_pairs(n_q, n_k)
-    bytes_ = itemsize * batch * head_dim * (2 * n_q * heads
-                                            + 2 * n_k * kv_heads)
+def flash_fwd(batch, n_q, n_k, heads, kv_heads, head_dim, v_head_dim=None,
+              itemsize=2):
+    """Causal flash attention forward: QK^T over ``head_dim`` and PV over
+    ``v_head_dim`` (``head_dim`` unless given) for the kept pairs; reads
+    q, k, v and writes o once."""
+    dv = head_dim if v_head_dim is None else v_head_dim
+    flops = 2 * batch * heads * (head_dim + dv) * causal_pairs(n_q, n_k)
+    bytes_ = itemsize * batch * (head_dim + dv) * (n_q * heads
+                                                   + n_k * kv_heads)
     return flops, bytes_
 
 
-def flash_bwd(batch, n_q, n_k, heads, kv_heads, head_dim, itemsize=2):
-    """Flash attention backward without recomputation: dV, dP, dQ, dK are
-    four matmuls over the kept pairs (the score recompute is not
-    counted); reads q, k, v, o, do and writes dq, dk, dv."""
-    flops = 8 * batch * heads * head_dim * causal_pairs(n_q, n_k)
-    bytes_ = itemsize * batch * head_dim * (4 * n_q * heads
-                                            + 4 * n_k * kv_heads)
+def flash_bwd(batch, n_q, n_k, heads, kv_heads, head_dim, v_head_dim=None,
+              itemsize=2):
+    """Flash attention backward without recomputation: dV and dP over
+    ``v_head_dim``, dQ and dK over ``head_dim``, four matmuls over the
+    kept pairs (the score recompute is not counted); reads q, k, v, o, do
+    and writes dq, dk, dv."""
+    dv = head_dim if v_head_dim is None else v_head_dim
+    flops = 4 * batch * heads * (head_dim + dv) * causal_pairs(n_q, n_k)
+    bytes_ = itemsize * batch * 2 * (head_dim + dv) * (n_q * heads
+                                                       + n_k * kv_heads)
     return flops, bytes_
 
 

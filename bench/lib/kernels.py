@@ -5,12 +5,17 @@ On a TPU the profiler names a Pallas call after its HLO instruction
 custom_call_target="tpu_custom_call"``); its operand shapes tell the
 kernels of ``repro.kernels`` apart:
 
-- flash attention forward: q ``(B, KV, G, Lq, D)``, k, v ``(B, KV, Lk, D)``;
+- flash attention forward: q ``(B, KV, G, Lq, Dk)``, k ``(B, KV, Lk, Dk)``,
+  v ``(B, KV, Lk, Dv)``;
 - flash attention backward (two calls, dk/dv then dq): q and dO
   ``(B, KV, G, Lq, D)``, k, v, and the two ``(B, KV, G, Lq)`` rows;
-- dense decode: lens ``(B,)``, q ``(B, KV, G, D)``, k, v ``(B, KV, S, D)``;
-- paged decode: block tables ``(B, nb)``, lens ``(B,)``, q
-  ``(B, KV, G, D)``, k, v pools ``(blocks, KV, bs, D)``;
+- decode: first the integer scalar-prefetch operands, then q
+  ``(B, KV, G, D)`` and k, v: the dense kernel takes lens ``(B,)`` and the
+  layer index ``(1,)`` and reads the stacked arena ``(layers, B, KV, S,
+  D)``; the paged kernel takes block tables ``(B, nb)`` first and reads
+  the stacked pools ``(layers, blocks, KV, bs, D)``; the int8 variants
+  add scale planes after v.  Kernels that read one layer's slab (rank 4)
+  are told apart the same way;
 - rmsnorm: x ``(N, D)``, w ``(D,)``.
 """
 from __future__ import annotations
@@ -52,17 +57,19 @@ def classify(ops) -> Optional[str]:
         return "flash_fwd"
     if ranks[:4] == [5, 5, 4, 4] and len(ops) == 6:
         return "flash_bwd"
-    if ranks == [1, 4, 4, 4]:
-        return "dense_decode"
-    if ranks[:5] == [2, 1, 4, 4, 4]:
-        return "paged_decode"
+    n_int = next((i for i, (dt, _) in enumerate(ops) if dt != "s32"),
+                 len(ops))
+    if n_int and ranks[n_int:n_int + 3] in ([4, 4, 4], [4, 5, 5]):
+        return "paged_decode" if 2 in ranks[:n_int] else "dense_decode"
     if ranks == [2, 1]:
         return "rmsnorm"
     return "other"
 
 
 def flash_shape(ops):
-    """(batch, n_q, n_k, heads, kv_heads, head_dim) of a flash call."""
+    """(batch, n_q, n_k, heads, kv_heads, head_dim, v_head_dim) of a flash
+    call: q is the first operand, k and v the first two of rank 4 (in the
+    backward dO comes between them and q)."""
     B, KV, G, Lq, D = ops[0][1]
-    Lk = ops[1][1][2]
-    return B, Lq, Lk, KV * G, KV, D
+    k, v = [s for _, s in ops if len(s) == 4][:2]
+    return B, Lq, k[2], KV * G, KV, D, v[3]

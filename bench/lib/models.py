@@ -1,15 +1,23 @@
-"""The system's model configuration for a model of a configuration file."""
+"""The system's model configuration for a model of a configuration file.
+
+Every key of a model entry names a field of the system's ``ModelConfig``
+(``arch_type`` is ``"dense"`` unless the entry says otherwise); fields
+the entry leaves out take ``ModelConfig``'s defaults.  A key that is no
+field is refused, so that a misspelt size cannot pass as a default.
+"""
 from __future__ import annotations
 
-# sizes and numerics a configuration file gives each model, as the
-# system's ``ModelConfig`` names them
-PROGRAM_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-                "d_ff", "vocab_size", "rope_theta", "rms_eps", "logit_chunk",
-                "param_dtype", "compute_dtype")
+import dataclasses
+
+from bench.lib.spec import SpecError
 
 
-def model_config(name: str, sizes: dict, **extra):
-    """A dense decoder ``ModelConfig`` with the file's sizes."""
+def model_config(name: str, sizes: dict):
+    """The ``ModelConfig`` named ``name`` with the entry ``sizes``."""
     from repro.models.config import ModelConfig
-    return ModelConfig(name=name, arch_type="dense", **extra,
-                       **{k: sizes[k] for k in PROGRAM_KEYS})
+    fields = {f.name for f in dataclasses.fields(ModelConfig)} - {"name"}
+    unknown = sorted(set(sizes) - fields)
+    if unknown:
+        raise SpecError(f"model {name!r}: {unknown} are not fields of the "
+                        f"system's ModelConfig")
+    return ModelConfig(name=name, **{"arch_type": "dense", **sizes})

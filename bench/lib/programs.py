@@ -1,27 +1,22 @@
-"""Device time per named program, idle time per program span, and the
-iterations that stall, from a profiler trace and the program's tracer.
+"""Device time per named program and the iterations that stall, from a
+profiler trace and the program's tracer.
 
 The stage-3 path runs each jitted program under a name of its own
 (``repro.obs.named``): the ``XLA Modules`` line of a TPU plane holds one
-event per executed program, ``jit_<name>(<hash>)``.  The program's spans
-(``repro.obs.span``, ``layer.step``) sit on the host plane as annotations,
-on the same clock.  ``planes`` is what ``bench.lib.trace.reduce_events``
-reads: (plane name, [(line name, [event])]) with events that have
-``name``, ``start_ns`` and ``duration_ns``.
+event per executed program, ``jit_<name>(<hash>)``, which
+``bench.lib.trace`` keeps as ``TraceSummary.modules``: (module, start s,
+end s), clipped to the traced window.  The program's spans
+(``repro.obs.span``) and counters come from ``repro.obs.snapshot()``.
+The per-layer readers ``stage3.decode_step_ms`` and
+``stage3.actor_step_s`` and ``bench/tools/stage3_split.py`` read through
+these functions.
 """
 from __future__ import annotations
 
 import re
 import statistics
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
-from bench.lib import trace as TR
-
-MODULES_LINE = "XLA Modules"
-# the layers of the program's spans, and the harness's own
-SPAN_LAYERS = ("stage3", "ppo", "gen", "hybrid", "ema", "replay",
-               "pipeline")
-OUTSIDE = "outside spans"
 _MODULE = re.compile(r"^(?:jit_)?(.*?)(?:\(\d+\))?$")
 
 
@@ -30,29 +25,11 @@ def program_name(module: str) -> str:
     return _MODULE.match(module).group(1)
 
 
-def modules(planes, w0: float, w1: float) -> List[Tuple[str, float, float]]:
-    """(program, start s, end s) of every program that ran on a TPU core
-    inside the window [w0, w1] (seconds), clipped to it."""
-    out = []
-    for pname, plines in planes:
-        if not pname.startswith("/device:TPU:") or "SparseCore" in pname:
-            continue
-        for lname, events in plines:
-            if lname != MODULES_LINE:
-                continue
-            for ev in events:
-                s = ev.start_ns * 1e-9
-                e = s + ev.duration_ns * 1e-9
-                if e > w0 and s < w1:
-                    out.append((program_name(ev.name), max(s, w0),
-                                min(e, w1)))
-    return out
-
-
 def seconds_by_program(mods) -> Dict[str, Tuple[int, float]]:
-    """program -> (runs, device seconds)."""
+    """program -> (runs, device seconds), from (module, start, end)."""
     out: Dict[str, Tuple[int, float]] = {}
-    for name, s, e in mods:
+    for module, s, e in mods:
+        name = program_name(module)
         n, t = out.get(name, (0, 0.0))
         out[name] = (n + 1, t + e - s)
     return out
@@ -67,39 +44,15 @@ def per_iteration(mods, name: str, iterations: int) -> Optional[float]:
     return t / n
 
 
-def decode_step_ms(mods, decode_steps: float) -> Optional[float]:
-    """Device milliseconds of ``gen_decode`` per decode step counted by
-    the program (counter ``gen.decode_steps``)."""
+def decode_step_ms(mods, counters: Dict[str, float]) -> Optional[float]:
+    """Device milliseconds of ``gen_decode`` per decode step that the
+    program counted over the same iterations (``counters``: what
+    ``repro.obs`` counted, ``gen.decode_steps`` among them)."""
+    decode_steps = counters.get("gen.decode_steps", 0)
     n, t = seconds_by_program(mods).get("gen_decode", (0, 0.0))
     if n == 0 or not decode_steps:
         return None
     return 1e3 * t / decode_steps
-
-
-def _is_span(name: str) -> bool:
-    return name.split(".", 1)[0] in SPAN_LAYERS and "." in name
-
-
-def idle_by_span(s: "TR.TraceSummary") -> List[Tuple[str, float]]:
-    """Idle seconds of the window, each hole in the busy union put down to
-    the innermost program or harness span covering its midpoint, else to
-    ``outside spans``; largest first.  The busy union is taken over the
-    ops of every device at once (one-device cells)."""
-    w0, w1 = s.host_spans[TR.WINDOW_SPAN][0]
-    busy = TR._union([(max(o.start, w0), min(o.end, w1))
-                      for o in s.device_ops if o.end > w0 and o.start < w1])
-    spans = [(e - b, n, b, e) for n, v in s.host_spans.items()
-             if _is_span(n) for b, e in v]
-    out: Dict[str, float] = {}
-    edges = [w0] + [x for iv in busy for x in iv] + [w1]
-    for a, b in zip(edges[0::2], edges[1::2]):
-        if b <= a:
-            continue
-        mid = 0.5 * (a + b)
-        cover = [(d, n) for d, n, s0, s1 in spans if s0 <= mid <= s1]
-        label = min(cover)[1] if cover else OUTSIDE
-        out[label] = out.get(label, 0.0) + (b - a)
-    return sorted(out.items(), key=lambda kv: -kv[1])
 
 
 def stall(spans) -> Optional[dict]:

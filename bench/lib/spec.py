@@ -1,7 +1,12 @@
 """Discovery by name: everything a cell needs is found from
 ``BENCHMARK.json`` and files named after its entries.
 
-- configuration ``<c>``: ``bench/configs/<c>.json`` (the ``file`` key);
+- configuration ``<c>``: ``bench/configs/<c>.json`` (the ``file`` key),
+  whose ``reference`` names its plain reference ``bench/references/<r>.py``
+  (``init`` for the weights, ``fwd_flops`` for the layers' FLOPs, and
+  ``hidden``, ``logits``, ``values`` for the reference's forward) and
+  whose ``models`` entries are ``ModelConfig`` fields
+  (``bench/lib/models.py``);
 - traffic mix ``<t>``: ``bench/traffic/<t>.json``, whose ``kind`` names
   the module ``bench/kinds/<kind>.py``;
 - per-layer metric ``<m>``: ``bench/metrics/<m>.py`` with ``read(rec)``;

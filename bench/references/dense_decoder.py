@@ -11,8 +11,11 @@ backward, one scale per tensor): the next precision below the bfloat16
 compute that the configurations state, used as the control that a sound
 comparison must reject.
 
-Weights are trees in the layout of ``bench.lib.weights`` (layers stacked
-on a leading axis); the reference reads them by key.
+Besides the forward, the module gives what the harness asks of every
+reference (``bench/lib/weights.py``, ``bench/lib/flops.py``): ``init``,
+the random tree of one model in the layout the system consumes (layers
+stacked on a leading axis), which the reference reads by key; and
+``fwd_flops``, the forward FLOPs its layers require.
 """
 from __future__ import annotations
 
@@ -20,6 +23,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from bench.lib.flops import causal_pairs
 
 HIGHEST = jax.lax.Precision.HIGHEST
 _FP8 = {"e4m3": (jnp.float8_e4m3fn, 448.0), "e5m2": (jnp.float8_e5m2, 57344.0)}
@@ -129,3 +134,66 @@ def logits(params, h, mode="f32"):
 
 def values(params, h, mode="f32"):
     return dot("btd,dv->btv", h, params["v_head"], mode)[..., 0]
+
+
+# ---------------------------------------------------------------- #
+# weights: one model's tree, made on the device from a key
+# ---------------------------------------------------------------- #
+def _normal(key, shape, fan_in):
+    return jax.random.normal(key, shape, jnp.float32) / jnp.sqrt(
+        jnp.float32(fan_in))
+
+
+def init(key, m, head: str):
+    """One decoder's tree; ``head`` is ``"lm"`` (vocabulary logits) or
+    ``"value"`` (a scalar per position: critic and reward models).
+    Matrices are normal with standard deviation ``1/sqrt(fan_in)``; norm
+    weights are ones."""
+    L, D, H, KV, hd, F, V = (m["n_layers"], m["d_model"], m["n_heads"],
+                             m["n_kv_heads"], m["head_dim"], m["d_ff"],
+                             m["vocab_size"])
+    ks = iter(jax.random.split(key, 10))
+    layer = {
+        "ln1": jnp.ones((L, D), jnp.float32),
+        "attn": {"wq": _normal(next(ks), (L, D, H * hd), D),
+                 "wk": _normal(next(ks), (L, D, KV * hd), D),
+                 "wv": _normal(next(ks), (L, D, KV * hd), D),
+                 "wo": _normal(next(ks), (L, H * hd, D), H * hd)},
+        "ln2": jnp.ones((L, D), jnp.float32),
+        "mlp": {"w_gate": _normal(next(ks), (L, D, F), D),
+                "w_up": _normal(next(ks), (L, D, F), D),
+                "w_down": _normal(next(ks), (L, F, D), F)},
+    }
+    tree = {"embed": _normal(next(ks), (V, D), V),
+            "segments": ((layer,),),
+            "final_norm": jnp.ones((D,), jnp.float32)}
+    if head == "lm":
+        tree["lm_head"] = _normal(next(ks), (D, V), D)
+    else:
+        tree["v_head"] = _normal(next(ks), (D, 1), D)
+    return tree
+
+
+# ---------------------------------------------------------------- #
+# required FLOPs of the layers (a multiply-add is 2 FLOPs)
+# ---------------------------------------------------------------- #
+def body_params(m) -> int:
+    """Matmul weights of the decoder layers (attention and SwiGLU MLP)."""
+    D, H, KV, hd, F = (m["d_model"], m["n_heads"], m["n_kv_heads"],
+                       m["head_dim"], m["d_ff"])
+    per_layer = D * H * hd + 2 * D * KV * hd + H * hd * D + 3 * D * F
+    return m["n_layers"] * per_layer
+
+
+def attn_flops(m, pairs: int) -> int:
+    """Scores and weighted values over ``pairs`` query-key pairs, all
+    layers and heads: 2 matmuls of ``head_dim`` each, 2 FLOPs a MAC."""
+    return 4 * m["n_layers"] * m["n_heads"] * m["head_dim"] * pairs
+
+
+def fwd_flops(m, n_tokens: int, n_ctx: int | None = None) -> int:
+    """Forward FLOPs of the layers for ``n_tokens`` new tokens that follow
+    ``n_ctx - n_tokens`` tokens already in the cache (causal)."""
+    n_ctx = n_tokens if n_ctx is None else n_ctx
+    return (2 * n_tokens * body_params(m)
+            + attn_flops(m, causal_pairs(n_tokens, n_ctx)))

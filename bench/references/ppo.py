@@ -6,8 +6,10 @@ reference log-probs, critic values, the reward model's score at the last
 token), shapes per-token rewards with a KL penalty, runs GAE with
 whitened advantages, takes one clipped-surrogate actor step and one
 clipped-value critic step, each with global-norm gradient clipping and
-Adam.  Models are the plain decoder of ``dense_decoder``; work is done in
-blocks of rows so that float32 fits beside nothing else.
+Adam.  Models are the forward of the configuration's reference ``D``
+(``hidden``, ``logits``, ``values``; ``dense_decoder`` for the OPT pair);
+no EMA is read, so the compared numbers do not depend on ``use_ema``.
+Work is done in blocks of rows so that float32 fits beside nothing else.
 
 ``hp`` holds the job's settings as the traffic file states them.
 """

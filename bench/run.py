@@ -72,6 +72,24 @@ class _TraceBox:
     summary = None
 
 
+@contextlib.contextmanager
+def tracing(jax):
+    """Profile the block inside the window annotation; the box it yields
+    gets the trace's reduction (``bench.lib.trace``) as ``summary`` on
+    exit, and the trace files are removed."""
+    from bench.lib import trace as TR
+    shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    box = _TraceBox()
+    jax.profiler.start_trace(str(TRACE_DIR))
+    try:
+        with jax.profiler.TraceAnnotation(TR.WINDOW_SPAN):
+            yield box
+    finally:
+        jax.profiler.stop_trace()
+    box.summary = TR.reduce_file(TR.find_xplane(str(TRACE_DIR)))
+    shutil.rmtree(TRACE_DIR, ignore_errors=True)
+
+
 class Context:
     """What a traffic kind's ``run`` gets: the cell, the run's arguments, and the
     harness's clocks, annotations, tracing and memory reading."""
@@ -104,19 +122,8 @@ class Context:
         self.memory_peak = DV.memory_peak_bytes(self.devs)
         self.memory_limit = DV.memory_limit_bytes(self.devs)
 
-    @contextlib.contextmanager
     def tracing(self):
-        from bench.lib import trace as TR
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        box = _TraceBox()
-        self.jax.profiler.start_trace(str(TRACE_DIR))
-        try:
-            with self.annotate(TR.WINDOW_SPAN):
-                yield box
-        finally:
-            self.jax.profiler.stop_trace()
-        box.summary = TR.reduce_file(TR.find_xplane(str(TRACE_DIR)))
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        return tracing(self.jax)
 
 
 def per_layer_metrics(cell, record, peaks, root):

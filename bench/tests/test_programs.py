@@ -1,6 +1,7 @@
 """Device time per named program, idle time by program span and the
-stall finder on a constructed trace; the stage-3 split tool end to end
-on a tiny cell on the CPU."""
+stall finder on a constructed trace; the two per-layer readers of the
+program's programs and counters; the stage-3 split tool end to end on a
+tiny cell on the CPU."""
 import dataclasses
 
 import pytest
@@ -13,11 +14,12 @@ from repro.obs import Span
 MS = 1e6
 
 
-def planes(iterations=2):
+def planes(iterations=2, window_ms=None):
     """Two iterations of 100 ms: to_inference 0-2, prefill 3-10, decode
     10-70 (60 ms, 4 steps of 15), scoring 72-80, actor 80-92, critic
-    92-96, EMA 96-98 (ms from the iteration's start)."""
-    host = [Ev(TR.WINDOW_SPAN, 0, 100 * MS * iterations)]
+    92-96, EMA 96-98 (ms from the iteration's start).  The traced window
+    spans the iterations unless ``window_ms`` cuts it short."""
+    host = [Ev(TR.WINDOW_SPAN, 0, (window_ms or 100 * iterations) * MS)]
     mods, ops = [], []
     prog = [("jit_hybrid_to_inference(11)", 0, 2),
             ("jit_gen_prefill(12)", 3, 10), ("jit_gen_decode(13)", 10, 70),
@@ -49,9 +51,7 @@ def test_program_names_strip_jit_and_hash():
 
 
 def test_module_seconds_per_program_and_iteration():
-    p = planes()
-    s = TR.reduce_events(p)
-    mods = PG.modules(p, *s.host_spans[TR.WINDOW_SPAN][0])
+    mods = TR.reduce_events(planes()).modules
     by = PG.seconds_by_program(mods)
     assert by["gen_decode"] == (2, pytest.approx(0.12))
     it = {n: PG.per_iteration(mods, n, 2) for n in by}
@@ -62,17 +62,18 @@ def test_module_seconds_per_program_and_iteration():
     assert it["ppo_critic_step"] == pytest.approx(0.004)
     assert it["ema_update"] == pytest.approx(0.002)
     # 120 ms of decode over 8 counted steps
-    assert PG.decode_step_ms(mods, 8) == pytest.approx(15.0)
-    assert PG.decode_step_ms(mods, 0) is None
+    assert PG.decode_step_ms(mods, {"gen.decode_steps": 8}) == (
+        pytest.approx(15.0))
+    assert PG.decode_step_ms(mods, {"gen.decode_steps": 0}) is None
+    assert PG.decode_step_ms(mods, {}) is None
 
 
 def test_per_iteration_is_none_unless_once_per_iteration():
-    p = planes()
-    mods = PG.modules(p, 0.0, 0.2)
+    mods = TR.reduce_events(planes()).modules
     assert PG.per_iteration(mods, "gen_decode", 3) is None
     assert PG.per_iteration(mods, "no_such_program", 2) is None
     # a window that cuts the second iteration short clips its programs
-    cut = PG.modules(p, 0.0, 0.15)
+    cut = TR.reduce_events(planes(window_ms=150)).modules
     assert PG.seconds_by_program(cut)["gen_decode"] == (
         2, pytest.approx(0.060 + 0.040))
 
@@ -81,13 +82,13 @@ def test_modules_only_from_tpu_planes():
     p = planes()
     p.append(("/device:TPU:0 SparseCore 0",
               [("XLA Modules", [Ev("jit_gen_decode(13)", 0, 5 * MS)])]))
-    mods = PG.modules(p, 0.0, 0.2)
+    mods = TR.reduce_events(p).modules
     assert PG.seconds_by_program(mods)["gen_decode"][0] == 2
 
 
 def test_idle_by_span_puts_idle_in_innermost_program_span():
     s = TR.reduce_events(planes())
-    idle = dict(PG.idle_by_span(s))
+    idle = dict(s.idle_by_span)
     # per iteration, each hole to the shortest program span over its
     # midpoint: 2-3 ms to gen.prefill (2.5 is also hybrid.to_inference's
     # end), 70-72 ms to gen.assemble, 98-100 ms to ppo.train_read (the
@@ -96,7 +97,7 @@ def test_idle_by_span_puts_idle_in_innermost_program_span():
     assert idle["gen.assemble"] == pytest.approx(0.004)
     assert idle["ppo.train_read"] == pytest.approx(0.004)
     assert sum(idle.values()) == pytest.approx(0.2 - s.busy_s)
-    assert PG.OUTSIDE not in idle
+    assert TR.OUTSIDE not in idle
 
 
 def test_idle_outside_spans_and_existing_gaps_unchanged():
@@ -106,15 +107,18 @@ def test_idle_outside_spans_and_existing_gaps_unchanged():
         e for e in host if e.name not in ("ppo.train_read",
                                           "ppo.train_rlhf")])])
     s = TR.reduce_events(p)
-    idle = dict(PG.idle_by_span(s))
+    idle = dict(s.idle_by_span)
     # 98-100 ms: only a Python frame covers it, which is no program span
-    assert idle[PG.OUTSIDE] == pytest.approx(0.002)
+    assert idle[TR.OUTSIDE] == pytest.approx(0.002)
     # the harness's own reduction is untouched by the module line and the
     # program spans: idle_gaps still label by the innermost host event
     gaps = dict(s.idle_gaps)
     assert gaps["$ppo.py:500 train_rlhf"] == pytest.approx(0.002)
     assert sum(gaps.values()) == pytest.approx(s.window_s - s.busy_s)
-    assert set(TR.breakdown(s)) == {"device_ops", "idle_gaps"}
+    bd = TR.breakdown(s)
+    assert set(bd) == {"device_ops", "idle_gaps", "idle_by_span"}
+    assert bd["idle_gaps"] == [[n, t] for n, t in s.idle_gaps]
+    assert bd["idle_by_span"] == [[n, t] for n, t in s.idle_by_span]
 
 
 def _sp(name, a, b, parent=None, step=0):
@@ -161,3 +165,32 @@ def test_stage3_split_tool_on_a_tiny_cell():
     assert tr["per_iteration_s"]["gen_decode"] is None
     assert tr["decode_step_ms"] is None
     assert tr["window_s"] > 0
+
+
+def _record(**over):
+    rec = {"trace": TR.reduce_events(planes()), "trace_iterations": 2,
+           "program": {"spans": [], "counters": {"gen.decode_steps": 8}}}
+    return dict(rec, **over)
+
+
+def test_decode_step_reader():
+    from bench.lib import spec as SP
+    read = SP.load_metric_reader("stage3.decode_step_ms")
+    # 2 x 60 ms of gen_decode over 8 counted steps
+    assert read(_record()) == pytest.approx(15.0)
+    assert read(_record(trace=None)) is None
+    assert read(_record(program=None)) is None
+    assert read(_record(program={"spans": [], "counters": {}})) is None
+    no_tpu = [pl for pl in planes() if pl[0].startswith("/host:")]
+    assert read(_record(trace=TR.reduce_events(no_tpu))) is None
+
+
+def test_actor_step_reader():
+    from bench.lib import spec as SP
+    read = SP.load_metric_reader("stage3.actor_step_s")
+    # 12 ms of ppo_actor_step in each of the 2 traced iterations
+    assert read(_record()) == pytest.approx(0.012)
+    assert read(_record(trace=None)) is None
+    assert read(_record(trace_iterations=3)) is None
+    no_tpu = [pl for pl in planes() if pl[0].startswith("/host:")]
+    assert read(_record(trace=TR.reduce_events(no_tpu))) is None

@@ -55,6 +55,7 @@ def test_last_line_shape(name, trace):
         assert set(m) == {"value", "unit"}
     if trace:
         assert {"busy_s", "window_s"} <= set(dev)
-        assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
+        assert set(line["breakdown"]) == {"device_ops", "idle_gaps",
+                                          "idle_by_span"}
     for c in line["checks"].values():
         assert set(c) == {"value", "limit"}

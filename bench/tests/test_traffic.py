@@ -46,9 +46,10 @@ def test_weights_deterministic_and_seed_keeps_high_bits():
     import jax
     m = {"n_layers": 1, "d_model": 8, "n_heads": 2, "n_kv_heads": 1,
          "head_dim": 4, "d_ff": 16, "vocab_size": 32}
-    a = W.make({"model": m}, {"model": "lm"}, 2 ** 40 + 1)
-    b = W.make({"model": m}, {"model": "lm"}, 2 ** 40 + 1)
-    c = W.make({"model": m}, {"model": "lm"}, 1)
+    init = SP.load_reference(CELL.config["reference"]).init
+    a = W.make({"model": m}, {"model": "lm"}, 2 ** 40 + 1, init)
+    b = W.make({"model": m}, {"model": "lm"}, 2 ** 40 + 1, init)
+    c = W.make({"model": m}, {"model": "lm"}, 1, init)
     la, lb, lc = (jax.tree.leaves(x) for x in (a, b, c))
     assert all(np.array_equal(x, y) for x, y in zip(la, lb))
     assert not np.array_equal(la[0], lc[0])

@@ -6,6 +6,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import sys
+import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -43,3 +44,16 @@ def run_tiny(cell, seed=7, seconds=1.0, trace=0):
     args = argparse.Namespace(workload=cell.name, seed=seed,
                               seconds=seconds, trace=trace)
     return R.run_cell(cell, args, jax, jax.devices()[:1], None)
+
+
+def run_kind(cell, seed=7, seconds=1.0, trace=0) -> dict:
+    """What the kind's ``run`` returns for one run of ``cell`` on the CPU
+    (its record among it), skipping the look for a chip."""
+    import jax
+    from bench import run as R
+    from bench.lib.compile_log import CompileLog
+    args = argparse.Namespace(workload=cell.name, seed=seed,
+                              seconds=seconds, trace=trace)
+    ctx = R.Context(cell, args, jax, jax.devices()[:1], CompileLog(),
+                    time.perf_counter())
+    return SP.load_kind(cell.kind, cell.root).run(ctx)

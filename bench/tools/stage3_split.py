@@ -12,16 +12,18 @@ with the tracer (``repro.obs``) off and on in turn: ``ppo_iter_s`` of
 each and its slow iterations, and over the windows with it on the
 largest stall of a ``ppo.*`` root span and the child span that took it
 (``bench.lib.programs.stall``).
-Last it traces the cell's ``trace_iterations`` with the tracer on and
-reads from the trace: device seconds per named program per iteration,
-``gen_decode`` per decode step, and the idle seconds by the innermost
-program span (``bench.lib.programs``).  Prints one JSON object.  Benchmark runs never call this.
+Last it traces the cell's ``trace_iterations`` as a ``--trace 1`` run
+does (``ppo_fixed.traced``) and reads from the trace: device seconds per
+named program per iteration, ``gen_decode`` per decode step
+(``bench.lib.programs``, as the per-layer readers do), and the idle
+seconds by the innermost program span (``bench.lib.trace``).  Prints one
+JSON object.  Benchmark runs never call this.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import json
-import shutil
 import statistics
 import sys
 import time
@@ -51,7 +53,6 @@ def window(prog, i, seconds, annotate, obs, on):
     t0 = time.perf_counter()
     try:
         while True:
-            obs.step(i)
             t = time.perf_counter()
             prog.iteration(i, annotate)
             rows.append((i, time.perf_counter() - t))
@@ -102,45 +103,26 @@ def split(cell, seed: int, seconds: float, rounds: int, jax) -> dict:
     out["host_span_median_s"] = host_medians(snap["spans"])
 
     # ---- the traced iterations, tracer on ---- #
-    obs.reset()
-    obs.enable()
-    shutil.rmtree(R.TRACE_DIR, ignore_errors=True)
-    jax.profiler.start_trace(str(R.TRACE_DIR))
-    try:
-        with annotate(TR.WINDOW_SPAN):
-            for _ in range(tr["trace_iterations"]):
-                obs.step(i)
-                prog.iteration(i, annotate)
-                i += 1
-    finally:
-        jax.profiler.stop_trace()
-        obs.disable()
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(TR.find_xplane(str(R.TRACE_DIR)))
-    planes = [(pl.name, [(ln.name, list(ln.events)) for ln in pl.lines])
-              for pl in pd.planes]
-    shutil.rmtree(R.TRACE_DIR, ignore_errors=True)
-    s = TR.reduce_events(planes)
-    w0, w1 = s.host_spans[TR.WINDOW_SPAN][0]
-    mods = PG.modules(planes, w0, w1)
     n = tr["trace_iterations"]
-    snap = obs.snapshot()
-    steps = snap["counters"].get("gen.decode_steps", 0)
-    idle = PG.idle_by_span(s)
+    s, program = kind.traced(prog, i, n, functools.partial(R.tracing, jax),
+                             annotate)
+    steps = program["counters"].get("gen.decode_steps", 0)
+    idle = s.idle_by_span
     idle_s = sum(t for _, t in idle)
     out["trace"] = {
         "window_s": s.window_s, "busy_s": s.busy_s,
         "programs": {k: [c, t / n] for k, (c, t) in sorted(
-            PG.seconds_by_program(mods).items(), key=lambda kv: -kv[1][1])},
-        "per_iteration_s": {p: PG.per_iteration(mods, p, n)
+            PG.seconds_by_program(s.modules).items(),
+            key=lambda kv: -kv[1][1])},
+        "per_iteration_s": {p: PG.per_iteration(s.modules, p, n)
                             for p in PROGRAMS},
-        "decode_step_ms": PG.decode_step_ms(mods, steps),
+        "decode_step_ms": PG.decode_step_ms(s.modules, program["counters"]),
         "decode_steps": steps,
         "idle_by_span": idle,
-        "idle_in_spans_share": (1.0 - dict(idle).get(PG.OUTSIDE, 0.0)
+        "idle_in_spans_share": (1.0 - dict(idle).get(TR.OUTSIDE, 0.0)
                                 / idle_s) if idle_s else None,
         "idle_gaps": s.idle_gaps[:10],
-        "host_span_median_s": host_medians(snap["spans"]),
+        "host_span_median_s": host_medians(program["spans"]),
     }
     obs.reset()
     return out
