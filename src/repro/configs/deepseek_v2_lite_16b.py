@@ -1,12 +1,10 @@
-"""deepseek-v2-lite-16b [moe] — 27L d_model=2048 16H d_ff(expert)=1408
-vocab=102400; MLA kv_lora=512; MoE 2 shared + 64 routed, top-6.
-[arXiv:2405.04434]
-
-Spec note: the assignment's bracket text says "160 routed"; the primary
-spec line says "MoE 64e top-6", which matches the real DeepSeek-V2-Lite
-(64 routed + 2 shared).  We follow the primary spec.  (Deviation from the
-HF checkpoint: the real model's layer-0 MLP is dense d_ff=10944; we keep
-all 27 layers MoE for a homogeneous scan — noted per DESIGN.md.)
+"""deepseek-v2-lite-16b [moe] — 27L d_model=2048 16H; layer 0 a dense
+SwiGLU MLP of width 10944, layers 1-26 MoE: a softmax router over 64
+routed experts of width 1408, greedy top-6 without renormalisation, and 2
+shared experts; MLA (no q-LoRA, kv_lora 512, qk 128 + 64 rope, v 128)
+with YaRN rope scaling (factor 40, mscale 0.707 on both sides, original
+4096 positions); rms eps 1e-6; vocab 102400, untied head.
+[arXiv:2405.04434; huggingface.co/deepseek-ai/DeepSeek-V2-Lite config.json]
 """
 from repro.models.config import ModelConfig
 
@@ -18,8 +16,15 @@ CONFIG = ModelConfig(
     n_heads=16,
     n_kv_heads=16,
     head_dim=128,
-    d_ff=1408,
+    d_ff=10944,
     vocab_size=102400,
+    rope_theta=10000.0,
+    yarn_factor=40.0,
+    yarn_mscale=0.707,
+    yarn_mscale_all_dim=0.707,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_original_len=4096,
     mla=True,
     kv_lora_rank=512,
     qk_nope_head_dim=128,
@@ -28,8 +33,11 @@ CONFIG = ModelConfig(
     moe=True,
     n_experts=64,
     top_k=6,
+    norm_topk=False,
     n_shared_experts=2,
     moe_d_ff=1408,
+    n_dense_layers=1,
     capacity_factor=1.25,
+    rms_eps=1e-6,
     logit_chunk=512,
 )

@@ -10,7 +10,10 @@ from repro import obs
 
 
 def init(params):
-    return jax.tree.map(lambda p: p.astype(jnp.float32), params)
+    """A float32 copy of ``params`` (never the same arrays: a trainer
+    may donate its params)."""
+    return jax.tree.map(lambda p: jnp.array(p, jnp.float32, copy=True),
+                        params)
 
 
 def _update(ema, params, decay: float = 0.992):

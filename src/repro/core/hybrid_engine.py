@@ -106,8 +106,11 @@ class HybridEngine:
 
     def to_inference(self, params):
         """Enter generation mode: ONE all-gather pass over the params,
-        measured (bytes + wall time) into ``last_reshard_stats``."""
-        return self._reshard(self._to_infer, params, "to_inference")
+        measured (bytes + wall time) into ``last_reshard_stats``.  On one
+        device both layouts are the same, and ``params`` are handed over
+        as they are: no copy of the actor is made for generation."""
+        fn = (lambda p: p) if self.mesh.size == 1 else self._to_infer
+        return self._reshard(fn, params, "to_inference")
 
     def to_train(self, params):
         """Back to training mode (a slice per param — no communication

@@ -174,7 +174,10 @@ class RLHFPipeline:
             ref_params=self.e.ref_params,
             reward_params=self.e.reward_params,
             ppo=self.ppo, engine=self.e.hybrid,
-            rollout_mesh=getattr(self.e, "rollout_mesh", None))
+            rollout_mesh=getattr(self.e, "rollout_mesh", None),
+            # the async weight publisher retains earlier actor trees by
+            # reference, which a donated step would free
+            donate=self.async_cfg is None)
         if restored is not None:
             trainer.load_state_tree(restored["trainer"])
         ptx_iter = (self.blender.pretrain_batches(st.ppo_batch,
@@ -355,8 +358,12 @@ class RLHFPipeline:
                 "ema": (jax.eval_shape(EMA.init, self.e.actor_params)
                         if self.ppo.use_ema else None),
             },
-            "ref": jax.eval_shape(lambda t: t, self.e.actor_params),
-            "reward": jax.eval_shape(lambda t: t, self.e.critic_params),
+            # the trainer holds the frozen models as their compute-dtype
+            # cast, and saves them so
+            "ref": jax.eval_shape(partial(R.frozen, self.e.actor_cfg),
+                                  self.e.actor_params),
+            "reward": jax.eval_shape(partial(R.frozen, self.e.critic_cfg),
+                                     self.e.critic_params),
             "rng": np.asarray(key),
         }
         tree, meta = self.ckpt.restore(like, step=step)

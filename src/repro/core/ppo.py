@@ -30,7 +30,7 @@ from repro.core.hybrid_engine import HybridEngine
 from repro.models import reward as R
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
-from repro.serving.engine import GenerationEngine
+from repro.serving.engine import GenerationEngine, count_moe
 from repro.training.steps import lm_loss_fn
 from repro.training.train_state import TrainState
 
@@ -77,15 +77,24 @@ class PPOConfig:
 # ===================================================================== #
 # Pure loss / step functions (jitted once per shape)
 # ===================================================================== #
-def actor_logprobs(cfg: ModelConfig, params, sequences):
-    hidden, _, _ = T.forward(cfg, params, tokens=sequences, mode="full")
-    return T.per_token_logprobs(cfg, params, hidden[:, :-1],
-                                sequences[:, 1:])
+# the step metrics' key for a MoE model's counts (held slots, held
+# experts given a slot), taken out of the metrics into the tracer's
+# counters when the host reads the step's values
+MOE_COUNTS = "moe_counts"
+
+
+def actor_logprobs(cfg: ModelConfig, params, sequences, moe_counts=False):
+    """log p of each next token; with ``moe_counts`` also the forward's
+    MoE counts (:func:`repro.models.transformer.forward`)."""
+    hidden, _, _, counts = T.forward(cfg, params, tokens=sequences,
+                                     mode="full", moe_counts=True)
+    lp = T.per_token_logprobs(cfg, params, hidden[:, :-1], sequences[:, 1:])
+    return (lp, counts) if moe_counts else lp
 
 
 def actor_loss_fn(cfg: ModelConfig, ppo: PPOConfig, params, exp: X.Experience,
                   ptx_batch=None):
-    logp = actor_logprobs(cfg, params, exp.sequences)
+    logp, counts = actor_logprobs(cfg, params, exp.sequences, moe_counts=True)
     ratio = jnp.exp(logp - exp.logprobs)
     if ppo.is_clip is not None:
         ratio = jnp.clip(ratio, 1.0 / ppo.is_clip, ppo.is_clip)
@@ -98,6 +107,8 @@ def actor_loss_fn(cfg: ModelConfig, ppo: PPOConfig, params, exp: X.Experience,
     metrics = {"pg_loss": pg_loss,
                "ratio_mean": (ratio * exp.mask).sum() / n,
                "approx_kl": ((exp.logprobs - logp) * exp.mask).sum() / n}
+    if T.moe_layers(cfg):
+        metrics[MOE_COUNTS] = counts
     if ptx_batch is not None and ppo.ptx_coef > 0:
         ptx, _ = lm_loss_fn(cfg, params, ptx_batch)
         loss = loss + ppo.ptx_coef * ptx
@@ -107,14 +118,17 @@ def actor_loss_fn(cfg: ModelConfig, ppo: PPOConfig, params, exp: X.Experience,
 
 def critic_loss_fn(cfg: ModelConfig, ppo: PPOConfig, params,
                    exp: X.Experience):
-    v = R.values(cfg, params, exp.sequences)[:, :-1]
+    v, counts = R.values(cfg, params, exp.sequences, moe_counts=True)
+    v = v[:, :-1]
     v_clip = exp.values + jnp.clip(v - exp.values, -ppo.value_clip,
                                    ppo.value_clip)
     n = jnp.maximum(exp.mask.sum(), 1.0)
     l = jnp.maximum((v - exp.returns) ** 2, (v_clip - exp.returns) ** 2)
     loss = 0.5 * (l * exp.mask).sum() / n
-    return loss, {"v_loss": loss,
-                  "v_mean": (v * exp.mask).sum() / n}
+    metrics = {"v_loss": loss, "v_mean": (v * exp.mask).sum() / n}
+    if T.moe_layers(cfg):
+        metrics[MOE_COUNTS] = counts
+    return loss, metrics
 
 
 def actor_step(cfg: ModelConfig, ppo: PPOConfig, state: TrainState,
@@ -138,19 +152,30 @@ def critic_step(cfg: ModelConfig, ppo: PPOConfig, state: TrainState,
 def make_experience(actor_cfg: ModelConfig, critic_cfg: ModelConfig,
                     ppo: PPOConfig, actor_params, ref_params, critic_params,
                     reward_params, sequences, response_mask,
-                    attn_mask=None) -> X.Experience:
+                    attn_mask=None):
     """Score a generated batch: logprobs, ref logprobs, values, rewards,
     GAE.  Pure function — jitted by the trainer; also the dry-run's
     'experience scoring' graph.  ``attn_mask`` marks the real (prompt +
     generated) tokens of each row so the reward model scores the last
     real token; ``None`` means the batch has no padding tail (the
-    fixed-shape path)."""
-    logp = actor_logprobs(actor_cfg, actor_params, sequences)
-    ref_logp = actor_logprobs(actor_cfg, ref_params, sequences)
-    values = R.values(critic_cfg, critic_params, sequences)[:, :-1]
+    fixed-shape path).  Returns (experience, reward scores, the MoE
+    counts of each role whose model has MoE layers)."""
     if attn_mask is None:
         attn_mask = jnp.ones(sequences.shape, jnp.float32)
-    score = R.end_scores(critic_cfg, reward_params, sequences, attn_mask)
+    moe = {}
+
+    def run(role, cfg, fn, *args):
+        out, counts = fn(cfg, *args, moe_counts=True)
+        if T.moe_layers(cfg):
+            moe[role] = counts
+        return out
+
+    logp = run("actor", actor_cfg, actor_logprobs, actor_params, sequences)
+    ref_logp = run("ref", actor_cfg, actor_logprobs, ref_params, sequences)
+    values = run("critic", critic_cfg, R.values, critic_params,
+                 sequences)[:, :-1]
+    score = run("reward", critic_cfg, R.end_scores, reward_params,
+                sequences, attn_mask)
     mask = response_mask[:, 1:].astype(jnp.float32)
     rewards = X.kl_rewards(logp, ref_logp, mask, score,
                            kl_coef=ppo.kl_coef,
@@ -159,7 +184,7 @@ def make_experience(actor_cfg: ModelConfig, critic_cfg: ModelConfig,
     return X.Experience(sequences=sequences, logprobs=logp,
                         ref_logprobs=ref_logp, values=values,
                         rewards=rewards, advantages=adv, returns=ret,
-                        mask=mask), score
+                        mask=mask), score, moe
 
 
 def staleness_guard_stats(cfg: ModelConfig, params, sequences,
@@ -179,20 +204,39 @@ def staleness_guard_stats(cfg: ModelConfig, params, sequences,
 # ===================================================================== #
 # Trainer
 # ===================================================================== #
+def _own(tree):
+    """Copies of the arrays of ``tree``, which the trainer may donate."""
+    return jax.tree.map(lambda x: jnp.array(x, copy=True), tree)
+
+
 class PPOTrainer:
+    """Stage-3 trainer.  The frozen reference and reward models are held
+    as their compute-dtype cast (:func:`repro.models.reward.frozen`:
+    all but the embedding table), made once here: what every forward
+    reads of them, in about half the memory of fp32 masters.  On one
+    device the actor and critic steps
+    donate their train state, so the old and the new state are never
+    held at once; the states start as copies of the arrays handed in,
+    so no caller's array is donated.  ``donate=False`` keeps every
+    state the steps replace alive, for holders of earlier states (the
+    async pipeline's weight publisher retains them by reference)."""
+
     def __init__(self, *, actor_cfg: ModelConfig, critic_cfg: ModelConfig,
                  actor_params, critic_params, ref_params, reward_params,
                  ppo: PPOConfig, engine: Optional[HybridEngine] = None,
-                 rollout_mesh=None):
+                 rollout_mesh=None, donate: bool = True):
         self.actor_cfg, self.critic_cfg, self.ppo = actor_cfg, critic_cfg, ppo
-        self.actor = TrainState.create(actor_params)
-        self.critic = TrainState.create(critic_params)
-        self.ref_params = ref_params
-        self.reward_params = reward_params
         self.engine = engine
         self.mesh = engine.mesh if engine is not None else None
         self._multi = (self.mesh is not None and int(np.prod(
             list(self.mesh.shape.values()))) > 1)
+        self._donate = donate and not self._multi
+        if self._donate:
+            actor_params, critic_params = _own((actor_params, critic_params))
+        self.actor = TrainState.create(actor_params)
+        self.critic = TrainState.create(critic_params)
+        self.ref_params = R.frozen(actor_cfg, ref_params)
+        self.reward_params = R.frozen(critic_cfg, reward_params)
 
         if self._multi:
             from repro.sharding import strategy as S
@@ -206,10 +250,11 @@ class PPOTrainer:
             self.critic = engine.shard_train_state(self.critic, critic_cfg,
                                                    specs=crit_specs)
             self.ref_params = jax.device_put(
-                ref_params, S.param_shardings(actor_cfg, self.mesh, "tp"))
+                self.ref_params, S.param_shardings(actor_cfg, self.mesh,
+                                                   "tp"))
             self.reward_params = jax.device_put(
-                reward_params, S.shardings_for_tree(crit_specs, self.mesh,
-                                                    "tp"))
+                self.reward_params, S.shardings_for_tree(crit_specs,
+                                                         self.mesh, "tp"))
             # activation constraints inside the loss forwards: batch over
             # `data` (keeps GSPMD from replicating activations)
             actor_cfg = actor_cfg.replace(batch_axes=("data",),
@@ -266,8 +311,10 @@ class PPOTrainer:
             self._actor_step = jax.jit(a_step, out_shardings=(a_sh, rep))
             self._critic_step = jax.jit(c_step, out_shardings=(c_sh, rep))
         else:
-            self._actor_step = jax.jit(a_step)
-            self._critic_step = jax.jit(c_step)
+            # the state is rebound from the step's result: donate it
+            donate = (0,) if self._donate else ()
+            self._actor_step = jax.jit(a_step, donate_argnums=donate)
+            self._critic_step = jax.jit(c_step, donate_argnums=donate)
         # staleness telemetry (async mode, lag > 0 only)
         self._guard = jax.jit(obs.named(
             "ppo_staleness_guard", partial(staleness_guard_stats, actor_cfg)))
@@ -380,12 +427,18 @@ class PPOTrainer:
             extra = (attn,)
         with obs.span("ppo.score_rollout"):
             with obs.span("ppo.make_experience"), self._mesh_ctx():
-                exp, score = self._mk_exp(behavior, self.ref_params,
-                                          self.critic.params,
-                                          self.reward_params, seqs, mask,
-                                          *extra)
+                exp, score, moe = self._mk_exp(behavior, self.ref_params,
+                                               self.critic.params,
+                                               self.reward_params, seqs,
+                                               mask, *extra)
             with obs.span("ppo.score_read"):
-                sm = {"reward_score": float(score.mean())}
+                mean, moe = jax.device_get((score.mean(), moe))
+                sm = {"reward_score": float(mean)}
+                cfgs = {"actor": self.actor_cfg, "ref": self.actor_cfg,
+                        "critic": self.critic_cfg,
+                        "reward": self.critic_cfg}
+                for role, c in moe.items():
+                    count_moe("score", role, cfgs[role], seqs.size, c)
         if policy_lag is not None:
             sm["policy_lag"] = float(policy_lag)
             if policy_lag > 0:
@@ -541,6 +594,12 @@ class PPOTrainer:
                                           self.ppo.ema_decay)
             # the host's wait for the device: the step metrics' values
             with obs.span("ppo.train_read"):
+                am, cm = jax.device_get((am, cm))
+                for role, cfg, m in (("actor", self.actor_cfg, am),
+                                     ("critic", self.critic_cfg, cm)):
+                    if MOE_COUNTS in m:
+                        count_moe("train", role, cfg, exp.sequences.size,
+                                  m.pop(MOE_COUNTS))
                 return {**{k: float(v) for k, v in am.items()},
                         **{k: float(v) for k, v in cm.items()}}
 

@@ -28,7 +28,7 @@ NEG_INF = -1e30
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            causal, window, k_block, nk, q_offset, lk_valid):
+            causal, window, k_block, nk, q_offset, lk_valid, sm_scale):
     ik = pl.program_id(4)
     iq = pl.program_id(3)
 
@@ -45,7 +45,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    s = s * (1.0 / np.sqrt(D))                        # (qb, kb)
+    s = s * (1.0 / np.sqrt(D) if sm_scale is None else sm_scale)
 
     qpos = q_offset + iq * qb + jax.lax.broadcasted_iota(
         jnp.int32, (qb, k_block), 0)
@@ -75,10 +75,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=None,
                         q_block=256, k_block=512, q_offset=None,
-                        lk_valid=None, interpret=False):
+                        lk_valid=None, sm_scale=None, interpret=False):
     """q: (B, KV, G, Lq, D); k, v: (B, KV, Lk, D).  ``lk_valid`` (default
     Lk) counts the real keys; ``q_offset`` (default ``lk_valid - Lq``) is
-    the absolute position of query row 0."""
+    the absolute position of query row 0; ``sm_scale`` (default
+    1/sqrt(D)) multiplies the scores."""
     B, KV, G, Lq, D = q.shape
     Lk = k.shape[2]
     lk_valid = Lk if lk_valid is None else lk_valid
@@ -92,7 +93,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
 
     kernel = functools.partial(_kernel, causal=causal, window=window,
                                k_block=k_block, nk=nk, q_offset=q_offset,
-                               lk_valid=lk_valid)
+                               lk_valid=lk_valid, sm_scale=sm_scale)
     return pl.pallas_call(
         kernel,
         grid=grid,

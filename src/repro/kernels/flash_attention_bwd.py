@@ -57,7 +57,8 @@ def _positions(iq, ik, q_block, k_block, q_offset):
 
 def _dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *,
-                causal, window, q_block, k_block, nq, lk_valid, q_offset):
+                causal, window, q_block, k_block, nq, lk_valid, q_offset,
+                sm_scale):
     iq = pl.program_id(3)
     ik = pl.program_id(2)
 
@@ -71,7 +72,7 @@ def _dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     qpos, kpos = _positions(iq, ik, q_block, k_block, q_offset)
-    scale = 1.0 / np.sqrt(D)
+    scale = 1.0 / np.sqrt(D) if sm_scale is None else sm_scale
     # the G query heads of this KV group all sum into the same dK/dV
     for gi in range(G):
         q = q_ref[0, 0, gi].astype(jnp.float32)      # (qb, D)
@@ -94,7 +95,8 @@ def _dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
 
 def _dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
                dq_ref, dq_acc, *,
-               causal, window, q_block, k_block, nk, lk_valid, q_offset):
+               causal, window, q_block, k_block, nk, lk_valid, q_offset,
+               sm_scale):
     iq = pl.program_id(2)
     ik = pl.program_id(3)
 
@@ -107,7 +109,7 @@ def _dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     qpos, kpos = _positions(iq, ik, q_block, k_block, q_offset)
-    scale = 1.0 / np.sqrt(D)
+    scale = 1.0 / np.sqrt(D) if sm_scale is None else sm_scale
     for gi in range(G):
         q = q_ref[0, 0, gi].astype(jnp.float32)      # (qb, D)
         g = g_ref[0, 0, gi].astype(jnp.float32)
@@ -125,10 +127,10 @@ def _dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
 
 def flash_attention_bwd(q, k, v, g, lse, delta, *, causal=True, window=None,
                         q_block=256, k_block=256, q_offset=None,
-                        lk_valid=None, interpret=False):
+                        lk_valid=None, sm_scale=None, interpret=False):
     """q, g: (B, KV, G, Lq, D); k, v: (B, KV, Lk, D);
     lse, delta: (B, KV, G, Lq).  Returns (dq, dk, dv).  ``q_offset`` and
-    ``lk_valid`` as in :func:`flash_attention_fwd`."""
+    ``lk_valid`` and ``sm_scale`` as in :func:`flash_attention_fwd`."""
     B, KV, G, Lq, D = q.shape
     Lk = k.shape[2]
     q_block = min(q_block, Lq)
@@ -139,7 +141,8 @@ def flash_attention_bwd(q, k, v, g, lse, delta, *, causal=True, window=None,
     q_offset = lk_valid - Lq if q_offset is None else q_offset
 
     common = dict(causal=causal, window=window, q_block=q_block,
-                  k_block=k_block, lk_valid=lk_valid, q_offset=q_offset)
+                  k_block=k_block, lk_valid=lk_valid, q_offset=q_offset,
+                  sm_scale=sm_scale)
     q_spec = pl.BlockSpec((1, 1, G, q_block, D),
                           lambda b, h, ik, iq: (b, h, 0, iq, 0))
     kv_spec_dkv = pl.BlockSpec((1, 1, k_block, D),

@@ -54,11 +54,12 @@ def _pad_axis(x, axis: int, size: int):
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, q_block=256,
-                    k_block=512):
+                    k_block=512, sm_scale=None):
     """Differentiable Pallas attention in the model layout.
 
     q: (B, L, H, D); k, v: (B, Lk, KV, D) -> (B, L, H, D).  Queries are
-    the last L of the Lk key positions.  The query tile is a multiple of
+    the last L of the Lk key positions; ``sm_scale`` (default 1/sqrt(D))
+    multiplies the scores.  The query tile is a multiple of
     128 (it is the lane dim of the backward's lse/delta blocks) and the
     key tile a multiple of 8, or the whole axis."""
     B, Lq, H, D = q.shape
@@ -69,10 +70,34 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_block=256,
     q5 = jnp.moveaxis(q.reshape(B, Lq, KV, G, D), 1, 3)   # (B,KV,G,Lq,D)
     k4 = jnp.moveaxis(k, 1, 2)                            # (B,KV,Lk,D)
     v4 = jnp.moveaxis(v, 1, 2)
-    meta = (bool(causal), window, qb, kb, Lk - Lq, Lk)
+    meta = (bool(causal), window, qb, kb, Lk - Lq, Lk, sm_scale)
     o = _flash_pallas(meta, _pad_axis(q5, 3, Lq_p), _pad_axis(k4, 2, Lk_p),
                       _pad_axis(v4, 2, Lk_p))[:, :, :, :Lq]
     return jnp.moveaxis(o, 3, 1).reshape(B, Lq, H, D)
+
+
+def grouped_matmul(x, w, sizes):
+    """Grouped matmul by the Pallas megablox kernel: x (M, K) rows sorted
+    by group, w (G, K, N), sizes (G,) int32 rows per group, summing to at
+    most M.  The kernel's grid visits only the row tiles that hold a
+    group's rows, and reads the matrices of those groups alone, so rows
+    past ``sizes.sum()`` and empty groups cost nothing (the rows' output
+    is left undefined).  Tiles from :func:`gmm_tiling`.  Differentiable
+    (the kernel's own VJP: grouped matmuls for dx, a transposed one for
+    dw)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    M = x.shape[0]
+    tm, tk, tn = gmm_tiling(M, *w.shape[1:])
+    M_p = -(-M // tm) * tm
+    out = gmm(_pad_axis(x, 0, M_p), w, sizes.astype(jnp.int32), x.dtype,
+              (tm, tk, tn), None, None, False, _interpret())
+    return out[:M]
+
+
+def gmm_tiling(M: int, K: int, N: int) -> tuple:
+    """(tm, tk, tn) of :func:`grouped_matmul` for ``M`` rows times
+    (K, N) matrices."""
+    return (128 if M <= 1024 else 256, min(512, K), min(512, N))
 
 
 def decode_block(S: int) -> int:
@@ -231,25 +256,27 @@ def ssd_scan(X, dt, A, B, C, chunk, initial_state=None):
 # Differentiable Pallas attention (fwd + bwd kernels, custom VJP) — the
 # TPU training path behind ``flash_attention``.  Grouped layout: q
 # (B, KV, G, Lq, D), k/v (B, KV, Lk, D).  ``meta`` = (causal, window,
-# q_block, k_block, q_offset, lk_valid): query row 0 sits at absolute
-# position q_offset and keys at or past lk_valid are padding.
+# q_block, k_block, q_offset, lk_valid, sm_scale): query row 0 sits at
+# absolute position q_offset, keys at or past lk_valid are padding, and
+# sm_scale (None: 1/sqrt(D)) multiplies the scores.
 # ===================================================================== #
 def _fwd_with_lse(meta, q, k, v):
     """Forward kernel + lse recovery.  The fwd kernel keeps (m, l) in
     scratch; for the residual we recompute lse with the jnp oracle's
     blocked pass (cheap relative to bwd, avoids a second kernel output
     plumbing in interpret mode)."""
-    causal, window, qb, kb, q_offset, lk_valid = meta
+    causal, window, qb, kb, q_offset, lk_valid, sm_scale = meta
     out = _fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
                                   q_block=qb, k_block=kb, q_offset=q_offset,
-                                  lk_valid=lk_valid, interpret=_interpret())
+                                  lk_valid=lk_valid, sm_scale=sm_scale,
+                                  interpret=_interpret())
     B, KV, G, Lq, D = q.shape
     from repro.models.modules import _flash_fwd_impl
     qm = jnp.moveaxis(q, 3, 1).reshape(B, Lq, KV * G, D)
     km = jnp.moveaxis(k[:, :, :lk_valid], 2, 1)
     vm = jnp.moveaxis(v[:, :, :lk_valid], 2, 1)
     _, lse = _flash_fwd_impl((causal, window, qb, min(kb, lk_valid),
-                              q_offset), qm, km, vm)
+                              q_offset, sm_scale), qm, km, vm)
     return out, lse
 
 
@@ -264,13 +291,13 @@ def _flash_pallas_fwd(meta, q, k, v):
 
 
 def _flash_pallas_bwd(meta, res, g):
-    causal, window, qb, kb, q_offset, lk_valid = meta
+    causal, window, qb, kb, q_offset, lk_valid, sm_scale = meta
     q, k, v, out, lse = res
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), -1)
     dq, dk, dv = _fab.flash_attention_bwd(
         q, k, v, g, lse, delta, causal=causal, window=window,
         q_block=qb, k_block=kb, q_offset=q_offset, lk_valid=lk_valid,
-        interpret=_interpret())
+        sm_scale=sm_scale, interpret=_interpret())
     return dq, dk, dv
 
 

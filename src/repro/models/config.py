@@ -10,11 +10,13 @@ flat in depth and is what makes the 512-device dry-runs tractable.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # Layer kinds.
 ATTN = "attn"        # self-attention (GQA / qk-norm / sliding-window / MLA)
@@ -56,6 +58,14 @@ class ModelConfig:
     # --- attention ---
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    # YaRN context extension (DeepSeek-V2's ``rope_scaling`` of type
+    # "yarn"): ``yarn_factor`` 0 leaves RoPE as it is
+    yarn_factor: float = 0.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_original_len: int = 4096
     sliding_window: Optional[int] = None   # if set, ALL attn layers are SW
     # MLA (DeepSeek-V2 style multi-head latent attention)
     mla: bool = False
@@ -66,7 +76,15 @@ class ModelConfig:
 
     # --- MoE ---
     moe: bool = False
+    # experts whose weights this model holds: the first ``n_experts`` of
+    # the ``router_experts`` the router scores (0: the router scores the
+    # held experts alone).  Held as one chip's share of an
+    # expert-parallel layer, the layer computes what its own experts add
+    # for every slot routed to them.
     n_experts: int = 0
+    router_experts: int = 0
+    norm_topk: bool = True             # renormalise the top-k gates
+    n_dense_layers: int = 0            # leading dense layers of a MoE stack
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0                  # per-expert hidden size
@@ -150,6 +168,53 @@ class ModelConfig:
         return jax.default_backend() == "tpu" and not sharded
 
     @property
+    def n_router(self) -> int:
+        """Experts the router scores."""
+        return self.router_experts or self.n_experts
+
+    def rope_inv_freq(self, dim: int) -> np.ndarray:
+        """Inverse frequencies of RoPE over ``dim`` features; with YaRN,
+        DeepSeek-V2's blend: interpolated (``/ yarn_factor``) below the
+        correction range, extrapolated above it, a linear ramp between."""
+        extra = 1.0 / self.rope_theta ** (np.arange(0, dim, 2) / dim)
+        if not self.yarn_factor:
+            return extra
+
+        def corr(rot):
+            return (dim * math.log(self.yarn_original_len
+                                   / (rot * 2 * math.pi))
+                    / (2 * math.log(self.rope_theta)))
+
+        lo = max(math.floor(corr(self.yarn_beta_fast)), 0)
+        hi = min(math.ceil(corr(self.yarn_beta_slow)), dim - 1)
+        if lo == hi:
+            hi += 0.001
+        ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+        keep = 1.0 - ramp                   # 1: extrapolate (high freq)
+        return extra / self.yarn_factor * (1.0 - keep) + extra * keep
+
+    def _yarn_mscale(self, m: float) -> float:
+        if self.yarn_factor <= 1.0 or not m:
+            return 1.0
+        return 0.1 * m * math.log(self.yarn_factor) + 1.0
+
+    @property
+    def rope_mscale(self) -> float:
+        """YaRN's factor on RoPE's cos and sin (1 without YaRN)."""
+        return (self._yarn_mscale(self.yarn_mscale)
+                / self._yarn_mscale(self.yarn_mscale_all_dim))
+
+    @property
+    def softmax_mscale(self) -> float:
+        """YaRN's factor on attention's softmax scale (1 without YaRN)."""
+        return self._yarn_mscale(self.yarn_mscale_all_dim) ** 2
+
+    def softmax_scale(self, dim: int) -> float:
+        """Attention's softmax scale for query/key width ``dim``:
+        ``softmax_mscale`` / sqrt(dim)."""
+        return self.softmax_mscale / math.sqrt(dim)
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -184,7 +249,12 @@ class ModelConfig:
             if rem:
                 segs.append(Segment((LayerSpec(ATTN, moe=moe),), rem))
             return tuple(segs)
-        # dense / moe / audio: homogeneous stack
+        # dense / moe / audio: a homogeneous stack, or leading dense
+        # layers and then the expert layers
+        k = self.n_dense_layers if moe else 0
+        if k:
+            return (Segment((LayerSpec(ATTN),), k),
+                    Segment((LayerSpec(ATTN, moe=True),), self.n_layers - k))
         return (Segment((LayerSpec(ATTN, moe=moe),), self.n_layers),)
 
     def n_params(self) -> int:

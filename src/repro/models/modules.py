@@ -1,6 +1,7 @@
-"""Model primitives: norms, RoPE, attention (GQA / qk-norm / sliding-window /
-MLA / cross), SwiGLU MLP, MoE with capacity-based scatter dispatch, and the
-Mamba2 SSD mixer (chunked scan for train/prefill, O(1) recurrence for decode).
+"""Model primitives: norms, RoPE (with YaRN), attention (GQA / qk-norm /
+sliding-window / MLA / cross), SwiGLU MLP, dropless MoE over the experts a
+model holds (grouped matmuls), and the Mamba2 SSD mixer (chunked scan for
+train/prefill, O(1) recurrence for decode).
 
 Everything is functional: ``params`` are nested dicts of arrays; the
 structure (shapes + logical sharding axes) comes from ``ParamSpec`` trees so
@@ -143,19 +144,34 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., L, H, D) or (..., L, D); positions: (..., L)."""
+def apply_rope(x, positions, theta: float, inv_freq=None,
+               mscale: float = 1.0):
+    """x: (..., L, H, D) or (..., L, D); positions: (..., L).  Rotates
+    feature pairs (2i, 2i+1) by position * ``inv_freq[i]`` (default
+    theta**(-2i/D)); ``mscale`` multiplies cos and sin."""
     d = x.shape[-1]
-    inv = jnp.asarray(rope_freqs(d, theta), jnp.float32)
+    inv = jnp.asarray(rope_freqs(d, theta) if inv_freq is None else inv_freq,
+                      jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * inv        # (..., L, d/2)
     if x.ndim == ang.ndim + 1:                                   # heads axis
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = x[..., ::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
     o1 = x1 * cos - x2 * sin
     o2 = x2 * cos + x1 * sin
     out = jnp.stack([o1, o2], axis=-1).reshape(x.shape)
     return out.astype(x.dtype)
+
+
+def rope(cfg: ModelConfig, x, positions):
+    """RoPE as ``cfg`` states it: YaRN's frequencies and cos/sin scale
+    when ``cfg.yarn_factor`` is set."""
+    if not cfg.yarn_factor:
+        return apply_rope(x, positions, cfg.rope_theta)
+    return apply_rope(x, positions, cfg.rope_theta,
+                      cfg.rope_inv_freq(x.shape[-1]), cfg.rope_mscale)
 
 
 # ===================================================================== #
@@ -177,17 +193,18 @@ def _tile_mask(qpos, kpos, Lk, causal, window):
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, q_block=512,
-                    k_block=1024, qpos0=0):
+                    k_block=1024, qpos0=0, scale=None):
     """Memory-efficient attention.
 
     q: (B, Lq, H, D); k, v: (B, Lk, KV, D) with H = KV * G.
     Never materializes (Lq, Lk); scans KV blocks with online softmax.
     ``qpos0`` offsets query positions (prefill continuation); ``window``
-    applies sliding-window masking.
+    applies sliding-window masking; ``scale`` (default 1/sqrt(D))
+    multiplies the scores.
     """
     Lq, Lk = q.shape[1], k.shape[1]
     meta = (bool(causal), window, int(min(q_block, Lq)),
-            int(min(k_block, Lk)), int(qpos0))
+            int(min(k_block, Lk)), int(qpos0), scale)
     return _flash(meta, q, k, v)
 
 
@@ -201,11 +218,11 @@ def _blockify(x, blk):
 
 
 def _flash_fwd_impl(meta, q, k, v):
-    causal, window, q_block, k_block, qpos0 = meta
+    causal, window, q_block, k_block, qpos0, scale = meta
     B, Lq, H, D = q.shape
     Lk, KV = k.shape[1], k.shape[2]
     G = H // KV
-    scale = 1.0 / np.sqrt(D)
+    scale = 1.0 / np.sqrt(D) if scale is None else scale
     q5 = q.reshape(B, Lq, KV, G, D)
     qp, nq = _blockify(q5, q_block)               # (B,nq,qb,KV,G,D)
     kp, nk = _blockify(k, k_block)                # (B,nk,kb,KV,D)
@@ -264,12 +281,12 @@ def _flash_fwd(meta, q, k, v):
 
 
 def _flash_bwd(meta, res, g):
-    causal, window, q_block, k_block, qpos0 = meta
+    causal, window, q_block, k_block, qpos0, scale = meta
     q, k, v, out, lse = res
     B, Lq, H, D = q.shape
     Lk, KV = k.shape[1], k.shape[2]
     G = H // KV
-    scale = 1.0 / np.sqrt(D)
+    scale = 1.0 / np.sqrt(D) if scale is None else scale
 
     q5 = q.reshape(B, Lq, KV, G, D)
     g5 = g.reshape(B, Lq, KV, G, D)
@@ -557,8 +574,11 @@ def attn_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg.rms_eps)
         k = rmsnorm(k, p["k_norm"], cfg.rms_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = rope(cfg, q, positions)
+    k = rope(cfg, k, positions)
+    if cfg.softmax_mscale != 1.0:
+        # YaRN's softmax scale, folded into q for every attention path
+        q = q * cfg.softmax_mscale
 
     new_cache = None
     if mode == "decode":
@@ -691,26 +711,28 @@ def _mla_qkv(cfg, p, x, positions):
     q = (x @ wgather(p["wq"], cfg, ("embed", "heads"))).reshape(
         B, L, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope(cfg, q_rope, positions)
     dkv = x @ wgather(p["w_dkv"], cfg, ("embed", None))   # (B,L,r+dr)
     ckv = rmsnorm(dkv[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.rms_eps)
-    krope = apply_rope(dkv[..., cfg.kv_lora_rank:], positions, cfg.rope_theta)
+    krope = rope(cfg, dkv[..., cfg.kv_lora_rank:], positions)
     return q_nope, q_rope, ckv, krope
 
 
 def mla_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
               window=None, block_tables=None):
-    """MLA.  Prefill/train: expand compressed KV and run flash attention.
-    Decode: *absorbed* form — scores and values computed directly against
-    the compressed cache (W_UK folded into q, W_UV applied after), so the
-    per-token cost is O(L·(r+dr)) instead of O(L·H·(dn+dr))."""
+    """MLA.  Prefill/train: expand compressed KV and run flash attention
+    (the Pallas kernels under ``cfg.pallas_kernels``).  Decode: *absorbed*
+    form — scores and values computed directly against the compressed
+    cache (W_UK folded into q, W_UV applied after), so the per-token cost
+    is O(L·(r+dr)) instead of O(L·H·(dn+dr)).  Every path scales the
+    scores by ``cfg.softmax_scale(dn + dr)`` (YaRN's, where set)."""
     assert block_tables is None, "paged KV does not support MLA"
     B = x.shape[0]
     H = cfg.n_heads
     r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
     q_nope, q_rope, ckv, krope = _mla_qkv(cfg, p, x, positions)
-    scale = 1.0 / np.sqrt(dn + dr)
+    scale = cfg.softmax_scale(dn + dr)
     new_cache = None
 
     if mode == "decode":
@@ -748,7 +770,13 @@ def mla_apply(cfg: ModelConfig, p, x, *, positions, mode, cache=None,
         dq = dn + dr
         vpad = jnp.pad(vfull, ((0, 0), (0, 0), (0, 0), (0, dq - dv))) \
             if dq != dv else vfull
-        o = flash_attention(q, k, vpad, causal=True, window=window)
+        if cfg.pallas_kernels:
+            from repro.kernels import ops as kops
+            o = kops.flash_attention(q, k, vpad, causal=True, window=window,
+                                     sm_scale=scale)
+        else:
+            o = flash_attention(q, k, vpad, causal=True, window=window,
+                                scale=scale)
         o = o[..., :dv]
         if mode == "prefill":
             S = cache["ckv"].shape[1]
@@ -818,12 +846,12 @@ def mlp_apply(p, x, cfg=None):
 
 
 # ===================================================================== #
-# MoE (capacity-based scatter dispatch; experts sharded over `model`)
+# MoE: dropless routing over the experts this model holds
 # ===================================================================== #
 def moe_specs(cfg: ModelConfig) -> dict:
     D, E, F = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
     s = {
-        "router": ParamSpec((D, E), ("embed", None)),
+        "router": ParamSpec((D, cfg.n_router), ("embed", None)),
         "w_gate": ParamSpec((E, D, F), ("experts", "embed", "mlp")),
         "w_up": ParamSpec((E, D, F), ("experts", "embed", "mlp")),
         "w_down": ParamSpec((E, F, D), ("experts", "mlp", "embed")),
@@ -833,59 +861,102 @@ def moe_specs(cfg: ModelConfig) -> dict:
     return s
 
 
-def moe_apply(cfg: ModelConfig, p, x):
-    """x: (B,L,D) -> (out, aux_loss).  Top-k capacity dispatch via scatter:
-    tokens are written into a per-expert (E, C, D) buffer (overflow dropped),
-    experts run as one batched einsum, results are gathered back weighted by
-    the (renormalized) router gates.  Dispatch cost is O(T·k·E) int ops for
-    the position cumsum — no (T, E, C) one-hot is ever built."""
+def grouped_matmul(x, w, sizes, use_pallas=False, layer=None):
+    """Rows of ``x`` (M, K), sorted by group, times their group's matrix
+    of ``w`` (G, K, N): group g is the ``sizes[g]`` rows after those of
+    the groups before it.  Rows past ``sizes.sum()`` are not computed;
+    their output rows hold no defined value.
+
+    With ``layer``, ``w`` is (U, G, K, N), the matrices of every layer of
+    a scan, and the groups are layer ``layer``'s.  The Pallas kernel then
+    reads them where they lie in the stack: it is handed the whole stack
+    as U * G groups, all empty but the layer's, so no slice of the stack
+    is copied out for it."""
+    if not use_pallas:
+        if layer is not None:
+            w = jax.lax.dynamic_index_in_dim(w, layer, 0, False)
+        return jax.lax.ragged_dot(x, w, sizes)
+    from repro.kernels import ops as kops
+    if layer is not None:
+        U, G = w.shape[:2]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((U * G,), jnp.int32), sizes.astype(jnp.int32),
+            (layer * G,))
+        w = w.reshape(U * G, *w.shape[2:])
+    return kops.grouped_matmul(x, w, sizes)
+
+
+def moe_apply(cfg: ModelConfig, p, x, layer=None):
+    """x: (B,L,D) -> (out, aux_loss, counts).  Dropless routing over the
+    experts this model holds.  With ``layer``, ``p``'s expert weights are
+    stacked over a scan's layers and this is layer ``layer``
+    (:func:`grouped_matmul`).
+
+    A softmax router over all ``cfg.n_router`` experts picks each token's
+    top-k (gates renormalised over the k when ``cfg.norm_topk``).  The
+    slots that chose one of the first ``cfg.n_experts`` experts, whose
+    weights are held here, are grouped by expert and run through grouped
+    matmuls; each output, scaled by its gate, is added to its token, and
+    the shared experts are added to every token.  Slots routed to experts
+    held elsewhere contribute nothing here: an expert-parallel layer sums
+    the shares of all its chips.  No slot is dropped at any skew: the
+    grouped rows are bounded by the worst case, T x min(k, held), and
+    the rows past the groups are not computed.  ``counts`` is int32
+    ``(held slots, held experts that got a slot)``."""
     B, L, D = x.shape
     T = B * L
-    E, K = cfg.n_experts, cfg.top_k
+    E, R, K = cfg.n_experts, cfg.n_router, cfg.top_k
     xf = x.reshape(T, D)
-    logits = (xf @ p["router"]).astype(jnp.float32)             # (T,E)
+    logits = (xf @ p["router"]).astype(jnp.float32)             # (T,R)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, expert_idx = jax.lax.top_k(probs, K)             # (T,K)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
 
-    cap = int(np.ceil(T * K / E * cfg.capacity_factor))
-    onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.int32)     # (T,K,E)
-    flat = onehot.reshape(T * K, E)
-    pos = (jnp.cumsum(flat, axis=0) * flat).sum(-1) - 1         # (T*K,)
+    # each held slot's row in the grouped order: the rows of expert e
+    # start after those of experts 0..e-1, in slot order
+    n_rows = T * min(K, E)
     e_flat = expert_idx.reshape(T * K)
-    slot = jnp.where(pos < cap, e_flat * cap + pos, E * cap)    # OOB -> drop
-
-    # Dispatch via an index-inversion GATHER rather than a row scatter:
-    # scattering (T*K, D) value rows makes GSPMD materialize per-element
-    # u32 index matrices; scattering the (T*K,) scalar row-ids and then
-    # row-gathering keeps all index tensors 1-D.
-    inv = jnp.full((E * cap,), T, jnp.int32).at[slot].set(
+    held = e_flat < E
+    onehot = (e_flat[:, None] == jnp.arange(E)).astype(jnp.int32)  # (T*K,E)
+    sizes = onehot.sum(0)
+    rank = (jnp.cumsum(onehot, axis=0) * onehot).sum(-1) - 1
+    start = jnp.cumsum(sizes) - sizes
+    dest = jnp.where(held, start[jnp.minimum(e_flat, E - 1)] + rank, n_rows)
+    # the token of each grouped row (T: the zero row past the tokens)
+    rows = jnp.full((n_rows,), T, jnp.int32).at[dest].set(
         jnp.arange(T * K, dtype=jnp.int32) // K, mode="drop")
-    xf_ext = jnp.concatenate([xf, jnp.zeros((1, D), x.dtype)], axis=0)
-    ein = jnp.take(xf_ext, inv, axis=0).reshape(E, cap, D)
-    w_g = wgather(p["w_gate"], cfg, ("experts", "embed", "mlp"))
-    w_u = wgather(p["w_up"], cfg, ("experts", "embed", "mlp"))
-    w_d = wgather(p["w_down"], cfg, ("experts", "mlp", "embed"))
-    h = (jax.nn.silu(jnp.einsum("ecd,edf->ecf", ein, w_g))
-         * jnp.einsum("ecd,edf->ecf", ein, w_u))
-    eout = jnp.einsum("ecf,efd->ecd", h, w_d).reshape(E * cap, D)
-    eout_ext = jnp.concatenate([eout, jnp.zeros((1, D), eout.dtype)], axis=0)
-    gathered = jnp.take(eout_ext, jnp.minimum(slot, E * cap),
-                        axis=0)                                  # (T*K,D)
-    valid = (pos < cap).astype(x.dtype)
-    w = (gate_vals.reshape(T * K).astype(x.dtype) * valid)[:, None]
-    out = (gathered * w).reshape(T, K, D).sum(1)
+    xs = jnp.take(jnp.concatenate([xf, jnp.zeros((1, D), x.dtype)]), rows,
+                  axis=0)
+    lead = () if layer is None else (None,)
+    w_g = wgather(p["w_gate"], cfg, lead + ("experts", "embed", "mlp"))
+    w_u = wgather(p["w_up"], cfg, lead + ("experts", "embed", "mlp"))
+    w_d = wgather(p["w_down"], cfg, lead + ("experts", "mlp", "embed"))
+    gmm = partial(grouped_matmul, sizes=sizes, use_pallas=cfg.pallas_kernels,
+                  layer=layer)
+    h = jax.nn.silu(gmm(xs, w_g)) * gmm(xs, w_u)
+    y = gmm(h, w_d)                                             # (n_rows,D)
+    # back to the slots (a slot held elsewhere reads the zero row), then
+    # each token's slots weighted by their gates
+    y = jnp.concatenate([y.astype(x.dtype), jnp.zeros((1, D), x.dtype)])
+    ys = jnp.take(y, jnp.minimum(dest, n_rows), axis=0)         # (T*K,D)
+    w = jnp.where(held, gate_vals.reshape(T * K), 0.0).astype(x.dtype)
+    out = (ys * w[:, None]).reshape(T, K, D).sum(1)
 
     if cfg.n_shared_experts:
         out = out + mlp_apply(p["shared"], xf, cfg)
 
-    # aux losses: switch-style load balance + router z-loss
-    frac = onehot.sum(1).mean(0).astype(jnp.float32)            # (E,) tokens
-    imp = probs.mean(0)                                         # (E,)
-    aux = (cfg.router_aux_coef * E * jnp.sum(frac * imp) / K
+    # aux losses over the router's experts: switch-style load balance +
+    # router z-loss
+    frac = jax.nn.one_hot(expert_idx, R, dtype=jnp.int32).sum(1).mean(
+        0).astype(jnp.float32)                                  # (R,)
+    imp = probs.mean(0)                                         # (R,)
+    aux = (cfg.router_aux_coef * R * jnp.sum(frac * imp) / K
            + cfg.router_z_coef
            * jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2))
-    return out.reshape(B, L, D), aux
+    counts = jnp.stack([sizes.sum(), (sizes > 0).sum()]).astype(jnp.int32)
+    return out.reshape(B, L, D), aux, counts
 
 
 # ===================================================================== #

@@ -26,18 +26,41 @@ def init_params(cfg: ModelConfig, key) -> dict:
 
 
 def values(cfg: ModelConfig, params, tokens, *, embeds=None,
-           encoder_embeds=None):
-    """Per-token scalar values: (B, L)."""
-    hidden, _, _ = T.forward(cfg, params, tokens=tokens, embeds=embeds,
-                             encoder_embeds=encoder_embeds, mode="full")
-    return (hidden @ params["v_head"]).astype(jnp.float32)[..., 0]
+           encoder_embeds=None, moe_counts=False):
+    """Per-token scalar values: (B, L); with ``moe_counts`` also the
+    forward's MoE counts (:func:`repro.models.transformer.forward`)."""
+    hidden, _, _, counts = T.forward(cfg, params, tokens=tokens,
+                                     embeds=embeds,
+                                     encoder_embeds=encoder_embeds,
+                                     mode="full", moe_counts=True)
+    v = (hidden @ params["v_head"]).astype(jnp.float32)[..., 0]
+    return (v, counts) if moe_counts else v
 
 
-def end_scores(cfg: ModelConfig, params, tokens, attn_mask):
+def end_scores(cfg: ModelConfig, params, tokens, attn_mask,
+               moe_counts=False):
     """Score at the last non-pad token of each sequence: (B,)."""
-    v = values(cfg, params, tokens)
+    v, counts = values(cfg, params, tokens, moe_counts=True)
     last = jnp.maximum(attn_mask.sum(-1) - 1, 0).astype(jnp.int32)
-    return jnp.take_along_axis(v, last[:, None], axis=1)[:, 0]
+    s = jnp.take_along_axis(v, last[:, None], axis=1)[:, 0]
+    return (s, counts) if moe_counts else s
+
+
+def frozen(cfg: ModelConfig, params) -> dict:
+    """A frozen (never trained) model's tree as its forward reads it: the
+    layers and the LM head in the compute dtype (the cast
+    :func:`repro.models.transformer.forward` makes at entry).  Kept as
+    they are: the value head, which :func:`values` applies uncast, and
+    the embedding table, which the forward gathers rows of and casts.
+    (With the table cast too, the TPU compiler tiles the vocabulary
+    projection of the scoring program worse, for the actor's forward as
+    well as the reference's: scoring the 3-layer OPT-1.3B/350M pair took
+    11 ms longer on a v5e.)"""
+    out = T.cast_params(cfg, params)
+    for k in ("v_head", "embed"):
+        if k in params:
+            out[k] = params[k]
+    return out
 
 
 def pairwise_loss(cfg: ModelConfig, params, chosen, rejected, chosen_mask,

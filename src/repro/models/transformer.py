@@ -172,13 +172,15 @@ def decode_layer_split(cfg: ModelConfig) -> tuple[int, int]:
 def _unit_apply(cfg: ModelConfig, unit_spec, uparams, x, positions, mode,
                 ucache, enc, block_tables=None, layer=None):
     """One scan unit.  In decode ``ucache`` holds the segment's stacked
-    caches and ``layer`` the unit's index into them (see
+    caches, the MoE layers' expert weights in ``uparams`` are stacked
+    too, and ``layer`` is the unit's index into both (see
     :func:`_segment_apply`); otherwise ``ucache`` is this unit's own."""
     # barrier: stops XLA promoting the whole stacked scan carry / cache to
     # f32 outside the loop (it hoists `convert` of loop-invariant stacks,
     # materializing layer-count-sized f32 temps)
     x = jax.lax.optimization_barrier(x)
     aux = jnp.zeros((), jnp.float32)
+    counts = jnp.zeros((2,), jnp.int32)
     new_caches = []
     for i, spec in enumerate(unit_spec):
         lp = uparams[i]
@@ -198,8 +200,8 @@ def _unit_apply(cfg: ModelConfig, unit_spec, uparams, x, positions, mode,
             x = x + att
             h2 = M.rmsnorm(x, lp["ln2"], cfg.rms_eps, cfg.pallas_kernels)
             if spec.moe:
-                m, a = M.moe_apply(cfg, lp["mlp"], h2)
-                aux = aux + a
+                m, a, c = M.moe_apply(cfg, lp["mlp"], h2, layer=layer)
+                aux, counts = aux + a, counts + c
             else:
                 m = M.mlp_apply(lp["mlp"], h2, cfg)
             x = x + m
@@ -210,8 +212,8 @@ def _unit_apply(cfg: ModelConfig, unit_spec, uparams, x, positions, mode,
             x = x + att
             h2 = M.rmsnorm(x, lp["ln2"], cfg.rms_eps, cfg.pallas_kernels)
             if spec.moe:
-                m, a = M.moe_apply(cfg, lp["mlp"], h2)
-                aux = aux + a
+                m, a, c = M.moe_apply(cfg, lp["mlp"], h2, layer=layer)
+                aux, counts = aux + a, counts + c
             else:
                 m = M.mlp_apply(lp["mlp"], h2, cfg)
             x = x + m
@@ -228,7 +230,32 @@ def _unit_apply(cfg: ModelConfig, unit_spec, uparams, x, positions, mode,
                                                                  0),
                 ucache[i], nc)
         new_caches.append(nc if nc is not None else {})
-    return x, aux, tuple(new_caches)
+    return x, (aux, counts), tuple(new_caches)
+
+
+def _add(a, b):
+    return jax.tree.map(jnp.add, a, b)
+
+
+def _aux0():
+    """(aux loss, MoE counts: held slots, held experts given a slot)."""
+    return jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.int32)
+
+
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _split_experts(seg: Segment, sparams):
+    """(``sparams`` without its MoE layers' expert weights, those weights
+    as {unit position: {name: stack over the units}})."""
+    rest, stacks = [], {}
+    for i, (spec, lp) in enumerate(zip(seg.unit_spec, sparams)):
+        if spec.moe:
+            stacks[i] = {n: lp["mlp"][n] for n in _EXPERT_WEIGHTS}
+            lp = {**lp, "mlp": {n: w for n, w in lp["mlp"].items()
+                                if n not in _EXPERT_WEIGHTS}}
+        rest.append(lp)
+    return type(sparams)(rest), stacks
 
 
 def _segment_apply(cfg: ModelConfig, seg: Segment, sparams, x, positions,
@@ -237,16 +264,23 @@ def _segment_apply(cfg: ModelConfig, seg: Segment, sparams, x, positions,
         # the segment's stacked caches ride in the scan carry, not in
         # xs -> ys: a layer writes its new row into the stack and reads it
         # there, so a step moves one row per layer, never a whole slab or
-        # the whole stack (see _in_place for the layers that still slice)
+        # the whole stack (see _in_place for the layers that still slice).
+        # The expert weights stay out of xs too: each layer's grouped
+        # matmuls read its experts in the whole stack, so no layer's
+        # experts are sliced out into a buffer of their own.
+        sparams, stacks = _split_experts(seg, sparams)
+
         def step(carry, xs):
             xc, aux, sc = carry
             up, li = xs
+            up = type(up)({**lp, "mlp": {**lp["mlp"], **stacks[i]}}
+                          if i in stacks else lp for i, lp in enumerate(up))
             xc, a, sc = _unit_apply(cfg, seg.unit_spec, up, xc, positions,
                                     mode, sc, enc, block_tables, layer=li)
-            return (xc, aux + a, sc), None
+            return (xc, _add(aux, a), sc), None
 
         (x, aux, scache), _ = jax.lax.scan(
-            step, (x, jnp.zeros((), jnp.float32), scache),
+            step, (x, _aux0(), scache),
             (sparams, jnp.arange(seg.n_units, dtype=jnp.int32)))
         return x, aux, scache
 
@@ -262,12 +296,12 @@ def _segment_apply(cfg: ModelConfig, seg: Segment, sparams, x, positions,
         # threaded through the carry
         xc, a, nc = _unit_apply(cfg, seg.unit_spec, up, xc, positions, mode,
                                 uc, enc, block_tables)
-        return (xc, aux + a), (nc if has_cache else None)
+        return (xc, _add(aux, a)), (nc if has_cache else None)
 
     if cfg.remat and mode == "full":
         body = jax.checkpoint(body)
     xs = (sparams, scache) if has_cache else sparams
-    (x, aux), ncache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    (x, aux), ncache = jax.lax.scan(body, (x, _aux0()), xs)
     return x, aux, ncache
 
 
@@ -280,8 +314,10 @@ def cast_params(cfg: ModelConfig, params):
 
 def forward(cfg: ModelConfig, params, *, tokens=None, embeds=None,
             encoder_embeds=None, mode: str = "full", cache=None,
-            positions=None, block_tables=None):
-    """Returns (hidden (B,L,D), new_cache, aux_loss).
+            positions=None, block_tables=None, moe_counts: bool = False):
+    """Returns (hidden (B,L,D), new_cache, aux_loss), and with
+    ``moe_counts`` also the int32 ``(held slots, held experts given a
+    slot)`` summed over the MoE layers (see :func:`moe_layers`).
 
     mode='full'    — training / scoring, no cache.
     mode='prefill' — like full but also fills ``cache``.
@@ -307,17 +343,26 @@ def forward(cfg: ModelConfig, params, *, tokens=None, embeds=None,
         enc = (encoder_embeds.astype(cfg.cdtype) @ params["projector"]
                ) if encoder_embeds is not None else None
 
-    aux = jnp.zeros((), jnp.float32)
+    aux = _aux0()
     new_segs = []
     for si, seg in enumerate(cfg.segments()):
         sc = cache[si] if cache is not None else None
         x, a, nc = _segment_apply(cfg, seg, params["segments"][si], x,
                                   positions, mode, sc, enc, block_tables)
-        aux = aux + a
+        aux = _add(aux, a)
         new_segs.append(nc)
     x = M.rmsnorm(x, params["final_norm"], cfg.rms_eps, cfg.pallas_kernels)
     new_cache = tuple(new_segs) if cache is not None else None
-    return x, new_cache, aux
+    if moe_counts:
+        return x, new_cache, aux[0], aux[1]
+    return x, new_cache, aux[0]
+
+
+def moe_layers(cfg: ModelConfig) -> int:
+    """Layers with a MoE MLP (each routes every token to ``cfg.top_k``
+    of the router's experts)."""
+    return sum(seg.n_units * sum(ls.moe for ls in seg.unit_spec)
+               for seg in cfg.segments())
 
 
 def lm_head(cfg: ModelConfig, params):

@@ -122,6 +122,19 @@ from repro.serving.generate import decode_scan_step, decode_step, prefill
 from repro.serving.sampling import sample, sample_rows
 
 
+def count_moe(phase: str, role: str, cfg, tokens: int, counts) -> None:
+    """Add one model's MoE work to the tracer's counters: the slots its
+    router filled over ``tokens`` tokens (``moe.routed_slots.<phase>.
+    <role>``), the slots routed to the experts held here
+    (``moe.held_slots``) and, summed over the layer calls, the held
+    experts that got a slot (``moe.active_experts``); ``counts`` is what
+    :func:`repro.models.transformer.forward` returns for them."""
+    routed = tokens * cfg.top_k * T.moe_layers(cfg)
+    obs.count(f"moe.routed_slots.{phase}.{role}", routed)
+    obs.count(f"moe.held_slots.{phase}.{role}", int(counts[0]))
+    obs.count(f"moe.active_experts.{phase}.{role}", int(counts[1]))
+
+
 class _Unset:
     """Sentinel distinguishing "not set, use the engine default" from an
     explicit ``None`` (e.g. ``eos_id=None`` = never stop on a token)."""
@@ -363,9 +376,10 @@ class GenerationEngine:
         tokens = self._constrain_batch_arr(tokens)
         cache = self._constrain_cache(
             T.init_cache(self.cfg, B, Lp + self.max_new_tokens), B)
-        logits, cache = prefill(self.cfg, params, tokens, cache,
-                                encoder_embeds=encoder_embeds)
-        return logits, self._constrain_cache(cache, B)
+        logits, cache, counts = prefill(self.cfg, params, tokens, cache,
+                                        encoder_embeds=encoder_embeds,
+                                        moe_counts=True)
+        return logits, self._constrain_cache(cache, B), counts
 
     def _fixed_chunk(self, n: int):
         if n not in self._chunk_fns:
@@ -373,10 +387,10 @@ class GenerationEngine:
                 step = decode_scan_step(
                     self.cfg, params, temperature=self.temperature,
                     top_k=self.top_k, top_p=self.top_p, eos_id=self.eos_id,
-                    encoder_embeds=encoder_embeds)
-                carry, (toks, was) = jax.lax.scan(
+                    encoder_embeds=encoder_embeds, moe_counts=True)
+                carry, (toks, was, counts) = jax.lax.scan(
                     step, (logits, cache, key, pos, done), None, length=n)
-                return carry, toks, was
+                return carry, toks, was, counts.sum(0)
             # donate the whole carry (rebound every dispatch) so chunked
             # decode never memcpys the KV cache between chunks
             self._chunk_fns[n] = jax.jit(obs.named("gen_decode", fn),
@@ -397,8 +411,8 @@ class GenerationEngine:
             return {"sequences": tokens,
                     "response_mask": jnp.zeros((B, Lp), bool)}
         with obs.span("gen.prefill"):
-            logits, cache = self._prefill_fixed(params, tokens,
-                                                encoder_embeds)
+            logits, cache, moe_pre = self._prefill_fixed(params, tokens,
+                                                         encoder_embeds)
             pos = jnp.full((B,), Lp, jnp.int32)
             done = jnp.zeros((B,), bool)
             # the chunk fns donate their whole carry; copy the caller's
@@ -408,16 +422,20 @@ class GenerationEngine:
         # without an EOS there is nothing to exit early on — one fused
         # dispatch, no per-chunk host sync (the PPO default)
         chunk = self.chunk if self.eos_id is not None else max_new
-        tok_parts, was_parts, steps = [], [], 0
+        tok_parts, was_parts, moe_parts, steps = [], [], [], 0
         n_in, n_sliced = T.decode_layer_split(self.cfg)
+        n_moe = T.moe_layers(self.cfg)
         with obs.span("gen.decode"):
             while steps < max_new:
                 n = min(chunk, max_new - steps)
                 fn = self._fixed_chunk(n)
-                (logits, cache, key, pos, done), toks, was = fn(
+                (logits, cache, key, pos, done), toks, was, moe = fn(
                     params, logits, cache, key, pos, done, encoder_embeds)
+                moe_parts.append(moe)
                 obs.count("gen.decode.inplace_layers", n_in)
                 obs.count("gen.decode.copied_layers", n_sliced)
+                if n_moe:
+                    obs.count("moe.layers_held", n_moe)
                 tok_parts.append(np.asarray(toks))
                 was_parts.append(np.asarray(was))
                 steps += n
@@ -438,6 +456,12 @@ class GenerationEngine:
             sequences = np.concatenate([np.asarray(tokens), gen], axis=1)
             mask = np.concatenate(
                 [np.zeros((B, Lp), bool), ~was_done], axis=1)
+            if n_moe:
+                # what the experts held here computed: read with the
+                # tokens, which the host already waited for
+                count_moe("gen", "actor", self.cfg, B * (Lp + steps),
+                          np.asarray(moe_pre)
+                          + sum(np.asarray(c) for c in moe_parts))
             self.last_stats = {
                 "decode_steps": steps,
                 "scheduled_tokens": B * steps,

@@ -31,38 +31,41 @@ from repro.serving.sampling import sample
 
 
 def prefill(cfg: ModelConfig, params, tokens, cache, *, embeds=None,
-            encoder_embeds=None):
+            encoder_embeds=None, moe_counts=False):
     """Run the prompt through the model, filling ``cache``.
-    Returns (last-position logits (B, V), cache)."""
-    hidden, cache, _ = T.forward(cfg, params, tokens=tokens, embeds=embeds,
-                                 encoder_embeds=encoder_embeds,
-                                 mode="prefill", cache=cache)
+    Returns (last-position logits (B, V), cache), and with ``moe_counts``
+    the forward's MoE counts (:func:`repro.models.transformer.forward`)."""
+    hidden, cache, _, counts = T.forward(
+        cfg, params, tokens=tokens, embeds=embeds,
+        encoder_embeds=encoder_embeds, mode="prefill", cache=cache,
+        moe_counts=True)
     logits = T.logits_fn(cfg, params, hidden[:, -1:])[:, 0]
-    return logits, cache
+    return (logits, cache, counts) if moe_counts else (logits, cache)
 
 
 def decode_step(cfg: ModelConfig, params, token, cache, position, *,
-                embeds=None, encoder_embeds=None, block_tables=None):
+                embeds=None, encoder_embeds=None, block_tables=None,
+                moe_counts=False):
     """One decode step.  token: (B,) int32; position: (B,) absolute.
-    Returns (logits (B, V), new_cache).  With ``block_tables`` set,
-    ``cache`` is the paged block pool (see
-    :func:`repro.models.transformer.init_paged_cache`)."""
+    Returns (logits (B, V), new_cache), and with ``moe_counts`` the
+    step's MoE counts.  With ``block_tables`` set, ``cache`` is the paged
+    block pool (see :func:`repro.models.transformer.init_paged_cache`)."""
     kw = {}
     if cfg.embed_inputs:
         kw["tokens"] = token[:, None]
     else:
         kw["embeds"] = embeds
-    hidden, cache, _ = T.forward(cfg, params, mode="decode", cache=cache,
-                                 positions=position[:, None],
-                                 encoder_embeds=encoder_embeds,
-                                 block_tables=block_tables, **kw)
+    hidden, cache, _, counts = T.forward(
+        cfg, params, mode="decode", cache=cache, positions=position[:, None],
+        encoder_embeds=encoder_embeds, block_tables=block_tables,
+        moe_counts=True, **kw)
     logits = T.logits_fn(cfg, params, hidden)[:, 0]
-    return logits, cache
+    return (logits, cache, counts) if moe_counts else (logits, cache)
 
 
 def decode_scan_step(cfg: ModelConfig, params, *, temperature: float,
                      top_k: int, eos_id: Optional[int], top_p: float = 1.0,
-                     encoder_embeds=None):
+                     encoder_embeds=None, moe_counts=False):
     """Build the ``lax.scan`` body shared by :func:`generate` and the
     chunked engine decode.
 
@@ -70,7 +73,8 @@ def decode_scan_step(cfg: ModelConfig, params, *, temperature: float,
     ``(tok, was_done)`` where ``was_done`` is the *pre-step* done flag:
     the step that emits the first EOS still records ``was_done=False``
     (the EOS token itself counts as generated), every later step forces
-    ``eos_id`` out of the sampler with ``was_done=True``.
+    ``eos_id`` out of the sampler with ``was_done=True``.  With
+    ``moe_counts`` the step's MoE counts follow as a third output.
     """
     def step(carry, _):
         logits, cache, key, pos, done = carry
@@ -79,10 +83,12 @@ def decode_scan_step(cfg: ModelConfig, params, *, temperature: float,
                      top_p=top_p)
         if eos_id is not None:
             tok = jnp.where(done, eos_id, tok)
-        logits, cache = decode_step(cfg, params, tok, cache, pos,
-                                    encoder_embeds=encoder_embeds)
+        logits, cache, counts = decode_step(cfg, params, tok, cache, pos,
+                                            encoder_embeds=encoder_embeds,
+                                            moe_counts=True)
         new_done = done | (tok == eos_id) if eos_id is not None else done
-        return (logits, cache, key, pos + 1, new_done), (tok, done)
+        ys = (tok, done, counts) if moe_counts else (tok, done)
+        return (logits, cache, key, pos + 1, new_done), ys
     return step
 
 

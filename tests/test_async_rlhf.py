@@ -156,7 +156,7 @@ def test_async_config_validation():
 # ===================================================================== #
 # behavior logprobs are the SAMPLING-time logprobs (satellite fix)
 # ===================================================================== #
-def _tiny_trainer():
+def _tiny_trainer(**kw):
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     from repro.models import reward as R
     from repro.models import transformer as T
@@ -166,11 +166,13 @@ def _tiny_trainer():
         critic_params=R.init_params(CRITIC, k2),
         ref_params=T.init_params(ACTOR, k1),
         reward_params=R.init_params(CRITIC, k2),
-        ppo=PPOConfig(max_new_tokens=4, temperature=1.0))
+        ppo=PPOConfig(max_new_tokens=4, temperature=1.0), **kw)
 
 
 def test_behavior_logprobs_are_sampling_time_not_recomputed():
-    tr = _tiny_trainer()
+    # the behavior params are retained by reference, as the async
+    # pipeline's weight publisher retains them: no donation
+    tr = _tiny_trainer(donate=False)
     prompts = jnp.asarray(np.full((4, 6), 3, np.int32))
     rollout, _ = tr.generate_rollout(prompts, jax.random.PRNGKey(7))
     exp0, sm0 = tr.score_rollout(rollout, policy_lag=0)

@@ -364,3 +364,78 @@ def test_tile_picks_legal_blocks(total, preferred, align, want):
     assert (blk, padded) == want
     assert padded % blk == 0 and padded >= total
     assert blk == padded or blk % align == 0
+
+
+# ------------------------------------------------------------------ #
+# grouped matmul of the held experts, and flash with a softmax scale
+# ------------------------------------------------------------------ #
+@pytest.mark.parametrize("sizes", [(5, 0, 40, 3), (0, 0, 0, 0), (96, 0, 0, 0)])
+def test_grouped_matmul_kernel_matches_ragged_dot(sizes):
+    """The megablox kernel (interpret mode) computes each group's rows as
+    ``jax.lax.ragged_dot`` does, empty groups and rows past the groups
+    included (those rows: no defined value, so not compared); so do its
+    gradients on the grouped rows."""
+    M, K, N = 96, 128, 256
+    kx, kw = jax.random.split(KEY)
+    x = jax.random.normal(kx, (M, K), jnp.float32)
+    w = jax.random.normal(kw, (4, K, N), jnp.float32) / np.sqrt(K)
+    gs = jnp.asarray(sizes, jnp.int32)
+    n = sum(sizes)
+    got = ops.grouped_matmul(x, w, gs)
+    want = jax.lax.ragged_dot(x, w, gs)
+    np.testing.assert_allclose(np.asarray(got[:n]), np.asarray(want[:n]),
+                               rtol=1e-5, atol=1e-5)
+    mask = (jnp.arange(M) < n)[:, None]
+
+    def loss(f):
+        return lambda x, w: jnp.sum(jnp.where(mask, f(x, w, gs), 0.0) ** 2)
+
+    gk = jax.grad(loss(ops.grouped_matmul), argnums=(0, 1))(x, w)
+    gr = jax.grad(loss(jax.lax.ragged_dot), argnums=(0, 1))(x, w)
+    np.testing.assert_allclose(np.asarray(gk[0][:n]), np.asarray(gr[0][:n]),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(gk[1]), np.asarray(gr[1]),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_grouped_matmul_reads_a_layer_of_the_stack(use_pallas, layer):
+    """A layer's grouped matmul handed the whole (layers, groups, K, N)
+    stack and the layer's index equals the one handed that layer's
+    slice, for the megablox kernel (interpret mode) and the ragged dot."""
+    from repro.models import modules as M
+    M_, K, N, U = 96, 128, 256, 3
+    kx, kw = jax.random.split(KEY)
+    x = jax.random.normal(kx, (M_, K), jnp.float32)
+    w = jax.random.normal(kw, (U, 4, K, N), jnp.float32) / np.sqrt(K)
+    gs = jnp.asarray((5, 0, 40, 3), jnp.int32)
+    got = jax.jit(lambda x, w, li: M.grouped_matmul(
+        x, w, gs, use_pallas, layer=li))(x, w, jnp.int32(layer))
+    want = M.grouped_matmul(x, w[layer], gs, use_pallas)
+    np.testing.assert_allclose(np.asarray(got[:48]), np.asarray(want[:48]),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_flash_softmax_scale_matches_jnp():
+    """``sm_scale`` reaches the Pallas flash forward and backward as it
+    reaches the jnp flash: MLA's YaRN scale on 192-wide heads."""
+    from repro.models.modules import flash_attention as jnp_flash
+    B, L, H, D = 1, 128, 2, 192
+    ks = jax.random.split(KEY, 3)
+    q, k, v = (jax.random.normal(kk, (B, L, H, D), jnp.float32) for kk in ks)
+    s = 0.11472
+
+    def f(fn, **kw):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, causal=True, **kw) ** 2)
+
+    o = ops.flash_attention(q, k, v, sm_scale=s)
+    o_ref = jnp_flash(q, k, v, causal=True, scale=s)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), rtol=2e-4,
+                               atol=2e-4)
+    g = jax.grad(f(ops.flash_attention, sm_scale=s), argnums=(0, 1, 2))(
+        q, k, v)
+    g_ref = jax.grad(f(jnp_flash, scale=s), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
+                                   atol=2e-3)

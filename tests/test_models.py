@@ -115,6 +115,9 @@ def _slice(kw, sl):
 
 # the dense decode kernels (interpret mode) read the carried arena in place
 FAMILIES["dense-pallas"] = FAMILIES["dense"].replace(use_pallas=True)
+# the held experts' grouped matmuls by the megablox kernel; decode reads
+# each layer's experts in the stack over the layers
+FAMILIES["moe-pallas"] = FAMILIES["moe"].replace(use_pallas=True)
 FAMILIES["kvquant-pallas"] = FAMILIES["dense"].replace(kv_quant=True,
                                                        use_pallas=True)
 

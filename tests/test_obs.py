@@ -308,12 +308,12 @@ def _lowered(trainer, name):
     """The program ``name`` of the stage-3 path, lowered at a tiny size."""
     he, eng = trainer.engine, trainer.gen_engine
     pa = trainer.actor.params
-    logits, cache = jax.eval_shape(eng._prefill_fixed, pa, PROMPTS, None)
+    logits, cache, _ = jax.eval_shape(eng._prefill_fixed, pa, PROMPTS, None)
     seqs = jax.ShapeDtypeStruct((2, 6 + GEN), jnp.int32)
     mask = jax.ShapeDtypeStruct((2, 6 + GEN), jnp.bool_)
     exp_args = (pa, trainer.ref_params, trainer.critic.params,
                 trainer.reward_params, seqs, mask)
-    exp, _ = jax.eval_shape(trainer._mk_exp, *exp_args)
+    exp, _, _ = jax.eval_shape(trainer._mk_exp, *exp_args)
     B = jax.ShapeDtypeStruct((2,), jnp.int32)
     done = jax.ShapeDtypeStruct((2,), jnp.bool_)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)

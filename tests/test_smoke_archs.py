@@ -85,7 +85,7 @@ def test_full_config_spec_matches_assignment(arch):
         "llama4-scout-17b-a16e": (48, 5120, 40, 8, 8192, 202048),
         "mamba2-370m": (48, 1024, 0, 0, 0, 50280),
         "zamba2-1.2b": (38, 2048, 32, 32, 8192, 32000),
-        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 1408, 102400),
+        "deepseek-v2-lite-16b": (27, 2048, 16, 16, 10944, 102400),
         "smollm-135m": (30, 576, 9, 3, 1536, 49152),
         "llama-3.2-vision-11b": (40, 4096, 32, 8, 14336, 128256),
     }[arch]

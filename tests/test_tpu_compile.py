@@ -200,3 +200,75 @@ def test_decode_step_moves_no_arena(one_chip, monkeypatch, kv_quant):
     arena_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree.leaves(cache))
     assert compiled.memory_analysis().alias_size_in_bytes >= arena_bytes
+
+
+# ------------------------------------------------------------------ #
+# DeepSeek-V2-Lite: the held experts' grouped matmuls and MLA's flash
+# ------------------------------------------------------------------ #
+def test_decode_step_slices_no_experts(one_chip, monkeypatch):
+    """``gen_decode`` of one chip's share of DeepSeek-V2-Lite (a dense
+    layer and 4 MoE layers holding 8 of 64 experts, published widths),
+    compiled with the Pallas kernels: each layer's grouped matmuls read
+    its experts in the stack over the layers, so no op inside the decode
+    loops makes a buffer of one layer's experts."""
+    from repro.configs.deepseek_v2_lite_16b import CONFIG
+    from repro.models import transformer as T
+    from repro.serving.engine import GenerationEngine
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = CONFIG.replace(n_layers=5, router_experts=64, n_experts=8,
+                         vocab_size=12800, compute_dtype="bfloat16",
+                         use_pallas=True)
+    batch, prompt, gen = 16, 64, 64
+    eng = GenerationEngine(cfg, max_new_tokens=gen)
+
+    def on_chip(x):
+        dt = BF16 if x.dtype == jnp.float32 else x.dtype
+        return jax.ShapeDtypeStruct(x.shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.tree.map(on_chip, T.cache_struct(cfg, batch, prompt + gen))
+    logits, key, pos, done = (
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in (
+            ((batch, cfg.vocab_size), jnp.float32), ((2,), jnp.uint32),
+            ((batch,), jnp.int32), ((batch,), jnp.bool_)))
+    text = eng._fixed_chunk(gen).lower(
+        params, logits, cache, key, pos, done, None).compile().as_text()
+    assert "gmm" in text
+    one_layer = re.compile(r"= bf16\[8,(2048,1408|1408,2048)\]")
+    made = [line.strip()[:120] for line in text.splitlines()
+            if one_layer.search(line) and "parameter(" not in line
+            and "get-tuple-element(" not in line]
+    assert made == []
+
+
+@pytest.mark.parametrize("rows", [16 * 6, 16 * 512 * 6])
+def test_grouped_matmul_compiles(one_chip, monkeypatch, rows):
+    """The megablox kernel, forward and backward, at the held experts'
+    widths (8 experts, d_model 2048, width 1408) for a decode step's and
+    a training batch's worst-case rows."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+    def fwd_bwd(x, w, sizes):
+        return jax.grad(lambda x, w: jnp.sum(ops.grouped_matmul(
+            x, w, sizes).astype(jnp.float32)), argnums=(0, 1))(x, w)
+
+    text = _compile(fwd_bwd, one_chip, ((rows, 2048), BF16),
+                    ((8, 2048, 1408), BF16), ((8,), jnp.int32))
+    assert "gmm" in text and "tgmm" in text
+
+
+def test_flash_attention_mla_scale_compiles(one_chip, monkeypatch):
+    """MLA's training attention: 16 heads of 192 (V padded to 192), YaRN's
+    softmax scale, forward and backward."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(ops.flash_attention(
+            q, k, v, sm_scale=0.11472).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    shape = ((2, L, 16, 192), BF16)
+    _compile(fwd_bwd, one_chip, shape, shape, shape)
